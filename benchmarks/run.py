@@ -24,8 +24,6 @@ from benchmarks.pipelines import (bench6_schema_errors,  # noqa: E402
                                   pipelines_bench)
 from benchmarks.serving import (bench5_schema_errors,  # noqa: E402
                                 serving_bench)
-from benchmarks.roofline_stencil import (bench9_schema_errors,  # noqa: E402
-                                         roofline_stencil_bench)
 from benchmarks.serving_load import (bench8_schema_errors,  # noqa: E402
                                      serving_load_bench)
 from benchmarks.slabs import (bench7_schema_errors,  # noqa: E402
@@ -38,7 +36,6 @@ BENCHES = (
     fig14_mapping, table4_instructions, temporal_blocking,
     structure_bench, stencil_wallclock, serving_bench, pipelines_bench,
     slabs_bench, serving_load_bench, lm_roofline, stencil_cluster_mapping,
-    roofline_stencil_bench,
 )
 
 
@@ -96,19 +93,10 @@ def write_bench8(detail: dict, root: str = _ROOT) -> str:
                         "BENCH_8.json", root)
 
 
-def write_bench9(detail: dict, root: str = _ROOT) -> str:
-    """Write the roofline-calibration bench's BENCH_9.json at the repo
-    root (per-backend measured bandwidth, calibrated-vs-measured tile
-    ranking agreement, achieved roofline fraction); schema-checked
-    before writing."""
-    return _write_bench(detail, "bench9", bench9_schema_errors,
-                        "BENCH_9.json", root)
-
-
 def main() -> None:
-    # Environment setup goes through the one config helper (platform +
-    # GPU XLA flags when requested) instead of ad-hoc jax.config calls.
-    _env.set_platform(os.environ.get("CASPER_BENCH_PLATFORM", "cpu"))
+    # JAX picks the platform (JAX_PLATFORMS pins one); the compile cache
+    # goes through the one config helper.
+    _env.enable_compile_cache()
     out_dir = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "results")
     os.makedirs(out_dir, exist_ok=True)
@@ -130,8 +118,6 @@ def main() -> None:
     print(f"# wrote {write_bench7(all_detail['slabs_bench'])}",
           file=sys.stderr)
     print(f"# wrote {write_bench8(all_detail['serving_load_bench'])}",
-          file=sys.stderr)
-    print(f"# wrote {write_bench9(all_detail['roofline_stencil_bench'])}",
           file=sys.stderr)
     summaries = {k: v.get("summary") for k, v in all_detail.items()
                  if isinstance(v, dict) and v.get("summary")}

@@ -31,7 +31,7 @@ from __future__ import annotations
 
 import numpy as np
 import jax
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.serve import (AsyncStencilServer, ServeConfig, StencilServer,
                          mixed_requests, poisson_workload, submit_open_loop)

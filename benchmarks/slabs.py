@@ -30,7 +30,7 @@ import time
 import numpy as np
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import PAPER_STENCILS, advect2d
 from repro.core import perfmodel as pm

@@ -11,10 +11,8 @@ plan-cache 0-lower/0-autotune pin), the continuous-batching load check
 under open-loop Poisson arrivals + zero low-load deadline misses + f32
 and f64 bit-identity vs serve_sequential), and the fused-pipeline check
 (BENCH_6 schema + fused modeled HBM bytes strictly below the
-stage-by-stage chain + fused wallclock beating the unfused chain), and
-the roofline-calibration check (BENCH_9 schema + calibrated analytic
-tile ranking agreeing with the measured ranking per backend + sane
-roofline fractions) — a couple of minutes on a laptop CPU.
+stage-by-stage chain + fused wallclock beating the unfused chain) — a
+couple of minutes on a laptop CPU.
 
 The full harness (``benchmarks/run.py``) also runs measured-wallclock and
 256-device subprocess benches; this entry point keeps CI fast and
@@ -86,8 +84,10 @@ def distributed_smoke() -> dict:
     halo exactly spans a whole 4-point shard on ``sx`` — the halo==block
     single-hop boundary case; multi-hop is covered by
     tests/test_distributed.py) must match the single-device oracle and
-    show ~4x fewer collective-permute launches than the unfused path."""
-    env = dict(os.environ)
+    show ~4x fewer collective-permute launches than the unfused path.
+    The child is a CPU-only study on forced host devices: it pins the
+    CPU itself and never contends for an accelerator."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["PYTHONPATH"] = os.path.join(_ROOT, "src")
     proc = subprocess.run([sys.executable, "-c", _DIST_CODE],
                           capture_output=True, text=True, env=env,
@@ -323,42 +323,6 @@ def serving_load_smoke() -> dict:
             "sustained_rps": detail["summary"]["sustained_rps"]}
 
 
-def roofline_calibration_smoke() -> dict:
-    """Measured roofline calibration end to end: run the BENCH_9
-    calibration bench on a reduced matrix (one workload, top-2 tiles),
-    schema-check its payload, write the BENCH_9.json perf-trajectory
-    artifact, and assert
-
-    * the calibrated analytic top tile agrees with the measured tile
-      ranking (ties allowed) for every (workload, backend) cell — the
-      acceptance criterion that makes the cost models *measured*,
-    * every achieved roofline fraction is finite and within the loose
-      interpret-mode sanity bounds (0, 64], and
-    * the fitted calibration carries a positive measured bandwidth.
-    """
-    from benchmarks.roofline_stencil import (bench9_schema_errors,
-                                             roofline_stencil_bench)
-    from benchmarks.run import write_bench9
-    rows, detail = roofline_stencil_bench(
-        reps=2, top_k=2, workloads=(("jacobi2d", (96, 128), 2),),
-        bandwidth_mbytes=16)
-    payload = detail["bench9"]
-    errs = bench9_schema_errors(payload)
-    assert not errs, errs
-    path = write_bench9(detail)
-    assert detail["summary"]["all_agree"], detail["summary"]
-    for c in payload["workloads"]:
-        frac = c["roofline"]["roofline_fraction"]
-        assert 0.0 < frac <= 64.0, (c["backend"], frac)
-        bw = [v for k, v in c["calibration"].items() if k.endswith("_bw")]
-        assert bw and bw[0] > 0, c["calibration"]
-    return {"bench9_path": path,
-            "backends": payload["backends"],
-            "roofline_fractions": {
-                k: round(v, 2)
-                for k, v in detail["summary"]["roofline_fractions"].items()}}
-
-
 def serve_smoke() -> dict:
     """Serve determinism: same key -> same tokens, and exactly
     ``n_tokens - 1`` jitted decode steps per generate call."""
@@ -394,6 +358,8 @@ def serve_smoke() -> dict:
 
 
 def main() -> None:
+    from repro.configs import env as _env
+    _env.enable_compile_cache()
     print("name,us_per_call,derived")
     n_rows = 0
     for bench in SMOKE_BENCHES:
@@ -442,13 +408,10 @@ def main() -> None:
     slab = slab_smoke()
     for n, r in slab["traffic_overheads"].items():
         print(f"slab_smoke_{n}_traffic_overhead,0.000,{r}")
-    roof = roofline_calibration_smoke()
-    for n, r in roof["roofline_fractions"].items():
-        print(f"roofline_smoke_{n.replace('/', '_')}_fraction,0.000,{r}")
     print(f"# smoke OK: {n_rows} rows, engine parity err {err:.2e}, "
           f"structure {struct}, distributed {dist}, serve {srv}, "
           f"stencil serving {ssrv}, serving load {load}, "
-          f"pipelines {pipe}, slabs {slab}, roofline {roof}",
+          f"pipelines {pipe}, slabs {slab}",
           file=sys.stderr)
 
 

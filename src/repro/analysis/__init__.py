@@ -32,6 +32,7 @@ from .verify import (
 from .jaxpr_lint import (
     LINT_CHECKS,
     count_primitive,
+    count_tap_windows,
     lint_plan,
     slice_budget,
     trace_plan_jaxpr,
@@ -42,7 +43,7 @@ __all__ = [
     "CHECKS", "LINT_CHECKS", "Finding", "PlanVerificationError",
     "PlanVerificationWarning", "Report", "VERIFY_ENV", "VERIFY_MODES",
     "SHED_POLICIES", "analyze_plan", "check_serve_config",
-    "clear_reports", "count_primitive", "counters",
+    "clear_reports", "count_primitive", "count_tap_windows", "counters",
     "lint_plan", "report_for", "set_verify_mode", "slice_budget",
     "summarize_plan", "trace_plan_jaxpr", "verify_and_record",
     "verify_mode", "verify_plan",

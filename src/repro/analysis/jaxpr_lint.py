@@ -1,10 +1,9 @@
 """Layer-2 static plan lint: trace the plan's jitted executor and walk
-the jaxpr (and, for fused pipelines, the compiled HLO via
-:mod:`repro.roofline.hlo_walk`) for properties the layer-1 field checks
-cannot see:
+the jaxpr for properties the layer-1 field checks cannot see:
 
 * **de-specialization** — a structured plan whose traced executor emits
-  more ``dynamic_slice`` fetches than its factored tap-op budget
+  more tap-window slices (``slice`` eqns under the
+  ``ref.TAP_WINDOW_SCOPE`` name scope) than its factored tap-op budget
   (``sweeps * sum_k tap_ops(stage_k)``): the compute core silently fell
   back to the dense per-tap chain.  This generalizes the one-off jaxpr
   slice-count guard of ``tests/test_structure.py`` into a real pass.
@@ -19,8 +18,9 @@ cannot see:
   scanned f64 plan — it is the documented contract, not a bug.
 * **HBM round-trips** — a fused pipeline must move strictly fewer HBM
   bytes than its staged per-stage fallback (the whole point of fusion).
-  Both executors are compiled and their optimized HLO walked with the
-  trip-count-aware :func:`repro.roofline.hlo_walk.walk`.
+  Both executors are traced and their bytes counted as the chip moves
+  them (:func:`hbm_bytes`): a kernel reads its operands and writes its
+  results, its body works in VMEM.
 
 The VM backend is numpy (untraceable) and distributed plans trace under
 a mesh; both are skipped with an info finding.
@@ -32,6 +32,7 @@ import contextlib
 import numpy as np
 
 from repro.core import plan as _plan
+from repro.core import ref as _ref
 from repro.core.stencil import factor_taps
 
 from .verify import Finding, Report, summarize_plan
@@ -73,9 +74,20 @@ def count_primitive(jaxpr, name: str) -> int:
     return sum(1 for eqn in _walk_eqns(inner) if eqn.primitive.name == name)
 
 
+def count_tap_windows(jaxpr) -> int:
+    """Tap-window fetches in ``jaxpr``: the ``slice`` eqns traced under
+    the ``ref.TAP_WINDOW_SCOPE`` name scope, recursing like
+    :func:`count_primitive` (the executors' other static slices — the
+    kernel's window cut, mirror shifts, output crops — do not count)."""
+    inner = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
+    return sum(1 for eqn in _walk_eqns(inner)
+               if eqn.primitive.name == "slice"
+               and _ref.TAP_WINDOW_SCOPE in str(eqn.source_info.name_stack))
+
+
 def _x64_if_needed(dtype):
     if np.dtype(dtype).itemsize == 8:
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         return enable_x64()
     return contextlib.nullcontext()
 
@@ -100,7 +112,7 @@ def trace_plan_jaxpr(plan, iters: int | None = None):
 # The checks
 # ---------------------------------------------------------------------------
 def _stage_slice_budget(stage) -> int:
-    """``dynamic_slice`` budget of ONE application of ``stage``: one
+    """Tap-window slice budget of ONE application of ``stage``: one
     fetch per factored tap-op plus the window re-centers — one per
     application (the shrinking deep-halo window) and, on the factored
     separable path, one per sequential 1-D axis pass.  Star/dense specs
@@ -114,7 +126,7 @@ def _stage_slice_budget(stage) -> int:
 
 
 def slice_budget(plan) -> int:
-    """Upper bound on ``dynamic_slice`` fetches one fused block
+    """Upper bound on tap-window slices one fused block
     (``plan.execute``) may emit: the per-stage budget times ``sweeps``
     applications."""
     return plan.sweeps * sum(_stage_slice_budget(s) for s in plan.stages)
@@ -124,11 +136,11 @@ def lint_despecialization(plan, jaxpr=None) -> list[Finding]:
     if jaxpr is None:
         jaxpr = trace_plan_jaxpr(plan)
     budget = slice_budget(plan)
-    n = count_primitive(jaxpr, "dynamic_slice")
+    n = count_tap_windows(jaxpr)
     if n > budget:
         return [Finding(
             "de-specialization", "error",
-            f"traced executor emits {n} dynamic_slice fetches; the "
+            f"traced executor emits {n} tap-window slices; the "
             f"factored budget is {budget} (sweeps={plan.sweeps}, "
             f"per-stage budgets "
             f"{[_stage_slice_budget(s) for s in plan.stages]}) — the "
@@ -185,14 +197,43 @@ def lint_fma_contraction(plan, iters: int | None = None) -> list[Finding]:
         f"bit-identity (fuzz corpus seed 29)")]
 
 
+def _aval_bytes(v) -> int:
+    aval = getattr(v, "aval", None)
+    shape = getattr(aval, "shape", None)
+    if shape is None or not hasattr(aval, "dtype"):
+        return 0
+    return int(np.prod(shape, dtype=np.int64)) * np.dtype(aval.dtype).itemsize
+
+
+def hbm_bytes(jaxpr) -> int:
+    """HBM bytes of a traced executor, counted from its array operands:
+    every ``pallas_call`` is charged its operands read once and its
+    results written once (the kernel body is not walked), every other
+    array op reads its operands and writes its result, and call-like
+    eqns (``pjit``, ``custom_vmap_call``, ...) are walked instead of
+    counted.  For kernels this is a lower bound: the kernel DMAs each
+    tile's granule-aligned window (``perfmodel.fetch_window``), so
+    overlapping windows re-read the halo and the alignment padding.
+    Unfused XLA ops are counted one by one — an upper bound.  Both
+    sides of a comparison are counted alike."""
+    inner = jaxpr.jaxpr if hasattr(jaxpr, "jaxpr") else jaxpr
+    total = 0
+    for eqn in inner.eqns:
+        subs = [sub for v in eqn.params.values() for sub in _subjaxprs(v)]
+        if subs and eqn.primitive.name != "pallas_call":
+            total += sum(hbm_bytes(sub) for sub in subs)
+            continue
+        total += sum(_aval_bytes(v) for v in eqn.invars)
+        total += sum(_aval_bytes(v) for v in eqn.outvars)
+    return total
+
+
 def lint_hbm(plan, staged_fn=None) -> list[Finding]:
-    """Compile one fused block and its staged per-stage fallback and
-    compare HBM bytes counted from the optimized HLO: fusion must move
-    strictly fewer bytes (intermediates staying in VMEM/registers is
-    the whole point).  ``staged_fn`` overrides the fallback executor
-    (the mutation tests pass the fused executor itself to prove the
-    check has teeth)."""
-    from repro.roofline import hlo_walk
+    """Trace one fused block and its staged per-stage fallback and
+    compare their HBM bytes (:func:`hbm_bytes`): fusion must move
+    strictly fewer (intermediates staying in VMEM is the whole point).
+    ``staged_fn`` overrides the fallback executor (the mutation tests
+    pass the fused executor itself to prove the check has teeth)."""
     import jax
 
     if staged_fn is None:
@@ -208,13 +249,13 @@ def lint_hbm(plan, staged_fn=None) -> list[Finding]:
 
     with _x64_if_needed(plan.dtype):
         dummy = jax.ShapeDtypeStruct(plan.shape, np.dtype(plan.dtype))
-        fused = hlo_walk.walk_jit(fused_fn, dummy)
-        staged = hlo_walk.walk_jit(staged_fn, dummy)
-    if fused.bytes >= staged.bytes:
+        fused = hbm_bytes(jax.make_jaxpr(fused_fn)(dummy))
+        staged = hbm_bytes(jax.make_jaxpr(staged_fn)(dummy))
+    if fused >= staged:
         return [Finding(
             "hbm-roundtrips", "error",
-            f"fused pipeline moves {fused.bytes:.0f} HBM bytes but its "
-            f"staged per-stage fallback moves {staged.bytes:.0f}: "
+            f"fused pipeline moves {fused} HBM bytes but its "
+            f"staged per-stage fallback moves {staged}: "
             f"fusion is not eliding the intermediate round-trips")]
     return []
 
@@ -226,13 +267,10 @@ def lint_plan(plan, hbm: bool | None = None) -> Report:
     """Run the full layer-2 lint over ``plan`` and return a
     :class:`~repro.analysis.verify.Report`.
 
-    ``hbm=None`` compiles the HBM round-trip comparison exactly when it
-    is meaningful: a fused single-device Pallas pipeline with a
-    non-periodic boundary.  (The staged fallback of a ``ref`` pipeline
-    is *defined* as the chain; distributed plans compile under a mesh;
-    and the periodic pad-free kernel blocks the whole grid in VMEM, so
-    the CPU-interpret HLO byte count — which cannot see the VMEM/HBM
-    split — is not an HBM proxy for it.)
+    ``hbm=None`` runs the HBM round-trip comparison exactly when it is
+    meaningful: a fused single-device Pallas pipeline.  (The staged
+    fallback of a ``ref`` pipeline is *defined* as the chain, and
+    distributed plans trace under a mesh.)
     """
     findings: list[Finding] = []
     if plan.backend == "vm":
@@ -266,8 +304,7 @@ def lint_plan(plan, hbm: bool | None = None) -> Report:
     findings += lint_fma_contraction(plan)
     if hbm is None:
         hbm = (plan.is_pipeline and plan.fused
-               and plan.backend in _plan.KERNEL_BACKENDS
-               and plan.boundary_mode != "periodic")
+               and plan.backend in _plan.KERNEL_BACKENDS)
     if hbm:
         findings += lint_hbm(plan)
     return Report(summarize_plan(plan), LINT_CHECKS, tuple(findings))

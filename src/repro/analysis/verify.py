@@ -253,11 +253,12 @@ def _check_decompose(plan) -> list[Finding]:
 
 @_check("tile-legality")
 def _check_tile(plan) -> list[Finding]:
-    """Only fused kernel-backend plans (pallas / triton) carry a
-    resolved tile; its rank matches the grid, entries are positive, and
-    a non-periodic pad-free kernel's clamped fetch needs
-    ``window <= grid`` per dim (else lowering should have fallen back
-    to the padded window)."""
+    """Only fused kernel-backend plans carry a resolved tile; its rank
+    matches the grid, entries are positive, a pad-free kernel's wrapped
+    fetch needs every extent to be a multiple of its tile and every tile
+    to be at least its aligned fetch depth (else lowering should have
+    fallen back to the padded window), and a compiled plan's tile must
+    be a multiple of the HBM granule its DMAs are aligned to."""
     out = []
     needs_tile = plan.backend in _plan.KERNEL_BACKENDS and plan.fused
     if not needs_tile:
@@ -277,54 +278,42 @@ def _check_tile(plan) -> list[Finding]:
     if any(t < 1 for t in plan.tile):
         return [Finding("tile-legality", "error",
                         f"tile entries must be positive, got {plan.tile}")]
-    if plan.ghost_strategy == "pad-free" and plan.boundary_mode != "periodic":
-        win = _pm.tile_window(plan.tile, plan.halo, plan.sweeps)
-        bad = [d for d, (w, n) in enumerate(zip(win, plan.shape)) if w > n]
-        if bad:
-            out.append(Finding(
-                "tile-legality", "error",
-                f"pad-free clamped fetch needs window <= grid per dim; "
-                f"window {win} exceeds grid {plan.shape} on dims {bad}"))
+    itemsize = np.dtype(plan.dtype).itemsize
+    grain = _pm.fetch_grain(len(plan.tile), itemsize)
+    if plan.ghost_strategy == "pad-free" and not _pm.pad_free_fetch(
+            plan.shape, plan.tile, plan.deep_halo, itemsize):
+        out.append(Finding(
+            "tile-legality", "error",
+            f"pad-free fetch needs grid {plan.shape} to be a multiple of "
+            f"tile {plan.tile} with every tile >= its fetch depth "
+            f"{_pm.fetch_halo(plan.deep_halo, grain)}"))
+    if not plan.interpret and any(t % g for t, g in zip(plan.tile, grain)):
+        out.append(Finding(
+            "tile-legality", "error",
+            f"compiled tile {plan.tile} is not a multiple of the HBM "
+            f"granule {grain}"))
     return out
 
 
 @_check("vmem-budget")
 def _check_vmem(plan) -> list[Finding]:
-    """The fused kernel's resident set — window, accumulator, per-term
-    intermediates, output block, plus the whole grid for a periodic
-    pad-free wrap gather — must fit the backend's scratch memory:
-    VMEM for the mosaic (``"pallas"``) lowering, one SM's shared
-    memory for ``"triton"`` (whose periodic whole-grid block streams
-    through L2, so it is *not* charged against shared memory).  The
-    shared-memory bound applies to *compiled* triton plans only: an
-    interpret-mode plan executes on CPU, where the 96 KiB budget is
-    vacuous — deep-sweep f64 windows that could never compile on a GPU
-    must still run in the CI correctness matrix (pass ``tile="auto"``
-    on real hardware; the GPU autotuner rejects infeasible tiles)."""
+    """The fused kernel's resident set — DMA buffer, window,
+    accumulator, per-term intermediates, output block — must fit
+    VMEM."""
     if not (plan.backend in _plan.KERNEL_BACKENDS and plan.fused
             and plan.tile is not None):
-        return []
-    if plan.backend == "triton" and plan.interpret:
         return []
     itemsize = np.dtype(plan.dtype).itemsize
     n_terms = max(
         (1 if s.factorization.compute_terms is None
          else len(s.factorization.compute_terms)) for s in plan.stages)
-    if plan.backend == "triton":
-        budget, budget_name, grid_shape = (
-            _pm.GPU_SMEM_BYTES, "GPU shared memory", None)
-    else:
-        budget, budget_name = _pm.TPU_VMEM_BYTES, "VMEM"
-        grid_shape = (plan.shape if plan.ghost_strategy == "pad-free"
-                      and plan.boundary_mode == "periodic" else None)
-    vmem = _pm.vmem_residency(
-        plan.tile, plan.halo, plan.sweeps, itemsize, n_terms,
-        boundary_mode=plan.boundary_mode, shape=grid_shape)
-    if vmem > budget:
+    vmem = _pm.vmem_residency(plan.tile, plan.halo, plan.sweeps, itemsize,
+                              n_terms)
+    if vmem > _pm.TPU_VMEM_BYTES:
         return [Finding(
             "vmem-budget", "error",
-            f"resident set {vmem} B exceeds {budget_name} "
-            f"{budget} B (tile={plan.tile}, "
+            f"resident set {vmem} B exceeds VMEM "
+            f"{_pm.TPU_VMEM_BYTES} B (tile={plan.tile}, "
             f"window={_pm.tile_window(plan.tile, plan.halo, plan.sweeps)}, "
             f"terms={n_terms})")]
     return []
@@ -337,8 +326,7 @@ def _check_ghost(plan) -> list[Finding]:
     pipeline stages, distributed Pallas always takes the padded window,
     a single-device ref/pallas grid past the recorded slab budget
     streams from host, and single-device Pallas otherwise re-derives
-    pad-free vs padded-window (periodic additionally bounded by the
-    whole-grid VMEM budget)."""
+    pad-free vs padded-window."""
     g = plan.ghost_strategy
     if g not in _plan.GHOST_STRATEGIES:
         return [Finding("ghost-strategy", "error",
@@ -361,7 +349,7 @@ def _check_ghost(plan) -> list[Finding]:
     else:
         expected = _plan.ghost_strategy_for(
             plan.spec, plan.shape, np.dtype(plan.dtype).itemsize,
-            plan.sweeps, plan.tile, backend=plan.backend)
+            plan.sweeps, plan.tile)
     if g != expected:
         return [Finding(
             "ghost-strategy", "error",

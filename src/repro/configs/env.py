@@ -3,11 +3,11 @@ host-device count, debug flags).
 
 One home for the ad-hoc ``jax.config`` / ``XLA_FLAGS`` fiddling the
 benchmarks used to do inline: the bit-identity matrix needs x64, the
-distributed smokes need a forced host-device count, and a GPU run wants
-the documented XLA performance flags.  All of these only take full
-effect **before** jax initializes its backends, so benchmark entry
-points call them at the top of ``main()`` (the benchmark runner and the
-roofline-calibration bench both do).
+distributed smokes need a forced host-device count, and every entry
+point that compiles for the chip keeps JAX's persistent compilation
+cache in one place (:func:`enable_compile_cache`).  All of these only
+take full effect **before** jax initializes its backends, so entry
+points call them at the top of ``main()``.
 """
 from __future__ import annotations
 
@@ -17,17 +17,25 @@ from multiprocessing import cpu_count
 
 import jax
 
-# The documented GPU performance flags
-# (https://jax.readthedocs.io/en/latest/gpu_performance_tips.html):
-# triton-backed fusions on, async collectives + latency-hiding
-# scheduling for the distributed path.
-GPU_XLA_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true "
-    "--xla_gpu_triton_gemm_any=True "
-    "--xla_gpu_enable_async_collectives=true "
-    "--xla_gpu_enable_latency_hiding_scheduler=true "
-    "--xla_gpu_enable_highest_priority_async_stream=true"
-)
+#: Environment variable naming JAX's persistent compilation cache
+#: directory; where it is set, it wins over every default here.
+COMPILE_CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+#: Fixed in-repo fallback cache directory (listed in ``.gitignore``).  A
+#: fixed path, never a temp, pid or time-based one: the directory is
+#: part of what makes a later run find the entries again.
+REPO_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory: ``$JAX_COMPILATION_CACHE_DIR`` where it is set, else
+    :data:`REPO_CACHE_DIR`.  Call before the first compile."""
+    path = os.environ.get(COMPILE_CACHE_ENV, "").strip() or REPO_CACHE_DIR
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def jax_enable_x64(use_x64: bool = True) -> None:
@@ -36,24 +44,6 @@ def jax_enable_x64(use_x64: bool = True) -> None:
     if not use_x64:
         use_x64 = bool(os.getenv("JAX_ENABLE_X64", 0))
     jax.config.update("jax_enable_x64", use_x64)
-
-
-def set_platform(platform: str = "cpu") -> None:
-    """Pin the jax platform to ``'cpu'``, ``'gpu'`` or ``'tpu'``.
-
-    Only takes full effect before the first jax computation.  On GPU the
-    documented XLA performance flags (:data:`GPU_XLA_FLAGS`) are
-    appended to ``XLA_FLAGS`` so pallas-triton and XLA fusions run with
-    async collectives and latency-hiding scheduling enabled.
-    """
-    if platform not in ("cpu", "gpu", "tpu"):
-        raise ValueError(f"unknown platform {platform!r}")
-    jax.config.update("jax_platform_name", platform)
-    if platform == "gpu":
-        have = os.environ.get("XLA_FLAGS", "")
-        missing = " ".join(f for f in GPU_XLA_FLAGS.split() if f not in have)
-        if missing:
-            os.environ["XLA_FLAGS"] = (have + " " + missing).strip()
 
 
 def set_cpu_cores(n: int) -> None:

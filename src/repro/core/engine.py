@@ -55,7 +55,7 @@ from .plan import resolve_interpret  # canonical home is core.plan
 from .segment import SegmentConfig
 from .stencil import StencilPipeline, StencilSpec
 
-Backend = Literal["ref", "pallas", "triton"]
+Backend = Literal["ref", "pallas"]
 
 
 class CasperEngine:
@@ -76,8 +76,8 @@ class CasperEngine:
         self.backend = backend
         self.segment = segment or SegmentConfig()
         # None -> auto-detect: interpret kernels on CPU, compile on
-        # real hardware (backend-aware: triton wants a GPU).
-        self.interpret = resolve_interpret(interpret, backend)
+        # the chip.
+        self.interpret = resolve_interpret(interpret)
         self.sweeps = sweeps
         self.tile = tile
         # Pipelines assemble to a PipelineProgram (one Program per stage).

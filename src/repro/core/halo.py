@@ -50,7 +50,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from . import plan as _plan
 from . import ref as _ref
@@ -132,7 +131,7 @@ def _fix_edge_ghosts_1axis(padded: jax.Array, axis: int, halo: int,
         inside = ((g >= 0) & (g < grid_n)).reshape(shape)
         return jnp.where(inside, padded,
                          jnp.asarray(value, padded.dtype))
-    return _ref.reflect_gather(padded, axis, start - halo, grid_n, ext)
+    return _ref.reflect_gather(padded, axis, start - halo, grid_n, halo)
 
 
 def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
@@ -171,8 +170,7 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
             from repro.kernels import engine as keng  # lazy: optional dep
             return keng.pipeline_window_sweep(
                 spec, padded, x.shape, origin, grid_shape,
-                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
-                lowering="triton" if plan.backend == "triton" else None)
+                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret)
         return _ref.masked_window_pipeline(
             padded, spec.stages, x.shape, plan.sweeps, origin, grid_shape,
             x.dtype).astype(x.dtype)
@@ -180,8 +178,7 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
         from repro.kernels import engine as keng  # lazy: optional dep
         return keng.stencil_window_sweep(
             spec, padded, x.shape, origin, grid_shape,
-            tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
-            lowering="triton" if plan.backend == "triton" else None)
+            tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret)
     return _ref.masked_window_sweeps(
         padded, spec.taps, halo, x.shape, plan.sweeps, origin, grid_shape,
         x.dtype, mode=mode, value=value,
@@ -198,9 +195,9 @@ def execute_plan(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
     local = functools.partial(_local_multisweep, plan)
     # pallas_call has no shard_map replication rule; the local fn is
     # purely per-shard, so disabling the check is sound there.
-    step = shard_map(local, mesh=plan.mesh, in_specs=(pspec,),
-                     out_specs=pspec,
-                     check_rep=(plan.backend not in _plan.KERNEL_BACKENDS))
+    step = jax.shard_map(local, mesh=plan.mesh, in_specs=(pspec,),
+                         out_specs=pspec,
+                         check_vma=(plan.backend not in _plan.KERNEL_BACKENDS))
     return step(x)
 
 
@@ -211,7 +208,7 @@ def distributed_stencil_fn(
     iters: int = 1,
     *,
     sweeps: int = 1,
-    backend: Literal["ref", "pallas", "triton"] = "ref",
+    backend: Literal["ref", "pallas"] = "ref",
     tile: Sequence[int] | Literal["auto"] | None = None,
     interpret: bool | None = None,
 ) -> Callable[[jax.Array], jax.Array]:

@@ -13,8 +13,6 @@ the full grid.
 from __future__ import annotations
 
 import dataclasses
-import functools
-import json
 import math
 import os
 
@@ -217,116 +215,6 @@ TPU_GRID_STEP_S = 2e-7               # per-grid-step sequencing overhead;
 VPU_SUBLANES, VPU_LANES = 8, 128     # f32 min tile (sublane x lane)
 
 
-#: Whole-grid VMEM budget for the periodic pad-free kernel's wrap
-#: gather (its input block is the *entire* grid, so the far edge is
-#: addressable).  Canonical home of the knob; ``kernels.engine``
-#: re-exports it as its patchable ``_PERIODIC_WHOLE_GRID_BYTES``.
-PERIODIC_WHOLE_GRID_BYTES = TPU_VMEM_BYTES // 4
-
-# ----------------------------------------------------------------------------
-# GPU-side tile cost model (drives the backend="triton" autotuner column)
-# ----------------------------------------------------------------------------
-# Titan V (Volta, same part as the Table 5 GPU rows above): the triton
-# lowering schedules one CTA per output tile, so the analogue of the TPU
-# sequential grid walk is CTA scheduling across 80 SMs.
-GPU_N_SMS = 80
-GPU_SMEM_BYTES = 96 * 1024       # max shared memory per SM (Volta)
-GPU_L2_BYTES = 4608 * 1024       # 4.5 MB device-wide L2
-GPU_PEAK_FLOPS_F32 = 14.9e12     # f32 peak (f64 peak is GPU_PEAK_FLOPS)
-WARP_LANES = 32                  # coalescing/divergence grain (one warp)
-GPU_CTA_STEP_S = 1.0e-8          # per-CTA issue/retire overhead beyond the
-                                 # launch floor; CALIBRATED: same role as
-                                 # TPU_GRID_STEP_S, ~80x smaller because CTAs
-                                 # schedule concurrently across SMs
-
-#: Whole-grid budget for the periodic pad-free wrap gather on the GPU
-#: path.  The gathered grid block streams through L2 (not shared
-#: memory), so the budget is L2-derived: past it the repeated wrap
-#: gathers of every CTA would double global traffic and the plan falls
-#: back to the wrap-padded window, exactly like the TPU VMEM rule.
-#: ``kernels.gpu`` re-exports it as its patchable knob.
-GPU_PERIODIC_WHOLE_GRID_BYTES = GPU_L2_BYTES // 4
-
-# ----------------------------------------------------------------------------
-# Measured calibration (CASPER_CALIBRATION): benchmarks/roofline_stencil.py
-# back-fits the bandwidth/overhead constants from a bandwidth
-# microbenchmark + measured fused-kernel timings, and publishes the fit
-# through this env knob so the analytic tile ranking runs on *measured*
-# numbers instead of asserted ones.
-# ----------------------------------------------------------------------------
-#: Environment override: either an inline JSON object or a path to a
-#: JSON file.  Recognized keys (all floats, unknown keys ignored so a
-#: calibration file can carry provenance fields):
-#: ``tpu_hbm_bw``, ``tpu_grid_step_s``, ``tpu_vpu_flops_f32`` (pallas
-#: cost model) and ``gpu_bw``, ``gpu_launch_s``, ``gpu_cta_step_s``,
-#: ``gpu_peak_flops_f32``, ``gpu_n_sms`` (triton cost model;
-#: ``gpu_n_sms`` may be fractional — a serial interpret host fits it
-#: below 1 to neutralize the occupancy term, see the roofline
-#: calibration bench).
-CALIBRATION_ENV = "CASPER_CALIBRATION"
-
-_CALIBRATION_KEYS = frozenset((
-    "tpu_hbm_bw", "tpu_grid_step_s", "tpu_vpu_flops_f32",
-    "gpu_bw", "gpu_launch_s", "gpu_cta_step_s", "gpu_peak_flops_f32",
-    "gpu_n_sms",
-))
-
-
-@functools.lru_cache(maxsize=32)
-def _parse_calibration(raw: str) -> tuple[tuple[str, float], ...]:
-    text = raw
-    if not raw.lstrip().startswith("{"):
-        with open(raw, encoding="utf-8") as fh:
-            text = fh.read()
-    try:
-        data = json.loads(text)
-    except json.JSONDecodeError as e:
-        raise ValueError(f"{CALIBRATION_ENV} is not valid JSON: {e}") from e
-    if not isinstance(data, dict):
-        raise ValueError(f"{CALIBRATION_ENV} must be a JSON object")
-    out = []
-    for key in sorted(data):
-        if key not in _CALIBRATION_KEYS:
-            continue
-        val = float(data[key])
-        # Rates (bandwidth, flops) divide traffic — they must be
-        # strictly positive; per-step/launch overheads are additive and
-        # a measured fit may legitimately clamp them to zero.
-        floor_ok = val >= 0.0 if key.endswith("_s") else val > 0.0
-        if not floor_ok or math.isinf(val) or math.isnan(val):
-            raise ValueError(
-                f"{CALIBRATION_ENV}[{key!r}] must be a finite "
-                f"{'non-negative' if key.endswith('_s') else 'positive'} "
-                f"number, got {data[key]!r}")
-        out.append((key, val))
-    return tuple(out)
-
-
-def calibration() -> dict[str, float]:
-    """The measured constant overrides currently in force: the parsed
-    ``CASPER_CALIBRATION`` JSON (inline or a file path) filtered to the
-    recognized keys, ``{}`` when the env var is unset.  Consulted at
-    call time by every tile-cost function below, so a calibration run
-    re-ranks tiles without re-importing anything."""
-    raw = os.environ.get(CALIBRATION_ENV)
-    if raw is None or not raw.strip():
-        return {}
-    return dict(_parse_calibration(raw))
-
-
-def calibration_fingerprint() -> tuple[tuple[str, float], ...]:
-    """Hashable identity of the active calibration — part of the
-    autotune cache key (``kernels.tune``), so rankings computed under
-    different measured constants never collide."""
-    raw = os.environ.get(CALIBRATION_ENV)
-    if raw is None or not raw.strip():
-        return ()
-    return _parse_calibration(raw)
-
-
-def _cal(key: str, default: float) -> float:
-    return calibration().get(key, default)
-
 # ----------------------------------------------------------------------------
 # Out-of-core slab streaming budget (ghost strategy "stream-from-host")
 # ----------------------------------------------------------------------------
@@ -397,6 +285,33 @@ def _ceil_to(x: int, grain: int) -> int:
     return -(-x // grain) * grain
 
 
+def fetch_grain(ndim: int, itemsize: int) -> tuple[int, ...]:
+    """Per-dim granule of an HBM array's tiled layout on the TPU: a DMA
+    may only start and end on these.  Rank 1 is tiled in runs of 1024
+    32-bit words, rank >= 2 in (sublane, lane) tiles of (8, 128) 32-bit
+    words — sub-32-bit dtypes pack more rows per sublane — and leading
+    dims are untiled."""
+    pack = max(1, 4 // itemsize)
+    if ndim == 1:
+        return (1024 * pack,)
+    return (1,) * (ndim - 2) + (VPU_SUBLANES * pack, VPU_LANES)
+
+
+def fetch_halo(deep: tuple[int, ...], grain: tuple[int, ...]
+               ) -> tuple[int, ...]:
+    """Ghost layers the kernel fetches per side: the ``sweeps*halo``
+    window rounded up to the HBM granule so every DMA stays aligned."""
+    return tuple(_ceil_to(w, g) for w, g in zip(deep, grain))
+
+
+def fetch_window(tile: tuple[int, ...], deep: tuple[int, ...],
+                 itemsize: int) -> tuple[int, ...]:
+    """Extents of the VMEM buffer one grid step DMAs in: the tile plus
+    the aligned fetch halo on both sides."""
+    lo = fetch_halo(deep, fetch_grain(len(tile), itemsize))
+    return tuple(t + 2 * w for t, w in zip(tile, lo))
+
+
 def tile_window(tile: tuple[int, ...], halo: tuple[int, ...],
                 sweeps: int = 1) -> tuple[int, ...]:
     """Fetched input-window extents of one fused block: ``tile +
@@ -406,32 +321,44 @@ def tile_window(tile: tuple[int, ...], halo: tuple[int, ...],
     return tuple(t + 2 * sweeps * h for t, h in zip(tile, halo))
 
 
-def vmem_residency(tile: tuple[int, ...], halo: tuple[int, ...],
-                   sweeps: int = 1, itemsize: int = 4, n_terms: int = 1,
-                   *, boundary_mode: str = "zero",
-                   shape: tuple[int, ...] | None = None,
-                   periodic_budget_bytes: int | None = None) -> int:
-    """Bytes resident in VMEM during one grid step of the fused kernel:
-    the fetched window, a same-size accumulator, one live window-sized
-    intermediate per extra factored term, and the output block.
+def pad_free_fetch(shape: tuple[int, ...], tile: tuple[int, ...],
+                   deep: tuple[int, ...], itemsize: int) -> bool:
+    """Whether the pad-free kernel can fetch every window straight from
+    the unpadded grid: each extent a multiple of its tile, and each tile
+    at least its aligned fetch depth, so no wrapped ghost slab straddles
+    the grid edge.  ``plan.ghost_strategy_for`` decides on it."""
+    lo = fetch_halo(deep, fetch_grain(len(tile), itemsize))
+    return all(n % t == 0 and t >= f for n, t, f in zip(shape, tile, lo))
 
-    A periodic pad-free kernel additionally keeps the *whole grid* as
-    its input block (the wrap gather must address the far edge), so when
-    ``boundary_mode == "periodic"`` and the grid fits the pad-free
-    budget the grid block is charged too — previously the cost model
-    silently omitted it (found by the plan verifier's first full-matrix
-    run; see tests/test_analysis.py)."""
+
+def vmem_residency(tile: tuple[int, ...], halo: tuple[int, ...],
+                   sweeps: int = 1, itemsize: int = 4,
+                   n_terms: int = 1) -> int:
+    """Bytes resident in VMEM during one grid step of the fused kernel:
+    the aligned DMA buffer (:func:`fetch_window`), the window and a
+    same-size accumulator at the accumulation width, one live
+    window-sized intermediate per extra factored term, and the
+    double-buffered output block."""
     acc_itemsize = max(itemsize, 4)
+    deep = tuple(sweeps * h for h in halo)
     window = math.prod(tile_window(tile, halo, sweeps))
-    vmem = ((1 + n_terms) * window * acc_itemsize
-            + math.prod(tile) * itemsize)
-    if boundary_mode == "periodic" and shape is not None:
-        budget = (PERIODIC_WHOLE_GRID_BYTES if periodic_budget_bytes is None
-                  else periodic_budget_bytes)
-        grid_bytes = math.prod(shape) * itemsize
-        if grid_bytes <= budget:        # pad-free: whole grid is resident
-            vmem += grid_bytes
-    return vmem
+    fetch = math.prod(fetch_window(tile, deep, itemsize))
+    return (fetch * itemsize + (1 + n_terms) * window * acc_itemsize
+            + 2 * math.prod(tile) * itemsize)
+
+
+def _window_traffic(shape: tuple[int, ...], tile: tuple[int, ...],
+                    deep: tuple[int, ...], itemsize: int) -> int:
+    """HBM bytes of one fused call: every tile reads its aligned fetch
+    window and writes its tile; the padded-window fallback also reads
+    the grid once and writes its padded copy once."""
+    n_tiles = math.prod(-(-n // t) for n, t in zip(shape, tile))
+    traffic = n_tiles * (math.prod(fetch_window(tile, deep, itemsize))
+                         + math.prod(tile)) * itemsize
+    if not pad_free_fetch(shape, tile, deep, itemsize):
+        traffic += (math.prod(shape) + math.prod(
+            n + 2 * w for n, w in zip(shape, deep))) * itemsize
+    return traffic
 
 
 def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
@@ -472,17 +399,15 @@ def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
     terms = spec.factorization.compute_terms
     n_terms = 1 if terms is None else len(terms)
 
-    window = math.prod(tile_window(tile, halo, sweeps))
-    # Resident set: fetched window + same-size accumulator + output block,
-    # plus one live window-sized intermediate per extra factored term —
-    # and the whole grid when a periodic pad-free wrap gather holds it.
-    vmem = vmem_residency(tile, halo, sweeps, itemsize, n_terms,
-                          boundary_mode=spec.boundary_mode, shape=shape)
+    # Resident set: DMA buffer + window + accumulator + output block,
+    # plus one live window-sized intermediate per extra factored term.
+    vmem = vmem_residency(tile, halo, sweeps, itemsize, n_terms)
     if vmem > TPU_VMEM_BYTES:
         return float("inf")
 
-    traffic = n_tiles * (window + math.prod(tile)) * itemsize
-    t_mem = traffic / _cal("tpu_hbm_bw", TPU_HBM_BW)
+    traffic = _window_traffic(shape, tile, tuple(sweeps * h for h in halo),
+                              itemsize)
+    t_mem = traffic / TPU_HBM_BW
 
     def padded_points(layers: int) -> int:
         dims = [t + 2 * layers * h for t, h in zip(tile, halo)]
@@ -498,9 +423,8 @@ def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
         # one elementwise gather pass per axis per intermediate window
         flops += sum(padded_points(sweeps - 1 - s) * len(tile)
                      for s in range(sweeps - 1)) * n_tiles
-    t_compute = flops / _cal("tpu_vpu_flops_f32", TPU_VPU_FLOPS_F32)
-    return (max(t_mem, t_compute)
-            + n_tiles * _cal("tpu_grid_step_s", TPU_GRID_STEP_S))
+    t_compute = flops / TPU_VPU_FLOPS_F32
+    return max(t_mem, t_compute) + n_tiles * TPU_GRID_STEP_S
 
 
 def pallas_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
@@ -528,14 +452,13 @@ def pallas_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
         (1 if s.factorization.compute_terms is None
          else len(s.factorization.compute_terms)) for s in stages)
 
-    window = math.prod(tile_window(tile, big_halo, sweeps))
-    vmem = vmem_residency(tile, big_halo, sweeps, itemsize, max_terms,
-                          boundary_mode=pipeline.boundary_mode, shape=shape)
+    vmem = vmem_residency(tile, big_halo, sweeps, itemsize, max_terms)
     if vmem > TPU_VMEM_BYTES:
         return float("inf")
 
-    traffic = n_tiles * (window + math.prod(tile)) * itemsize
-    t_mem = traffic / _cal("tpu_hbm_bw", TPU_HBM_BW)
+    traffic = _window_traffic(shape, tile,
+                              tuple(sweeps * h for h in big_halo), itemsize)
+    t_mem = traffic / TPU_HBM_BW
 
     def padded_points(rem: tuple[int, ...]) -> int:
         dims = [t + 2 * r for t, r in zip(tile, rem)]
@@ -558,137 +481,8 @@ def pallas_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
             if (step < total
                     and stages[(k + 1) % n].boundary_mode == "reflect"):
                 flops += pts * len(tile)
-    t_compute = flops * n_tiles / _cal("tpu_vpu_flops_f32",
-                                       TPU_VPU_FLOPS_F32)
-    return (max(t_mem, t_compute)
-            + n_tiles * _cal("tpu_grid_step_s", TPU_GRID_STEP_S))
-
-
-# ----------------------------------------------------------------------------
-# Triton (GPU) tile cost: the backend="triton" autotuner ranking
-# ----------------------------------------------------------------------------
-def _gpu_padded_points(dims: list[int]) -> int:
-    """Points computed for a window, padded to the warp coalescing grain
-    on the innermost axis only — the GPU has no sublane constraint, but
-    a partial final warp still occupies a whole one."""
-    dims = list(dims)
-    dims[-1] = _ceil_to(dims[-1], WARP_LANES)
-    return math.prod(dims)
-
-
-def _gpu_terms(n_tiles: int, traffic: int, flops: float,
-               itemsize: int) -> float:
-    """Shared tail of the triton cost: occupancy-scaled bandwidth,
-    dtype-matched peak flops, launch floor + per-CTA sequencing."""
-    # One CTA per tile: below ~2 resident CTAs per SM the memory system
-    # cannot be kept saturated, so effective bandwidth scales with the
-    # achieved parallelism.  This is the GPU-shaped pressure *against*
-    # huge tiles, opposing the per-CTA overhead that favors them.  The
-    # SM count is calibratable: a host that executes CTAs serially (the
-    # CPU interpreter) fits gpu_n_sms < 1, saturating occupancy at one
-    # tile and letting the measured per-CTA slope carry the ranking.
-    occupancy = min(1.0, n_tiles / (2.0 * _cal("gpu_n_sms", GPU_N_SMS)))
-    t_mem = traffic / (_cal("gpu_bw", GPU_BW) * occupancy)
-    peak = (_cal("gpu_peak_flops_f32", GPU_PEAK_FLOPS_F32)
-            if itemsize <= 4 else GPU_PEAK_FLOPS)
-    t_compute = flops / peak
-    return (max(t_mem, t_compute) + _cal("gpu_launch_s", GPU_LAUNCH_S)
-            + n_tiles * _cal("gpu_cta_step_s", GPU_CTA_STEP_S))
-
-
-def triton_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
-                     tile: tuple[int, ...], sweeps: int = 1,
-                     itemsize: int = 4) -> float:
-    """Predicted seconds for ``sweeps`` fused applications under the
-    ``backend="triton"`` lowering with CTA tile ``tile`` — the GPU
-    sibling of :func:`pallas_tile_cost`, sharing its traffic arithmetic
-    (the kernels are literally the same bodies) but with GPU-shaped
-    resource terms:
-
-    * **shared-memory feasibility** replaces the VMEM bound: the fused
-      working set (window + accumulator + per-term intermediates +
-      output tile) must fit one SM's shared memory
-      (:data:`GPU_SMEM_BYTES`); the periodic whole-grid wrap block is
-      *not* charged here — on the GPU it streams through L2, bounded
-      separately by :data:`GPU_PERIODIC_WHOLE_GRID_BYTES` at
-      ghost-strategy selection time;
-    * compute pads to the **warp grain** (innermost multiple of 32),
-      not the TPU (8, 128) sublane x lane grain;
-    * the sequencing term is a one-off **kernel launch floor** plus a
-      per-CTA cost ~80x smaller than the TPU per-grid-step cost (CTAs
-      schedule concurrently across SMs), and effective bandwidth is
-      scaled by **occupancy** — too few CTAs cannot saturate HBM, which
-      is why the GPU candidate set favors many small warp-aligned tiles
-      over the TPU's few lane-aligned slabs.
-
-    Returns ``inf`` when the shared-memory working set cannot fit.
-    Bandwidth/overhead constants are env-overridable via
-    ``CASPER_CALIBRATION`` (see :func:`calibration`).
-    """
-    halo = spec.halo
-    n_tiles = math.prod(-(-n // t) for n, t in zip(shape, tile))
-    terms = spec.factorization.compute_terms
-    n_terms = 1 if terms is None else len(terms)
-
-    smem = vmem_residency(tile, halo, sweeps, itemsize, n_terms,
-                          boundary_mode=spec.boundary_mode, shape=None)
-    if smem > GPU_SMEM_BYTES:
-        return float("inf")
-
-    window = math.prod(tile_window(tile, halo, sweeps))
-    traffic = n_tiles * (window + math.prod(tile)) * itemsize
-
-    flops = sum(
-        _gpu_padded_points([t + 2 * (sweeps - 1 - s) * h
-                            for t, h in zip(tile, halo)])
-        * spec.structured_flops_per_point()
-        for s in range(sweeps)) * n_tiles
-    if spec.boundary_mode == "reflect":
-        flops += sum(
-            _gpu_padded_points([t + 2 * (sweeps - 1 - s) * h
-                                for t, h in zip(tile, halo)])
-            * len(tile) for s in range(sweeps - 1)) * n_tiles
-    return _gpu_terms(n_tiles, traffic, flops, itemsize)
-
-
-def triton_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
-                              tile: tuple[int, ...], sweeps: int = 1,
-                              itemsize: int = 4) -> float:
-    """:func:`triton_tile_cost` generalized to a fused
-    :class:`~repro.core.stencil.StencilPipeline` chain — the GPU sibling
-    of :func:`pallas_pipeline_tile_cost`, walking the same exact
-    element-layer stage schedule with the GPU resource terms."""
-    stages = pipeline.stages
-    big_halo = pipeline.halo
-    n_tiles = math.prod(-(-n // t) for n, t in zip(shape, tile))
-    max_terms = max(
-        (1 if s.factorization.compute_terms is None
-         else len(s.factorization.compute_terms)) for s in stages)
-
-    smem = vmem_residency(tile, big_halo, sweeps, itemsize, max_terms,
-                          boundary_mode=pipeline.boundary_mode, shape=None)
-    if smem > GPU_SMEM_BYTES:
-        return float("inf")
-
-    window = math.prod(tile_window(tile, big_halo, sweeps))
-    traffic = n_tiles * (window + math.prod(tile)) * itemsize
-
-    n = len(stages)
-    total = sweeps * n
-    rem = tuple(sweeps * h for h in big_halo)
-    flops = 0.0
-    step = 0
-    for _ in range(sweeps):
-        for k, stage in enumerate(stages):
-            rem = tuple(r - h for r, h in zip(rem, stage.halo))
-            pts = _gpu_padded_points(
-                [t + 2 * r for t, r in zip(tile, rem)])
-            flops += pts * stage.structured_flops_per_point()
-            step += 1
-            if (step < total
-                    and stages[(k + 1) % n].boundary_mode == "reflect"):
-                flops += pts * len(tile)
-    return _gpu_terms(n_tiles, traffic, flops * n_tiles, itemsize)
+    t_compute = flops * n_tiles / TPU_VPU_FLOPS_F32
+    return max(t_mem, t_compute) + n_tiles * TPU_GRID_STEP_S
 
 
 # ----------------------------------------------------------------------------

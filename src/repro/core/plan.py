@@ -61,35 +61,35 @@ from .isa import assemble, assemble_pipeline
 from .stencil import (Factorization, StencilPipeline, StencilSpec, as_stages,
                       factor_taps)
 
-Backend = Literal["ref", "pallas", "vm", "triton"]
+Backend = Literal["ref", "pallas", "vm"]
 
 #: The execution layers a plan can target.  ``"ref"`` is the jnp oracle
 #: chain (the numpy oracle shares its pinned order), ``"pallas"`` the
-#: fused TPU (mosaic) kernel, ``"triton"`` the same fused kernels under
-#: the pallas *triton* GPU lowering (interpret mode on CPU hosts — the
-#: whole correctness matrix runs in CI), ``"vm"`` the software SPU.  A
+#: fused TPU (Mosaic) kernel — interpret mode on CPU hosts, so the whole
+#: correctness matrix runs in CI — and ``"vm"`` the software SPU.  A
 #: plan with a mesh fingerprint executes through the distributed halo
-#: path with shard-local ``ref``/``pallas``/``triton`` compute.
-BACKENDS = ("ref", "pallas", "vm", "triton")
+#: path with shard-local ``ref``/``pallas`` compute.
+BACKENDS = ("ref", "pallas", "vm")
 
 #: The backends that lower to fused pallas kernels (and therefore carry
 #: a resolved tile, a ghost strategy chosen by :func:`ghost_strategy_for`
-#: and a VMEM/shared-memory feasibility bound).  Everything tile-shaped
-#: branches on this tuple, not on ``== "pallas"``.
-KERNEL_BACKENDS = ("pallas", "triton")
+#: and a VMEM feasibility bound).
+KERNEL_BACKENDS = ("pallas",)
 
 #: Boundary-ghost strategies a plan can select (the *decision* lives
 #: here; the mechanics stay with their backend):
 #:
 #: * ``"pad"``          — oracle path: re-extend with ``ref.pad_boundary``
 #:                        before every application;
-#: * ``"pad-free"``     — Pallas: clamped element BlockSpec on the
-#:                        unpadded grid + in-kernel ghost materialization;
+#: * ``"pad-free"``     — Pallas: each tile's window is DMA'd straight
+#:                        from the unpadded grid + in-kernel ghost
+#:                        restoration;
 #: * ``"padded-window"`` — Pallas fallback: fetch windows from one
-#:                        ``pad_boundary`` copy (tiny grids; periodic
-#:                        grids past the whole-grid VMEM budget; and the
-#:                        distributed shard-local kernel, whose window is
-#:                        the exchanged halo);
+#:                        ``pad_boundary`` copy (grids that are not a
+#:                        multiple of the tile or tiles shallower than
+#:                        the fetch depth; and the distributed
+#:                        shard-local kernel, whose window is the
+#:                        exchanged halo);
 #: * ``"stream"``       — SPU VM: ghost stream elements served per mode
 #:                        at access time;
 #: * ``"staged"``       — non-fusable pipelines only: execute the chain
@@ -112,73 +112,38 @@ GHOST_STRATEGIES = ("pad", "pad-free", "padded-window", "stream", "staged",
 #: the reflect mirror).
 EXCHANGE_STRATEGIES = ("zero-fill", "wrap-ring", "edge-fixup")
 
-# Default output tiles per rank: innermost dim 128-aligned for the VPU
-# lane width, sublane-sized second-minor (see /opt guides; validated in
-# interpret mode on CPU).  This is the lowering-time default when no
-# tile is requested; ``repro.kernels.engine`` re-exports it.
+# Default output tiles per rank: every extent a multiple of the HBM
+# layout's granule (``perfmodel.fetch_grain``: 1024 words in rank 1,
+# (8, 128) sublanes x lanes in rank >= 2), so the kernel's DMAs stay
+# aligned.  This is the lowering-time default when no tile is
+# requested; ``repro.kernels.engine`` re-exports it.
 DEFAULT_TILES: dict[int, tuple[int, ...]] = {
-    1: (512,),
+    1: (1024,),
     2: (32, 256),
     3: (4, 16, 128),
 }
 
-# GPU (triton) defaults: one CTA per tile, innermost dim warp-aligned
-# (multiples of 32 coalesce; no 8x128 sublane constraint), sized so the
-# fused working set sits comfortably inside one SM's shared memory while
-# still launching enough CTAs to occupy the SMs — the cost-model terms
-# of ``perfmodel.triton_tile_cost``.  ``repro.kernels.gpu`` re-exports
-# this as its default.
-DEFAULT_GPU_TILES: dict[int, tuple[int, ...]] = {
-    1: (1024,),
-    2: (32, 64),
-    3: (4, 8, 64),
-}
 
-
-def default_tile(ndim: int, backend: str = "pallas") -> tuple[int, ...]:
-    if backend == "triton":
-        return DEFAULT_GPU_TILES[ndim]
+def default_tile(ndim: int) -> tuple[int, ...]:
     return DEFAULT_TILES[ndim]
 
 
-def resolve_interpret(interpret: bool | None,
-                      backend: str = "pallas") -> bool:
-    """``None`` → backend-aware auto-detect; an explicit bool passes
-    through.  This is the one encoding of the policy —
-    ``repro.core.engine`` and ``repro.kernels.engine`` re-export it.
-
-    * Any kernel backend on a **CPU** host resolves to interpret mode
-      (pallas kernels need real hardware; CPU runs the interpreter).
-    * ``backend="triton"`` compiles only where a GPU exists: on a
-      **GPU** host it resolves to compiled mode, and on a **TPU** host
-      it raises a clear lowering-time error (the triton lowering cannot
-      target TPUs — asking pallas to try would surface as an opaque
-      mosaic traceback deep inside the first kernel call).
-    * ``backend="pallas"`` keeps the original rule: interpret exactly
-      when the default jax backend is CPU.
-    """
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` → interpret mode exactly when the default jax backend is
+    the CPU (pallas kernels need the chip; the CPU runs the
+    interpreter); an explicit bool passes through.  This is the one
+    encoding of the policy — ``repro.core.engine`` and
+    ``repro.kernels.engine`` re-export it."""
     if interpret is not None:
         return interpret
-    host = jax.default_backend()
-    if host == "cpu":
-        return True
-    if backend == "triton" and host != "gpu":
-        raise ValueError(
-            f"backend='triton' cannot lower on a {host!r} host: the "
-            "pallas triton path targets GPUs only. Run on a GPU host, "
-            "or pass interpret=True to run the kernels in interpret "
-            "mode (what CI does on CPU).")
-    return False
+    return jax.default_backend() == "cpu"
 
 
 def normalize_tile(spec: StencilSpec,
-                   tile: Sequence[int] | int | None,
-                   backend: str = "pallas") -> tuple[int, ...]:
-    """Default / int-promote / validate a tile for ``spec``; the default
-    table is backend-shaped (lane-aligned TPU tiles vs warp-aligned GPU
-    tiles)."""
+                   tile: Sequence[int] | int | None) -> tuple[int, ...]:
+    """Default / int-promote / validate a tile for ``spec``."""
     if tile is None:
-        tile = default_tile(spec.ndim, backend)
+        tile = default_tile(spec.ndim)
     elif isinstance(tile, int):
         tile = (tile,)
     tile = tuple(int(t) for t in tile)
@@ -208,48 +173,28 @@ def exchange_strategy_for(mode: str) -> str:
 
 def ghost_strategy_for(spec: StencilSpec, shape: Sequence[int],
                        itemsize: int, sweeps: int,
-                       tile: Sequence[int] | int | None,
-                       *, periodic_budget_bytes: int | None = None,
-                       backend: str = "pallas") -> str:
+                       tile: Sequence[int] | int | None) -> str:
     """Pad-free vs padded-window decision for the single-device kernel
-    backends — previously an ad-hoc branch inside ``kernels.engine``.
+    backends.
 
-    The pad-free kernel's clamped fetch needs ``window <= grid`` per dim
-    (tiny grids fall back), and its periodic wrap gather blocks the
-    *whole* grid (the far edge must be addressable), which is only sane
-    while the grid sits comfortably next to the working set — inside
-    VMEM on the TPU path, inside L2 on the GPU path
-    (``periodic_budget_bytes``; when omitted the backend's configured
-    budget is consulted: ``kernels.engine._PERIODIC_WHOLE_GRID_BYTES``
-    for ``"pallas"``, ``kernels.gpu._PERIODIC_WHOLE_GRID_BYTES`` for
-    ``"triton"``).  Both fallbacks produce bitwise-identical results
-    through the padded window path.
+    The pad-free kernel DMAs each tile's window straight from the
+    unpadded grid, with the ghost slabs wrapped around the grid edge
+    (:func:`repro.kernels.engine._fetch_pieces`).  That needs every grid
+    extent to be a multiple of its tile, and every tile to be at least
+    the fetch depth — the ``sweeps*halo`` ghost layers rounded up to the
+    HBM granule (:func:`repro.core.perfmodel.fetch_halo`) — so that no
+    ghost slab straddles the wrap.  Anything else falls back to the
+    padded window, which produces bitwise-identical results.
 
     Also accepts a fusable :class:`~repro.core.stencil.StencilPipeline`:
-    its ``halo`` is the per-dim sum of stage radii and its
-    ``boundary_mode`` is ``"periodic"`` exactly when every stage is
-    periodic (the only fusable periodic case), so the same decision rule
+    its ``halo`` is the per-dim sum of stage radii, so the same rule
     applies verbatim to the chain's widened window.
     """
-    import math
-    tile = normalize_tile(spec, tile, backend)
-    shape = tuple(shape)
-    wide = tuple(sweeps * h for h in spec.halo)
-    win = tuple(t + 2 * w for t, w in zip(tile, wide))
-    if spec.boundary_mode == "periodic":
-        if periodic_budget_bytes is None:
-            if backend == "triton":
-                from repro.kernels import gpu as _kgpu  # lazy: optional dep
-                periodic_budget_bytes = _kgpu._PERIODIC_WHOLE_GRID_BYTES
-            else:
-                from repro.kernels import engine as _keng  # lazy: optional
-                periodic_budget_bytes = _keng._PERIODIC_WHOLE_GRID_BYTES
-        grid_bytes = math.prod(shape) * itemsize
-        return ("padded-window" if grid_bytes > periodic_budget_bytes
-                else "pad-free")
-    if any(w > n for w, n in zip(win, shape)):
-        return "padded-window"
-    return "pad-free"
+    tile = normalize_tile(spec, tile)
+    deep = tuple(sweeps * h for h in spec.halo)
+    if _pm.pad_free_fetch(tuple(shape), tile, deep, itemsize):
+        return "pad-free"
+    return "padded-window"
 
 
 # ---------------------------------------------------------------------------
@@ -430,6 +375,12 @@ class PlanCache:
         with self._lock:
             return list(self._store)
 
+    def plans(self):
+        """Current plans, least- to most-recently used (no counter
+        moves)."""
+        with self._lock:
+            return list(self._store.values())
+
     def stats(self) -> dict:
         with self._lock:
             total = self.hits + self.misses
@@ -533,7 +484,7 @@ def lower(spec: StencilSpec, shape: Sequence[int], dtype, *,
         raise ValueError("mesh and grid_axes must be passed together")
     if grid_axes is not None and len(grid_axes) != spec.ndim:
         raise ValueError("grid_axes must have one entry per grid dim")
-    interp = resolve_interpret(interpret, backend)
+    interp = resolve_interpret(interpret)
     tile_req = canonical_tile_request(tile)
     axes = tuple(grid_axes) if grid_axes is not None else None
 
@@ -630,16 +581,16 @@ def _lower_pipeline_uncached(pipe, shape, dtype, backend, sweeps, tile_req,
             PLAN_CACHE.autotune_calls += 1
             resolved_tile = tune.autotune_pipeline(
                 pipe, tune_shape, sweeps=sweeps,
-                itemsize=dtype.itemsize, backend=backend).tile
+                itemsize=dtype.itemsize).tile
         else:
-            resolved_tile = normalize_tile(pipe, tile_req, backend)
+            resolved_tile = normalize_tile(pipe, tile_req)
         if mesh is not None:
             ghost = "padded-window"
         elif slabs is not None:
             ghost = "stream-from-host"
         else:
             ghost = ghost_strategy_for(pipe, shape, dtype.itemsize, sweeps,
-                                       resolved_tile, backend=backend)
+                                       resolved_tile)
     elif backend == "vm":
         ghost = "stream"
     elif slabs is not None:                 # fused ref chain, over budget
@@ -690,10 +641,9 @@ def _lower_uncached(spec, shape, dtype, backend, sweeps, tile_req, mesh,
             from repro.kernels import tune      # lazy: optional dep
             PLAN_CACHE.autotune_calls += 1
             resolved_tile = tune.autotune(spec, tune_shape, sweeps=sweeps,
-                                          itemsize=dtype.itemsize,
-                                          backend=backend).tile
+                                          itemsize=dtype.itemsize).tile
         else:
-            resolved_tile = normalize_tile(spec, tile_req, backend)
+            resolved_tile = normalize_tile(spec, tile_req)
         if mesh is not None:
             # the shard-local kernel always runs on the exchanged
             # (already ghost-extended) window
@@ -702,7 +652,7 @@ def _lower_uncached(spec, shape, dtype, backend, sweeps, tile_req, mesh,
             ghost = "stream-from-host"
         else:
             ghost = ghost_strategy_for(spec, shape, dtype.itemsize, sweeps,
-                                       resolved_tile, backend=backend)
+                                       resolved_tile)
     elif backend == "vm":
         ghost = "stream"
     elif slabs is not None:                     # ref oracle, over budget
@@ -747,9 +697,6 @@ def execute(plan: ExecutionPlan, grid):
     if plan.backend == "pallas":
         from repro.kernels import engine as _keng   # lazy: optional dep
         return _keng.execute_plan(plan, grid)
-    if plan.backend == "triton":
-        from repro.kernels import gpu as _kgpu      # lazy: optional dep
-        return _kgpu.execute_plan(plan, grid)
     if plan.backend == "vm":
         from . import vm as _vm
         return _vm.execute_plan(plan, grid)[0]
@@ -926,7 +873,7 @@ def batch_handle(spec: StencilSpec | StencilPipeline, backend: str,
     :func:`batch_runner` cache entry)."""
     return BatchHandle(spec, backend, sweeps,
                        canonical_tile_request(tile_req),
-                       resolve_interpret(interpret, backend))
+                       resolve_interpret(interpret))
 
 
 def runner_cache_stats() -> dict:

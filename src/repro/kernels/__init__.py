@@ -9,7 +9,7 @@ through :class:`repro.core.engine.CasperEngine`, or lower an
 """
 import warnings
 
-from . import engine, gpu, ops, ref, stream, tune
+from . import engine, ops, ref, stream, tune
 from .engine import (stencil_apply, stencil_sweep, stencil_window_sweep,
                      run_sweeps, hbm_traffic, execute_plan)
 from .swa import sliding_window_attention, swa_ref
@@ -28,7 +28,7 @@ def _legacy_rank_shim(rank: int, spec, grid, tile, interpret):
     return _plan.execute(p, grid)
 
 
-def stencil1d(spec, grid, tile: int = 512, interpret: bool | None = None):
+def stencil1d(spec, grid, tile: int = 1024, interpret: bool | None = None):
     """DEPRECATED compat shim for the seed's 1-D kernel (one sweep)."""
     return _legacy_rank_shim(1, spec, grid, (tile,), interpret)
 
@@ -43,7 +43,7 @@ def stencil3d(spec, grid, tile=(4, 16, 128), interpret: bool | None = None):
     return _legacy_rank_shim(3, spec, grid, tile, interpret)
 
 
-__all__ = ["engine", "gpu", "ops", "ref", "stream", "tune",
+__all__ = ["engine", "ops", "ref", "stream", "tune",
            "stencil_apply", "stencil_sweep", "stencil_window_sweep",
            "run_sweeps", "hbm_traffic", "execute_plan",
            "autotune", "autotune_measured",

@@ -7,10 +7,10 @@ parameterized by
 
 * **tile** — the VMEM output block owned by one grid step (the paper's
   "stencil segment block", §4.1);
-* **halo** — taken from the spec; the input window is fetched with
-  *element-offset* BlockSpecs (``pl.Element``), the software analogue of
-  Casper's unaligned-load hardware: one DMA returns the window spanning
-  cache-line boundaries;
+* **halo** — taken from the spec; the grid stays in HBM and each grid
+  step DMAs its window into VMEM, rounded out to the HBM layout's
+  (8, 128) granule and cut to the exact window in VMEM — the software
+  analogue of Casper's unaligned-load hardware;
 * **dtype** — accumulation runs in f32 for sub-f32 inputs and in the
   input dtype otherwise, so f64 results are bit-identical to the
   `core.ref` oracle;
@@ -36,20 +36,18 @@ counterparts).  Each is the closed form of the oracle re-padding before
 every sweep, so fused results stay f64 bit-identical to chained oracle
 applications under all four modes — see docs/boundaries.md.
 
-**Pad-free fused sweeps**: :func:`stencil_sweep` no longer materializes a
-boundary-padded copy of the whole grid per fused call.  The kernel's
-input window is fetched straight from the unpadded grid with a *clamped*
-element-offset BlockSpec, and the boundary ghosts are materialized
-*inside* the kernel from the mode's closed form — a shift-realign gather
-plus fill masking (zero/constant), the in-window mirror gather
-(reflect), or a per-axis wrap gather against global coordinates
-(periodic — whole grid as the block, for VMEM-sized grids).  The ghost
-values are bitwise identical to what ``ref.pad_boundary`` would have
-produced, so f64 parity with the oracle is untouched while the per-call
-``O(grid)`` pad read+write round-trip disappears (:func:`hbm_traffic`
-now charges it to the unfused baseline only).  Grids smaller than one
-fetch window, and periodic grids past the whole-grid VMEM budget, fall
-back to the legacy padded path.
+**Pad-free fused sweeps**: :func:`stencil_sweep` does not materialize a
+boundary-padded copy of the whole grid per fused call.  Each tile's
+window is DMA'd straight from the unpadded grid, its ghost slabs taken
+from the far side of the grid (:func:`_fetch_pieces`) — exactly the
+periodic extension — and the kernel then overwrites the ghosts of the
+other modes from their closed form: fill masking (zero/constant) or the
+static-shift mirror (reflect).  The ghost values are bitwise identical
+to what ``ref.pad_boundary`` would have produced, so f64 parity with the
+oracle is untouched while the per-call ``O(grid)`` pad read+write
+round-trip disappears (:func:`hbm_traffic` charges it to the unfused
+baseline only).  Grids that are not a multiple of the tile, or tiles
+shallower than the aligned fetch depth, fall back to the padded path.
 
 **Structure specialization**: per-application compute inside the kernel
 dispatches on ``spec.structure`` (star / separable / dense — see
@@ -59,21 +57,23 @@ dispatches on ``spec.structure`` (star / separable / dense — see
 ``O(sum)`` instead of ``O(prod)`` tap temporaries, bit-identically to
 the oracle in f64.
 
-A leading batch dimension is handled by `vmap` (see
-:func:`stencil_apply`), so a stack of independent grids shares one
-compiled kernel.  ``interpret=None`` (the default everywhere) resolves
+A leading batch dimension (``vmap``, see :func:`stencil_apply`) becomes
+a leading grid axis of the same kernel, so a stack of independent grids
+shares one compiled kernel.  ``interpret=None`` (the default everywhere) resolves
 to interpret mode exactly when the backend is CPU, so TPU users get
 compiled kernels without passing a flag.
 """
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from typing import Sequence
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import plan as _plan
 from repro.core import ref as _ref
@@ -87,28 +87,6 @@ DEFAULT_TILES = _plan.DEFAULT_TILES
 default_tile = _plan.default_tile
 _normalize_tile = _plan.normalize_tile
 
-# The pad-free periodic path makes the whole (unpadded) grid the input
-# block — the wrap gather needs the far edge — which is only sane while
-# the grid comfortably fits the VMEM working set next to the window and
-# intermediates; larger periodic grids keep the wrap-padded fallback
-# (window-sized fetches, matching the hbm_traffic/pallas_tile_cost
-# window model).  The *decision* consuming this budget is
-# ``repro.core.plan.ghost_strategy_for``; this module attribute remains
-# the configurable knob (read at call time, so tests can patch it).
-# The canonical default lives in perfmodel so the cost model's
-# VMEM-residency accounting and the verifier share one number.
-_PERIODIC_WHOLE_GRID_BYTES = _pm.PERIODIC_WHOLE_GRID_BYTES
-
-
-def element_blockspec(block_shape, index_map) -> pl.BlockSpec:
-    """Element-offset BlockSpec across jax versions: jax>=0.5 spells it
-    ``pl.Element`` per dim, jax<0.5 as the ``unblocked`` indexing mode."""
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec(tuple(pl.Element(b) for b in block_shape),
-                            index_map)
-    return pl.BlockSpec(tuple(block_shape), index_map,
-                        indexing_mode=pl.unblocked)
-
 
 def _acc_dtype(dtype) -> jnp.dtype:
     """f32 accumulation for narrow inputs; native otherwise (f64 exact)."""
@@ -117,129 +95,182 @@ def _acc_dtype(dtype) -> jnp.dtype:
     return jnp.dtype(dtype)
 
 
-def _lowering_backend(lowering: str | None) -> str:
-    """Map a pallas ``lowering`` request to its plan backend name."""
-    return "triton" if lowering == "triton" else "pallas"
+def _fetch_pieces(i, tile: int, n: int, lo: int, ext: int, wrap: bool):
+    """The DMA pieces ``(dst offset, size, src start)`` that fill one
+    dim of tile ``i``'s ``ext``-long buffer.
+
+    ``wrap`` (pad-free): the source is the unpadded ``n``-point grid and
+    the buffer spans ``[i*tile - lo, (i+1)*tile + lo)``; the two ghost
+    slabs come from ``(start mod n)``, so a tile at the edge fetches the
+    far side of the grid — exactly the periodic extension, and data the
+    ghost restoration overwrites for the other modes.  Otherwise the
+    source is a window that already carries its ghost layers, and one
+    piece from ``i*tile`` covers the buffer."""
+    if not wrap:
+        return ((0, ext, i * tile),)
+    if not lo:
+        return ((0, tile, i * tile),)
+    return ((0, lo, (i * tile - lo + n) % n),
+            (lo, tile, i * tile),
+            (lo + tile, lo, (i * tile + tile) % n))
 
 
-def _call_kwargs(lowering: str | None, interpret: bool,
-                 tile: Sequence[int]) -> dict:
-    """Extra ``pallas_call`` kwargs selecting a non-default lowering.
+def _fused_kernel(org_ref, src_ref, o_ref, buf, sem, *, core, tile, wide,
+                  lo, cut, grain, grid_shape, wrap, fix, batched):
+    """One grid step of every fused kernel: DMA the tile's aligned
+    window from HBM into VMEM, cut the ``sweeps*halo`` window out of it,
+    restore its boundary ghosts (pad-free fetches only; a padded source
+    already carries them) and run ``core`` — the shared multi-sweep
+    (or fused-chain) body — on it.
 
-    ``lowering="triton"`` routes the *same* kernel bodies through the
-    pallas triton (GPU) lowering instead of mosaic — f64 bit-identity
-    with the oracle holds by construction because the traced computation
-    is unchanged.  Interpret mode still tags the call with the triton
-    backend (the pallas interpreter accepts it, so the whole matrix runs
-    on CPU CI); compiled mode additionally attaches
-    ``TritonCompilerParams`` with a warp count scaled to the tile so one
-    CTA's lanes cover the innermost (coalescing) dimension.
-    """
-    if lowering is None:
-        return {}
-    if lowering != "triton":
-        raise ValueError(f"unknown pallas lowering {lowering!r}")
-    kwargs: dict = {"backend": "triton"}
-    if not interpret:
-        from jax.experimental.pallas import triton as _plt
-        num_warps = max(1, min(8, math.prod(tile) // (4 * _pm.WARP_LANES)))
-        kwargs["compiler_params"] = _plt.TritonCompilerParams(
-            num_warps=num_warps, num_stages=2)
-    return kwargs
-
-
-def _kernel(x_ref, org_ref, o_ref, *, taps, halo, tile, sweeps, grid_shape,
-            acc_dtype, mode, value, structure):
-    """Apply ``sweeps`` fused stencil applications to one resident window.
-
-    The window enters with ``sweeps`` halo layers per side; the masked
-    multi-sweep core (:func:`repro.core.ref.masked_window_sweeps`)
-    consumes one layer per application and restores intermediates that
-    fall outside the true grid to the boundary extension for ``mode``
-    (which, for the fill modes, also kills values leaking in from the
-    tile-alignment pad).  ``org_ref`` holds the global coordinate of
-    the whole window-call's interior origin — zeros for a single-device
-    grid, the shard offset in the distributed path — so the ghost
-    restoration uses *global* coordinates.  ref.tap_sum (inside the
-    core) pins the f64 accumulation order, keeping the engine
-    bit-identical to the oracle in the validation dtype.
+    ``org_ref`` (SMEM) holds the global coordinate of the call's
+    interior origin: zeros on one device, the shard offset in the mesh
+    path, so ghost restoration always works on *global* coordinates.
+    A ``batched`` call walks a leading grid axis over the source's
+    leading batch dim.
     """
     ndim = len(tile)
-    starts = tuple(org_ref[d] + pl.program_id(d) * tile[d]
-                   for d in range(ndim))
-    o_ref[...] = _ref.masked_window_sweeps(
-        x_ref[...], taps, halo, tile, sweeps, starts, grid_shape,
-        acc_dtype, mode=mode, value=value,
-        structure=structure).astype(o_ref.dtype)
+    lead = (pl.program_id(0),) if batched else ()
+    ids = tuple(pl.program_id(d + len(lead)) for d in range(ndim))
+    per_dim = [_fetch_pieces(ids[d], tile[d], grid_shape[d], lo[d],
+                             buf.shape[d], wrap) for d in range(ndim)]
+    copies = []
+    for k, combo in enumerate(itertools.product(*per_dim)):
+        src = lead + tuple(
+            pl.ds(st if g == 1 else pl.multiple_of(st, g), size)
+            for (_, size, st), g in zip(combo, grain))
+        dst = tuple(pl.ds(off, size) for off, size, _ in combo)
+        copies.append(pltpu.make_async_copy(src_ref.at[src], buf.at[dst],
+                                            sem.at[k]))
+    for cp in copies:
+        cp.start()
+    for cp in copies:
+        cp.wait()
+    x = jax.lax.slice(buf[...], cut,
+                      tuple(c + t + 2 * w for c, t, w in zip(cut, tile, wide)))
+    starts = tuple(org_ref[d] + ids[d] * tile[d] for d in range(ndim))
+    if fix is not None:
+        mode, value = fix
+        x = _ref._restore_ghosts(x, mode, value,
+                                 tuple(s - w for s, w in zip(starts, wide)),
+                                 grid_shape, wide)
+    o_ref[...] = core(x, starts).astype(o_ref.dtype)
 
 
-def _materialize_window(x, s_true, win, grid_shape, mode, value):
-    """In-kernel ghost materialization for the pad-free fetch: turn the
-    fetched block ``x`` into the window spanning global coordinates
-    ``[s_true[d], s_true[d]+win[d])`` per dim with out-of-grid positions
-    holding the boundary ``mode``'s extension — bitwise what
-    ``ref.pad_boundary`` would have put there.
+def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
+                grid_shape: Sequence[int], tile: Sequence[int],
+                wide: Sequence[int], *, fix, interpret: bool) -> jax.Array:
+    """The one ``pallas_call`` emitter behind every fused kernel.
 
-    Fill/mirror modes arrive as a clamped fetch (the BlockSpec start was
-    clipped into the grid): a per-axis realign gather restores window
-    alignment, then ghosts take the fill value (zero/constant) or the
-    in-window mirror source (reflect).  Periodic arrives as the *whole*
-    unpadded grid and the window is assembled by a per-axis wrap gather
-    ``grid[(g0 + j) mod N]`` — the exact periodic extension at any depth.
-    Shared by the single-spec and pipeline pad-free kernels.
-    """
-    ndim = len(win)
-    if mode == "periodic":
-        for d in range(ndim):
-            idx = (s_true[d] + jnp.arange(win[d], dtype=jnp.int32)) \
-                % grid_shape[d]
-            x = jnp.take(x, idx, axis=d)
-        return x
-    for d in range(ndim):
-        s_clip = jnp.clip(s_true[d], 0, grid_shape[d] - win[d])
-        idx = jnp.clip(s_true[d] - s_clip
-                       + jnp.arange(win[d], dtype=jnp.int32),
-                       0, win[d] - 1)
-        x = jnp.take(x, idx, axis=d)
-    if mode in ("zero", "constant"):
-        valid = None
-        for d in range(ndim):
-            g = s_true[d] + jax.lax.broadcasted_iota(jnp.int32, win, d)
-            vd = (g >= 0) & (g < grid_shape[d])
-            valid = vd if valid is None else valid & vd
-        fill = jnp.asarray(value if mode == "constant" else 0.0, x.dtype)
-        return jnp.where(valid, x, fill)
-    for d in range(ndim):                       # reflect
-        x = _ref.reflect_gather(x, d, s_true[d], grid_shape[d], win[d])
-    return x
+    The source stays in HBM (``memory_space=pl.ANY``) and each grid
+    step DMAs its window into a VMEM buffer rounded out to the HBM
+    layout's granule (:func:`repro.core.perfmodel.fetch_grain`): Mosaic
+    only copies whole (8, 128) tiles, so the window is fetched aligned
+    and the exact window is cut out of it in VMEM.
 
-
-def _padfree_kernel(x_ref, o_ref, *, taps, halo, tile, sweeps, grid_shape,
-                    acc_dtype, mode, value, structure):
-    """Pad-free variant: the fetched block comes straight from the
-    *unpadded* grid, and this kernel materializes the window's boundary
-    ghosts itself from the mode's closed form.
-
-    For the fill/mirror modes the BlockSpec start was clamped into the
-    grid, so the fetch holds the right elements at a (per-tile) shifted
-    position: a per-axis realign gather restores window alignment, then
-    out-of-grid positions are overwritten with the fill value
-    (zero/constant) or the in-window mirror source (reflect) — bitwise
-    what ``ref.pad_boundary`` would have put there.  For periodic the
-    whole (unpadded) grid is the block and the window is assembled by a
-    per-axis wrap gather ``grid[(g0 + j) mod N]`` — the exact periodic
-    extension at any depth.  The multi-sweep core then runs unchanged.
+    ``fix=(mode, value)`` selects the **pad-free** fetch: ``src`` is the
+    unpadded grid (``out_shape == grid_shape``, every extent a multiple
+    of its tile, every tile at least the fetch depth), the buffer
+    carries the ``sweeps*halo`` ghost depth rounded up to the granule on
+    both sides, and the kernel restores the boundary ghosts itself.
+    ``fix=None`` takes ``src`` as a window that already carries ``wide``
+    ghost layers per side (the padded-window fallback and the mesh
+    path's exchanged block): tile ``i``'s window starts at ``i*tile``,
+    and only the buffer's far end is rounded up.
     """
     ndim = len(tile)
-    wide = tuple(sweeps * h for h in halo)
-    win = tuple(t + 2 * w for t, w in zip(tile, wide))
-    s_true = tuple(pl.program_id(d) * tile[d] - wide[d] for d in range(ndim))
-    x = _materialize_window(x_ref[...], s_true, win, grid_shape, mode, value)
-    starts = tuple(pl.program_id(d) * tile[d] for d in range(ndim))
-    o_ref[...] = _ref.masked_window_sweeps(
-        x, taps, halo, tile, sweeps, starts, grid_shape,
-        acc_dtype, mode=mode, value=value,
-        structure=structure).astype(o_ref.dtype)
+    tile = tuple(tile)
+    wide = tuple(wide)
+    out_shape = tuple(out_shape)
+    grid_shape = tuple(int(n) for n in grid_shape)
+    grain = _pm.fetch_grain(ndim, src.dtype.itemsize)
+    lo = _pm.fetch_halo(wide, grain)
+    grid_dims = tuple(-(-n // t) for n, t in zip(out_shape, tile))
+    padded = tuple(g * t for g, t in zip(grid_dims, tile))
+    wrap = fix is not None
+    if wrap:
+        if (out_shape != grid_shape or padded != out_shape
+                or any(t < f for t, f in zip(tile, lo))):
+            raise ValueError(
+                f"pad-free fetch needs grid {out_shape} to be a multiple "
+                f"of tile {tile} with every tile >= its fetch depth {lo}")
+        ext = tuple(t + 2 * f for t, f in zip(tile, lo))
+        cut = tuple(f - w for f, w in zip(lo, wide))
+    else:
+        ext = tuple(-(-(t + 2 * w) // g) * g
+                    for t, w, g in zip(tile, wide, grain))
+        cut = (0,) * ndim
+        src = jnp.pad(src, [(0, p - t + e - n - 2 * w) for p, t, e, n, w
+                            in zip(padded, tile, ext, out_shape, wide)])
+    if not interpret and any(t % g for t, g in zip(tile, grain)):
+        raise ValueError(f"compiled tile {tile} is not a multiple of the "
+                         f"HBM granule {grain}")
+    n_copies = math.prod(len(_fetch_pieces(0, t, 1, f, e, wrap))
+                         for t, f, e in zip(tile, lo, ext))
+    scratch = [pltpu.VMEM(ext, src.dtype),
+               pltpu.SemaphoreType.DMA((n_copies,))]
+
+    def emit(org, src, batch):
+        kernel = functools.partial(
+            _fused_kernel, core=core, tile=tile, wide=wide, lo=lo, cut=cut,
+            grain=grain, grid_shape=grid_shape, wrap=wrap, fix=fix,
+            batched=bool(batch))
+        if batch:
+            out_spec = pl.BlockSpec((pl.Squeezed(),) + tile,
+                                    lambda b, *ids: (b,) + ids)
+        else:
+            out_spec = pl.BlockSpec(tile, lambda *ids: ids)
+        return pl.pallas_call(
+            kernel,
+            grid=batch + grid_dims,
+            in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=out_spec,
+            out_shape=jax.ShapeDtypeStruct(batch + padded, src.dtype),
+            scratch_shapes=scratch,
+            interpret=interpret,
+        )(org, src)
+
+    # A memory_space=ANY operand has no pallas batching rule, so a
+    # vmapped call (a serving bucket) walks the batch as a leading grid
+    # axis of the same kernel instead.  Rank 1 maps the kernel over the
+    # rows: a row of a 2-D array is laid out in (8, 128) tiles, which
+    # Mosaic cannot DMA into a 1-D buffer's 1024-word tiles.
+    @jax.custom_batching.custom_vmap
+    def call(org, src):
+        return emit(org, src, ())
+
+    @call.def_vmap
+    def _call_batched(axis_size, in_batched, org, src):
+        if in_batched[0]:
+            raise NotImplementedError("a batched origin is not supported")
+        if not in_batched[1]:
+            src = jnp.broadcast_to(src, (axis_size,) + src.shape)
+        if ndim == 1:
+            return jax.lax.map(lambda row: emit(org, row, ()), src), True
+        return emit(org, src, (axis_size,)), True
+
+    out = call(jnp.asarray(origin, jnp.int32).reshape(ndim), src)
+    if padded == out_shape:
+        return out
+    return out[tuple(slice(0, n) for n in out_shape)]
+
+
+def _spec_core(spec: StencilSpec, tile, sweeps, grid_shape, acc_dtype):
+    def core(x, starts):
+        return _ref.masked_window_sweeps(
+            x, tuple(spec.taps), spec.halo, tile, sweeps, starts,
+            grid_shape, acc_dtype, mode=spec.boundary_mode,
+            value=spec.boundary_value, structure=spec.structure)
+    return core
+
+
+def _pipeline_core(pipeline: StencilPipeline, tile, sweeps, grid_shape,
+                   acc_dtype):
+    def core(x, starts):
+        return _ref.masked_window_pipeline(
+            x, pipeline.stages, tile, sweeps, starts, grid_shape, acc_dtype)
+    return core
 
 
 def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
@@ -248,8 +279,7 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
                          grid_shape: Sequence[int],
                          tile: Sequence[int] | int | None = None,
                          sweeps: int = 1,
-                         interpret: bool | None = None,
-                         lowering: str | None = None) -> jax.Array:
+                         interpret: bool | None = None) -> jax.Array:
     """``sweeps`` fused applications to a block that already carries its
     ``sweeps*halo``-wide halo.
 
@@ -260,71 +290,51 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
     ``origin`` (static ints or a traced value, e.g. ``axis_index`` inside
     shard_map) of a ``grid_shape`` grid, against which the between-sweep
     ghost restoration is evaluated.  This is the shard-local entry point
-    of the distributed deep-halo path; :func:`stencil_sweep` wraps it for
-    the single-device case (zero origin, window = boundary-padded grid).
+    of the distributed deep-halo path; :func:`stencil_sweep` uses it for
+    the single-device padded-window fallback.
     """
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    interpret = resolve_interpret(interpret, _lowering_backend(lowering))
-    tile = _normalize_tile(spec, tile, _lowering_backend(lowering))
-    halo = spec.halo
+    interpret = resolve_interpret(interpret)
+    tile = _normalize_tile(spec, tile)
     out_shape = tuple(out_shape)
-    grid_shape = tuple(int(n) for n in grid_shape)
-    wide = tuple(sweeps * h for h in halo)          # fetched halo per side
+    wide = tuple(sweeps * h for h in spec.halo)
     want = tuple(n + 2 * w for n, w in zip(out_shape, wide))
     if window.shape != want:
         raise ValueError(
             f"window shape {window.shape} != out_shape + 2*sweeps*halo "
             f"{want}")
+    grid_shape = tuple(int(n) for n in grid_shape)
+    core = _spec_core(spec, tile, sweeps, grid_shape,
+                      _acc_dtype(window.dtype))
+    return _fused_call(core, window, out_shape, origin, grid_shape, tile,
+                       wide, fix=None, interpret=interpret)
 
-    pads = tuple(-n % t for n, t in zip(out_shape, tile))
-    xp = jnp.pad(window, [(0, p) for p in pads])
-    grid_dims = tuple((n + p) // t for n, p, t in zip(out_shape, pads, tile))
-    padded = tuple(n + p for n, p in zip(out_shape, pads))
-    org = jnp.asarray(origin, jnp.int32)
 
-    kernel = functools.partial(
-        _kernel, taps=tuple(spec.taps), halo=halo, tile=tile, sweeps=sweeps,
-        grid_shape=grid_shape, acc_dtype=_acc_dtype(window.dtype),
-        mode=spec.boundary_mode, value=spec.boundary_value,
-        structure=spec.structure)
-
-    def in_map(*ids):
-        return tuple(i * t for i, t in zip(ids, tile))
-
-    out = pl.pallas_call(
-        kernel,
-        grid=grid_dims,
-        in_specs=[element_blockspec(
-            tuple(t + 2 * w for t, w in zip(tile, wide)), in_map),
-            pl.BlockSpec((spec.ndim,), lambda *ids: (0,))],
-        out_specs=pl.BlockSpec(tile, lambda *ids: ids),
-        out_shape=jax.ShapeDtypeStruct(padded, window.dtype),
-        interpret=interpret,
-        **_call_kwargs(lowering, interpret, tile),
-    )(xp, org)
-    return out[tuple(slice(0, n) for n in out_shape)]
+def _resolve_strategy(spec, grid, sweeps, tile) -> str:
+    """Direct callers (no plan in hand) ask core.plan for the pad-free
+    vs padded-window decision; ``execute_plan`` passes the plan's."""
+    return _plan.ghost_strategy_for(spec, grid.shape, grid.dtype.itemsize,
+                                    sweeps, tile)
 
 
 def stencil_sweep(spec: StencilSpec, grid: jax.Array,
                   tile: Sequence[int] | int | None = None,
                   sweeps: int = 1,
                   interpret: bool | None = None,
-                  strategy: str | None = None,
-                  lowering: str | None = None) -> jax.Array:
+                  strategy: str | None = None) -> jax.Array:
     """``sweeps`` fused applications of ``spec`` to ``grid`` under the
     spec's boundary mode, **pad-free**: the kernel fetches its window
     straight from the unpadded grid and materializes boundary ghosts
-    in-kernel (see :func:`_padfree_kernel`), so no host-side padded copy
-    of the grid is built per fused call.
+    in-kernel (see :func:`_fused_kernel`), so no padded copy of the grid
+    is built per fused call.
 
     Equivalent to ``sweeps`` chained :func:`repro.core.ref.apply_stencil`
     calls, but with a single HBM read/write per point instead of one per
     sweep.  ``grid`` rank must equal ``spec.ndim`` (1-3); use
-    :func:`stencil_apply` for a leading batch dimension.  Grids smaller
-    than one fetch window — and periodic grids too large to sit whole
-    in VMEM next to the working set (the wrap gather's block is the
-    whole grid) — fall back to the legacy padded path
+    :func:`stencil_apply` for a leading batch dimension.  Grids that are
+    not a multiple of the tile, or whose tile is shallower than the
+    aligned fetch depth, fall back to the padded path
     (:func:`stencil_window_sweep` on a ``ref.pad_boundary`` window —
     identical results, the ghosts are bitwise equal either way).
     """
@@ -332,96 +342,43 @@ def stencil_sweep(spec: StencilSpec, grid: jax.Array,
         raise ValueError(f"grid rank {grid.ndim} != spec ndim {spec.ndim}")
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    interpret = resolve_interpret(interpret, _lowering_backend(lowering))
-    tile = _normalize_tile(spec, tile, _lowering_backend(lowering))
-    halo = spec.halo
-    wide = tuple(sweeps * h for h in halo)
-    win = tuple(t + 2 * w for t, w in zip(tile, wide))
-    periodic = spec.boundary_mode == "periodic"
-    # The pad-free vs padded-window choice is a lowering decision:
-    # execute_plan passes the plan's recorded strategy; direct callers
-    # (no plan in hand) ask core.plan for the answer here (the budget
-    # knob stays a module attribute so it can be patched per test; the
-    # triton lowering's knob lives in repro.kernels.gpu and is resolved
-    # by ghost_strategy_for itself).
+    interpret = resolve_interpret(interpret)
+    tile = _normalize_tile(spec, tile)
+    wide = tuple(sweeps * h for h in spec.halo)
     if strategy is None:
-        if lowering == "triton":
-            strategy = _plan.ghost_strategy_for(
-                spec, grid.shape, grid.dtype.itemsize, sweeps, tile,
-                backend="triton")
-        else:
-            strategy = _plan.ghost_strategy_for(
-                spec, grid.shape, grid.dtype.itemsize, sweeps, tile,
-                periodic_budget_bytes=_PERIODIC_WHOLE_GRID_BYTES)
+        strategy = _resolve_strategy(spec, grid, sweeps, tile)
     if strategy == "padded-window":
-        # Padded fallback: the clamped fetch needs win <= N per dim
-        # (tiny grids), and the periodic wrap gather needs the whole
-        # grid as its block, which must stay well inside VMEM — beyond
-        # that, window-sized fetches from a wrap-padded copy are the
-        # right trade on real hardware (and what the traffic model
-        # charges).
         window = _ref.pad_boundary(grid, wide, spec.boundary_mode,
                                    spec.boundary_value)
         return stencil_window_sweep(
             spec, window, grid.shape, (0,) * spec.ndim, grid.shape,
-            tile=tile, sweeps=sweeps, interpret=interpret,
-            lowering=lowering)
-
-    grid_dims = tuple(-(-n // t) for n, t in zip(grid.shape, tile))
-    padded = tuple(d * t for d, t in zip(grid_dims, tile))
-    n_shape = grid.shape
-
-    kernel = functools.partial(
-        _padfree_kernel, taps=tuple(spec.taps), halo=halo, tile=tile,
-        sweeps=sweeps, grid_shape=n_shape, acc_dtype=_acc_dtype(grid.dtype),
-        mode=spec.boundary_mode, value=spec.boundary_value,
-        structure=spec.structure)
-
-    if periodic:
-        # whole grid as the block: the wrap gather needs the far edge.
-        in_spec = element_blockspec(n_shape, lambda *ids: (0,) * spec.ndim)
-    else:
-        def in_map(*ids):
-            return tuple(
-                jnp.clip(i * t - w, 0, n - wn)
-                for i, t, w, n, wn in zip(ids, tile, wide, n_shape, win))
-        in_spec = element_blockspec(win, in_map)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=grid_dims,
-        in_specs=[in_spec],
-        out_specs=pl.BlockSpec(tile, lambda *ids: ids),
-        out_shape=jax.ShapeDtypeStruct(padded, grid.dtype),
-        interpret=interpret,
-        **_call_kwargs(lowering, interpret, tile),
-    )(grid)
-    if padded == n_shape:
-        return out
-    return out[tuple(slice(0, n) for n in n_shape)]
+            tile=tile, sweeps=sweeps, interpret=interpret)
+    core = _spec_core(spec, tile, sweeps, grid.shape,
+                      _acc_dtype(grid.dtype))
+    return _fused_call(core, grid, grid.shape, (0,) * spec.ndim, grid.shape,
+                       tile, wide,
+                       fix=(spec.boundary_mode, spec.boundary_value),
+                       interpret=interpret)
 
 
 def stencil_apply(spec: StencilSpec, grid: jax.Array,
                   tile: Sequence[int] | int | None = None,
                   sweeps: int = 1,
                   interpret: bool | None = None,
-                  strategy: str | None = None,
-                  lowering: str | None = None) -> jax.Array:
+                  strategy: str | None = None) -> jax.Array:
     """Rank-dispatching entry point with an optional leading batch dim.
 
     ``grid.ndim == spec.ndim``    → one grid;
     ``grid.ndim == spec.ndim+1``  → dim 0 is a batch of independent
     grids, mapped with ``jax.vmap`` over one shared kernel.
     """
-    interpret = resolve_interpret(interpret, _lowering_backend(lowering))
+    interpret = resolve_interpret(interpret)
     if grid.ndim == spec.ndim:
         return stencil_sweep(spec, grid, tile=tile, sweeps=sweeps,
-                             interpret=interpret, strategy=strategy,
-                             lowering=lowering)
+                             interpret=interpret, strategy=strategy)
     if grid.ndim == spec.ndim + 1:
         fn = functools.partial(stencil_sweep, spec, tile=tile, sweeps=sweeps,
-                               interpret=interpret, strategy=strategy,
-                               lowering=lowering)
+                               interpret=interpret, strategy=strategy)
         return jax.vmap(fn)(grid)
     raise ValueError(
         f"grid rank {grid.ndim} incompatible with spec ndim {spec.ndim} "
@@ -431,113 +388,56 @@ def stencil_apply(spec: StencilSpec, grid: jax.Array,
 # ---------------------------------------------------------------------------
 # Fused multi-stencil pipelines (StencilPipeline)
 # ---------------------------------------------------------------------------
-def _pipeline_kernel(x_ref, org_ref, o_ref, *, stages, tile, sweeps,
-                     grid_shape, acc_dtype):
-    """Pipeline analogue of :func:`_kernel`: the window enters with
-    ``sweeps * H`` ghost layers (``H`` = per-dim sum of stage radii)
-    holding stage 0's boundary extension; the shared fused-chain core
-    (:func:`repro.core.ref.masked_window_pipeline`) consumes each
-    stage's radius in turn and restores between-stage ghosts per the
-    *next* stage's mode — bit-identical to the chained oracle in f64."""
-    ndim = len(tile)
-    starts = tuple(org_ref[d] + pl.program_id(d) * tile[d]
-                   for d in range(ndim))
-    o_ref[...] = _ref.masked_window_pipeline(
-        x_ref[...], stages, tile, sweeps, starts, grid_shape,
-        acc_dtype).astype(o_ref.dtype)
-
-
-def _padfree_pipeline_kernel(x_ref, o_ref, *, stages, tile, sweeps,
-                             grid_shape, acc_dtype, mode, value):
-    """Pad-free pipeline kernel: materialize the chain's widened window
-    in-kernel with stage 0's extension (:func:`_materialize_window`),
-    then run the fused-chain core.  ``mode`` is stage 0's — for periodic
-    it implies *every* stage is periodic (mixed chains never lower to a
-    fused kernel)."""
-    ndim = len(tile)
-    big_halo = tuple(sum(s.halo[d] for s in stages) for d in range(ndim))
-    wide = tuple(sweeps * h for h in big_halo)
-    win = tuple(t + 2 * w for t, w in zip(tile, wide))
-    s_true = tuple(pl.program_id(d) * tile[d] - wide[d] for d in range(ndim))
-    x = _materialize_window(x_ref[...], s_true, win, grid_shape, mode, value)
-    starts = tuple(pl.program_id(d) * tile[d] for d in range(ndim))
-    o_ref[...] = _ref.masked_window_pipeline(
-        x, stages, tile, sweeps, starts, grid_shape,
-        acc_dtype).astype(o_ref.dtype)
-
-
 def pipeline_window_sweep(pipeline: StencilPipeline, window: jax.Array,
                           out_shape: Sequence[int],
                           origin,
                           grid_shape: Sequence[int],
                           tile: Sequence[int] | int | None = None,
                           sweeps: int = 1,
-                          interpret: bool | None = None,
-                          lowering: str | None = None) -> jax.Array:
+                          interpret: bool | None = None) -> jax.Array:
     """``sweeps`` fused chain applications to a block that already
     carries its ``sweeps * H`` halo (``H`` = summed stage radii) filled
     with stage 0's boundary extension — the pipeline analogue of
     :func:`stencil_window_sweep`, and the shard-local entry point of the
-    distributed sum-of-radii deep-halo path."""
+    distributed sum-of-radii deep-halo path.  The shared fused-chain
+    core (:func:`repro.core.ref.masked_window_pipeline`) consumes each
+    stage's radius in turn and restores between-stage ghosts per the
+    *next* stage's mode — bit-identical to the chained oracle in f64."""
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
     if not pipeline.fusable:
         raise ValueError(
             f"{pipeline.name}: mixed periodic/non-periodic stages cannot "
             "run fused; lower the pipeline and use the staged plan")
-    interpret = resolve_interpret(interpret, _lowering_backend(lowering))
-    tile = _normalize_tile(pipeline, tile, _lowering_backend(lowering))
+    interpret = resolve_interpret(interpret)
+    tile = _normalize_tile(pipeline, tile)
     out_shape = tuple(out_shape)
-    grid_shape = tuple(int(n) for n in grid_shape)
     wide = tuple(sweeps * h for h in pipeline.halo)
     want = tuple(n + 2 * w for n, w in zip(out_shape, wide))
     if window.shape != want:
         raise ValueError(
             f"window shape {window.shape} != out_shape + 2*sweeps*H "
             f"{want}")
-
-    pads = tuple(-n % t for n, t in zip(out_shape, tile))
-    xp = jnp.pad(window, [(0, p) for p in pads])
-    grid_dims = tuple((n + p) // t for n, p, t in zip(out_shape, pads, tile))
-    padded = tuple(n + p for n, p in zip(out_shape, pads))
-    org = jnp.asarray(origin, jnp.int32)
-
-    kernel = functools.partial(
-        _pipeline_kernel, stages=pipeline.stages, tile=tile, sweeps=sweeps,
-        grid_shape=grid_shape, acc_dtype=_acc_dtype(window.dtype))
-
-    def in_map(*ids):
-        return tuple(i * t for i, t in zip(ids, tile))
-
-    out = pl.pallas_call(
-        kernel,
-        grid=grid_dims,
-        in_specs=[element_blockspec(
-            tuple(t + 2 * w for t, w in zip(tile, wide)), in_map),
-            pl.BlockSpec((pipeline.ndim,), lambda *ids: (0,))],
-        out_specs=pl.BlockSpec(tile, lambda *ids: ids),
-        out_shape=jax.ShapeDtypeStruct(padded, window.dtype),
-        interpret=interpret,
-        **_call_kwargs(lowering, interpret, tile),
-    )(xp, org)
-    return out[tuple(slice(0, n) for n in out_shape)]
+    grid_shape = tuple(int(n) for n in grid_shape)
+    core = _pipeline_core(pipeline, tile, sweeps, grid_shape,
+                          _acc_dtype(window.dtype))
+    return _fused_call(core, window, out_shape, origin, grid_shape, tile,
+                       wide, fix=None, interpret=interpret)
 
 
 def pipeline_sweep(pipeline: StencilPipeline, grid: jax.Array,
                    tile: Sequence[int] | int | None = None,
                    sweeps: int = 1,
                    interpret: bool | None = None,
-                   strategy: str | None = None,
-                   lowering: str | None = None) -> jax.Array:
+                   strategy: str | None = None) -> jax.Array:
     """``sweeps`` fused applications of a stage chain: one HBM read of
     the ``sweeps * H``-widened window and one write per tile — every
     intermediate stage field stays in VMEM, never round-tripping HBM.
     Bit-identical in f64 to ``sweeps`` chained
     :func:`repro.core.ref.apply_pipeline` calls.
 
-    Strategy resolution mirrors :func:`stencil_sweep` (pad-free clamped
-    fetch; padded-window fallback for tiny grids and out-of-budget
-    periodic chains).  A non-fusable chain (mixed periodic with
+    Strategy resolution mirrors :func:`stencil_sweep` (pad-free fetch;
+    padded-window fallback).  A non-fusable chain (mixed periodic with
     non-periodic stages — between-stage ghost restoration is not
     tile-local) executes ``"staged"``: per-stage single-sweep kernels,
     chained semantics at per-stage traffic.
@@ -547,93 +447,53 @@ def pipeline_sweep(pipeline: StencilPipeline, grid: jax.Array,
             f"grid rank {grid.ndim} != pipeline ndim {pipeline.ndim}")
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    interpret = resolve_interpret(interpret, _lowering_backend(lowering))
-    tile = _normalize_tile(pipeline, tile, _lowering_backend(lowering))
+    interpret = resolve_interpret(interpret)
+    tile = _normalize_tile(pipeline, tile)
     if strategy is None:
-        if not pipeline.fusable:
-            strategy = "staged"
-        elif lowering == "triton":
-            strategy = _plan.ghost_strategy_for(
-                pipeline, grid.shape, grid.dtype.itemsize, sweeps, tile,
-                backend="triton")
-        else:
-            strategy = _plan.ghost_strategy_for(
-                pipeline, grid.shape, grid.dtype.itemsize, sweeps, tile,
-                periodic_budget_bytes=_PERIODIC_WHOLE_GRID_BYTES)
+        strategy = ("staged" if not pipeline.fusable else
+                    _resolve_strategy(pipeline, grid, sweeps, tile))
     if strategy == "staged":
         out = grid
         for _ in range(sweeps):
             for stage in pipeline.stages:
                 out = stencil_sweep(stage, out, tile=tile, sweeps=1,
-                                    interpret=interpret, lowering=lowering)
+                                    interpret=interpret)
         return out
     if not pipeline.fusable:
         raise ValueError(
             f"{pipeline.name}: mixed periodic/non-periodic stages cannot "
             f"run fused (requested strategy {strategy!r}); use "
             "strategy='staged'")
-    big_halo = pipeline.halo
-    wide = tuple(sweeps * h for h in big_halo)
-    win = tuple(t + 2 * w for t, w in zip(tile, wide))
+    wide = tuple(sweeps * h for h in pipeline.halo)
     if strategy == "padded-window":
         window = _ref.pad_boundary(grid, wide, pipeline.boundary_mode,
                                    pipeline.boundary_value)
         return pipeline_window_sweep(
             pipeline, window, grid.shape, (0,) * pipeline.ndim, grid.shape,
-            tile=tile, sweeps=sweeps, interpret=interpret,
-            lowering=lowering)
-
-    grid_dims = tuple(-(-n // t) for n, t in zip(grid.shape, tile))
-    padded = tuple(d * t for d, t in zip(grid_dims, tile))
-    n_shape = grid.shape
-
-    kernel = functools.partial(
-        _padfree_pipeline_kernel, stages=pipeline.stages, tile=tile,
-        sweeps=sweeps, grid_shape=n_shape,
-        acc_dtype=_acc_dtype(grid.dtype), mode=pipeline.boundary_mode,
-        value=pipeline.boundary_value)
-
-    if pipeline.boundary_mode == "periodic":
-        in_spec = element_blockspec(n_shape,
-                                    lambda *ids: (0,) * pipeline.ndim)
-    else:
-        def in_map(*ids):
-            return tuple(
-                jnp.clip(i * t - w, 0, n - wn)
-                for i, t, w, n, wn in zip(ids, tile, wide, n_shape, win))
-        in_spec = element_blockspec(win, in_map)
-
-    out = pl.pallas_call(
-        kernel,
-        grid=grid_dims,
-        in_specs=[in_spec],
-        out_specs=pl.BlockSpec(tile, lambda *ids: ids),
-        out_shape=jax.ShapeDtypeStruct(padded, grid.dtype),
-        interpret=interpret,
-        **_call_kwargs(lowering, interpret, tile),
-    )(grid)
-    if padded == n_shape:
-        return out
-    return out[tuple(slice(0, n) for n in n_shape)]
+            tile=tile, sweeps=sweeps, interpret=interpret)
+    core = _pipeline_core(pipeline, tile, sweeps, grid.shape,
+                          _acc_dtype(grid.dtype))
+    return _fused_call(core, grid, grid.shape, (0,) * pipeline.ndim,
+                       grid.shape, tile, wide,
+                       fix=(pipeline.boundary_mode, pipeline.boundary_value),
+                       interpret=interpret)
 
 
 def pipeline_apply(pipeline: StencilPipeline, grid: jax.Array,
                    tile: Sequence[int] | int | None = None,
                    sweeps: int = 1,
                    interpret: bool | None = None,
-                   strategy: str | None = None,
-                   lowering: str | None = None) -> jax.Array:
+                   strategy: str | None = None) -> jax.Array:
     """Pipeline analogue of :func:`stencil_apply`: one grid, or a
     leading batch dim vmapped over one shared fused-chain kernel."""
-    interpret = resolve_interpret(interpret, _lowering_backend(lowering))
+    interpret = resolve_interpret(interpret)
     if grid.ndim == pipeline.ndim:
         return pipeline_sweep(pipeline, grid, tile=tile, sweeps=sweeps,
-                              interpret=interpret, strategy=strategy,
-                              lowering=lowering)
+                              interpret=interpret, strategy=strategy)
     if grid.ndim == pipeline.ndim + 1:
         fn = functools.partial(pipeline_sweep, pipeline, tile=tile,
                                sweeps=sweeps, interpret=interpret,
-                               strategy=strategy, lowering=lowering)
+                               strategy=strategy)
         return jax.vmap(fn)(grid)
     raise ValueError(
         f"grid rank {grid.ndim} incompatible with pipeline ndim "

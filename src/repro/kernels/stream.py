@@ -79,14 +79,13 @@ def _slab_fn(plan, slab_len: int):
     @functools.partial(jax.jit, donate_argnums=donate)
     def run(window, start0):
         starts = [start0] + [0] * (len(plan.shape) - 1)
-        lowering = "triton" if plan.backend == "triton" else None
         if plan.is_pipeline:
             if plan.backend in _plan.KERNEL_BACKENDS:
                 from repro.kernels import engine as keng  # lazy: optional dep
                 return keng.pipeline_window_sweep(
                     spec, window, out_shape, starts, plan.shape,
                     tile=plan.tile, sweeps=plan.sweeps,
-                    interpret=plan.interpret, lowering=lowering)
+                    interpret=plan.interpret)
             return _ref.masked_window_pipeline(
                 window, spec.stages, out_shape, plan.sweeps, starts,
                 plan.shape, window.dtype).astype(window.dtype)
@@ -94,8 +93,7 @@ def _slab_fn(plan, slab_len: int):
             from repro.kernels import engine as keng      # lazy: optional dep
             return keng.stencil_window_sweep(
                 spec, window, out_shape, starts, plan.shape,
-                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
-                lowering=lowering)
+                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret)
         return _ref.masked_window_sweeps(
             window, spec.taps, plan.halo, out_shape, plan.sweeps, starts,
             plan.shape, window.dtype, mode=plan.boundary_mode,

@@ -24,7 +24,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .engine import element_blockspec
+
+
+def element_blockspec(block_shape, index_map) -> pl.BlockSpec:
+    """Element-offset BlockSpec: ``pl.Element`` per dim."""
+    return pl.BlockSpec(tuple(pl.Element(b) for b in block_shape),
+                        index_map)
 
 NEG_INF = -1e30
 

@@ -1,22 +1,18 @@
-"""Tile-shape autotuner for the unified stencil engine (both lowerings).
+"""Tile-shape autotuner for the unified stencil engine.
 
-Ranks candidate output tiles with the first-order cost models in
-:mod:`repro.core.perfmodel` — ``pallas_tile_cost`` for the TPU (mosaic)
-lowering, ``triton_tile_cost`` for ``backend="triton"``: memory-traffic
-vs compute roofline, scratch-capacity feasibility (VMEM vs SM shared
-memory), alignment padding (128-lane vs 32-lane warp grain), and
-per-step sequencing overhead (grid steps vs CTA launches scaled by
-occupancy).  The analytic pass is free, so it runs for every (spec,
-shape, sweeps, backend) the engine sees; ``measure=True`` additionally
-wall-clocks the top analytic candidates on the real array (interpret
-mode on CPU, compiled on real hardware) and re-ranks by measurement.
+Ranks candidate output tiles with the first-order TPU cost models in
+:mod:`repro.core.perfmodel` (``pallas_tile_cost``): memory traffic of
+the aligned fetch windows vs compute roofline, VMEM feasibility,
+alignment padding and per-step sequencing overhead.  The analytic pass
+is free, so it runs for every (spec, shape, sweeps) the engine sees;
+``autotune_measured`` additionally wall-clocks the top analytic
+candidates on the real array (interpret mode on CPU, compiled on the
+chip) and re-ranks by measurement.
 
-The TPU candidate lists keep the innermost dimension a multiple of 128
-(VPU lane width) and the second-minor a multiple of 8 (f32 sublanes);
-the GPU lists keep the innermost a multiple of the 32-lane warp (the
-coalescing grain — there is no sublane constraint) and stay small
-enough that the fused working set fits one SM's shared memory while
-launching enough CTAs to occupy the device.  See docs/kernels.md.
+Every candidate extent is a multiple of the HBM layout's granule
+(:func:`repro.core.perfmodel.fetch_grain`: 1024 words in rank 1, 8
+sublanes x 128 lanes for 32-bit data in rank >= 2), so the kernel's
+window DMAs stay aligned.  See docs/kernels.md.
 
 The spec's boundary mode participates in the ranking (``reflect``
 charges the between-sweep ghost re-mirroring gather) and so does its
@@ -24,16 +20,12 @@ tap *structure*: the compute term uses the factored per-point flop
 count (``spec.structured_flops_per_point()``) and the feasibility check
 charges one live window-sized intermediate per factored term.  All of
 it enters the cache key: ``autotune`` is memoized on the full
-``StencilSpec`` (boundary + structure included), the backend, *and* the
-active measured-calibration fingerprint
-(:func:`repro.core.perfmodel.calibration_fingerprint`) — rankings
-computed under a ``CASPER_CALIBRATION`` override never collide with
-uncalibrated ones.
+``StencilSpec`` (boundary + structure included).
 
 ``autotune_measured`` results can persist across processes: point
 ``CASPER_TUNE_CACHE`` at a directory and each measured tune is stored
 as one JSON file keyed (sha256) like the plan cache — full spec, shape,
-dtype, sweeps, backend, measurement config and calibration fingerprint.
+dtype, sweeps and measurement config.
 Hit/miss/store counters (:data:`TUNE_DISK_CACHE`) are pinned by tests
 the same way ``plan.PLAN_CACHE``'s are.
 """
@@ -52,63 +44,28 @@ from repro.core import perfmodel as pm
 from repro.core.stencil import StencilSpec
 
 CANDIDATE_TILES: dict[int, tuple[tuple[int, ...], ...]] = {
-    1: ((256,), (512,), (1024,), (2048,), (4096,), (8192,)),
+    1: ((1024,), (2048,), (4096,), (8192,), (16384,)),
     2: ((8, 128), (8, 256), (16, 128), (16, 256), (32, 128), (32, 256),
         (32, 512), (64, 256), (8, 512)),
     3: ((2, 16, 128), (4, 8, 128), (4, 16, 128), (8, 16, 128),
         (4, 16, 256), (8, 8, 128), (4, 32, 128), (2, 32, 256)),
 }
 
-# GPU (triton) candidates: one CTA per tile.  Innermost dims are warp
-# multiples (32) for coalesced loads; totals run from a few hundred
-# points (deep-sweep f64 working sets must fit ~96 KiB of shared
-# memory) up to a few thousand (small grids should still fill the SMs
-# — triton_tile_cost's occupancy term penalizes too-coarse covers).
-CANDIDATE_GPU_TILES: dict[int, tuple[tuple[int, ...], ...]] = {
-    1: ((128,), (256,), (512,), (1024,), (2048,), (4096,)),
-    2: ((8, 32), (8, 64), (16, 32), (16, 64), (32, 32), (32, 64),
-        (32, 128), (64, 64), (8, 128)),
-    3: ((2, 4, 32), (2, 8, 32), (4, 4, 32), (4, 8, 32), (4, 8, 64),
-        (8, 8, 32), (2, 8, 64), (2, 16, 64), (4, 16, 32)),
-}
-
 
 def candidate_tiles(ndim: int,
                     shape: Sequence[int] | None = None,
-                    backend: str = "pallas"
-                    ) -> tuple[tuple[int, ...], ...]:
-    """Candidates for ``ndim`` under ``backend``'s alignment constraints,
-    dropping tiles absurdly larger than the grid (a tile more than 4x
-    the padded extent wastes every lane)."""
-    cands = (CANDIDATE_GPU_TILES if backend == "triton"
-             else CANDIDATE_TILES)[ndim]
+                    itemsize: int = 4) -> tuple[tuple[int, ...], ...]:
+    """Candidates for ``ndim`` whose extents are multiples of the HBM
+    granule for ``itemsize``, dropping tiles absurdly larger than the
+    grid (a tile more than 4x the padded extent wastes every lane)."""
+    grain = pm.fetch_grain(ndim, itemsize)
+    cands = tuple(t for t in CANDIDATE_TILES[ndim]
+                  if all(td % g == 0 for td, g in zip(t, grain)))
     if shape is None:
         return cands
     kept = tuple(t for t in cands
                  if all(td <= 4 * nd for td, nd in zip(t, shape)))
     return kept or cands[:1]
-
-
-def _tile_cost(spec, shape, tile, sweeps, itemsize, backend) -> float:
-    if backend == "triton":
-        return pm.triton_tile_cost(spec, shape, tile, sweeps=sweeps,
-                                   itemsize=itemsize)
-    return pm.pallas_tile_cost(spec, shape, tile, sweeps=sweeps,
-                               itemsize=itemsize)
-
-
-def _pipeline_tile_cost(pipe, shape, tile, sweeps, itemsize,
-                        backend) -> float:
-    if backend == "triton":
-        return pm.triton_pipeline_tile_cost(pipe, shape, tile,
-                                            sweeps=sweeps,
-                                            itemsize=itemsize)
-    return pm.pallas_pipeline_tile_cost(pipe, shape, tile, sweeps=sweeps,
-                                        itemsize=itemsize)
-
-
-def _budget_name(backend: str) -> str:
-    return "GPU shared memory" if backend == "triton" else "VMEM"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -128,57 +85,48 @@ class TuneResult:
 
 
 def autotune(spec: StencilSpec, shape: tuple[int, ...], sweeps: int = 1,
-             itemsize: int = 4, backend: str = "pallas") -> TuneResult:
-    """Best tile for (spec, shape, sweeps) under ``backend``'s analytic
-    cost model.  The memo key includes the live calibration fingerprint
-    so a ``CASPER_CALIBRATION`` change re-ranks instead of serving stale
-    cached rankings."""
-    return _autotune(spec, tuple(shape), sweeps, itemsize, backend,
-                     pm.calibration_fingerprint())
+             itemsize: int = 4) -> TuneResult:
+    """Best tile for (spec, shape, sweeps) under the analytic cost
+    model."""
+    return _autotune(spec, tuple(shape), sweeps, itemsize)
+
+
+def _rank(name, ndim, shape, sweeps, itemsize, cost) -> TuneResult:
+    scored = sorted(((tile, cost(tile))
+                     for tile in candidate_tiles(ndim, shape, itemsize)),
+                    key=lambda tc: tc[1])
+    best, best_cost = scored[0]
+    if math.isinf(best_cost):
+        raise ValueError(
+            f"no candidate tile fits VMEM for {name} sweeps={sweeps}")
+    return TuneResult(best, best_cost, tuple(scored))
 
 
 @functools.lru_cache(maxsize=512)
 def _autotune(spec: StencilSpec, shape: tuple[int, ...], sweeps: int,
-              itemsize: int, backend: str, _cal) -> TuneResult:
-    scored = sorted(
-        ((tile, _tile_cost(spec, shape, tile, sweeps, itemsize, backend))
-         for tile in candidate_tiles(spec.ndim, shape, backend)),
-        key=lambda tc: tc[1])
-    best, cost = scored[0]
-    if math.isinf(cost):
-        raise ValueError(
-            f"no candidate tile fits {_budget_name(backend)} for "
-            f"{spec.name} sweeps={sweeps}")
-    return TuneResult(best, cost, tuple(scored))
+              itemsize: int) -> TuneResult:
+    return _rank(spec.name, spec.ndim, shape, sweeps, itemsize,
+                 lambda tile: pm.pallas_tile_cost(
+                     spec, shape, tile, sweeps=sweeps, itemsize=itemsize))
 
 
 def autotune_pipeline(pipeline, shape: tuple[int, ...], sweeps: int = 1,
-                      itemsize: int = 4,
-                      backend: str = "pallas") -> TuneResult:
+                      itemsize: int = 4) -> TuneResult:
     """Best tile for a fused :class:`~repro.core.stencil.StencilPipeline`
-    chain: same candidate lists, ranked by the backend's pipeline cost
-    model (summed-halo window traffic, per-stage structured compute at
-    the exact element-layer schedule).  Memoized on the full pipeline —
-    stage order, per-stage boundary and structure all participate —
-    plus backend and calibration fingerprint."""
-    return _autotune_pipeline(pipeline, tuple(shape), sweeps, itemsize,
-                              backend, pm.calibration_fingerprint())
+    chain: same candidate lists, ranked by the pipeline cost model
+    (summed-halo window traffic, per-stage structured compute at the
+    exact element-layer schedule).  Memoized on the full pipeline —
+    stage order, per-stage boundary and structure all participate."""
+    return _autotune_pipeline(pipeline, tuple(shape), sweeps, itemsize)
 
 
 @functools.lru_cache(maxsize=512)
 def _autotune_pipeline(pipeline, shape: tuple[int, ...], sweeps: int,
-                       itemsize: int, backend: str, _cal) -> TuneResult:
-    scored = sorted(
-        ((tile, _pipeline_tile_cost(pipeline, shape, tile, sweeps,
-                                    itemsize, backend))
-         for tile in candidate_tiles(pipeline.ndim, shape, backend)),
-        key=lambda tc: tc[1])
-    best, cost = scored[0]
-    if math.isinf(cost):
-        raise ValueError(
-            f"no candidate tile fits {_budget_name(backend)} for "
-            f"{pipeline.name} sweeps={sweeps}")
-    return TuneResult(best, cost, tuple(scored))
+                       itemsize: int) -> TuneResult:
+    return _rank(pipeline.name, pipeline.ndim, shape, sweeps, itemsize,
+                 lambda tile: pm.pallas_pipeline_tile_cost(
+                     pipeline, shape, tile, sweeps=sweeps,
+                     itemsize=itemsize))
 
 
 # ---------------------------------------------------------------------------
@@ -215,16 +163,13 @@ def _tune_cache_dir() -> str | None:
     return root or None
 
 
-def _tune_cache_key(spec, shape, itemsize, sweeps, backend, top_k, reps,
+def _tune_cache_key(spec, shape, itemsize, sweeps, top_k, reps,
                     interpret) -> str:
     """Content key mirroring the plan cache's: the full spec repr
-    (taps + boundary + structure), grid shape, dtype width, sweeps,
-    backend, the measurement configuration and the live calibration
-    fingerprint — a calibrated measured tune never aliases an
-    uncalibrated one."""
+    (taps + boundary + structure), grid shape, dtype width, sweeps and
+    the measurement configuration."""
     payload = repr((spec, tuple(shape), int(itemsize), int(sweeps),
-                    backend, int(top_k), int(reps), interpret,
-                    pm.calibration_fingerprint()))
+                    int(top_k), int(reps), interpret))
     return hashlib.sha256(payload.encode()).hexdigest()[:40]
 
 
@@ -258,8 +203,7 @@ def _tune_cache_store(key: str, result: TuneResult) -> None:
 
 def autotune_measured(spec: StencilSpec, grid, sweeps: int = 1,
                       top_k: int = 3, reps: int = 2,
-                      interpret: bool | None = None,
-                      backend: str = "pallas") -> TuneResult:
+                      interpret: bool | None = None) -> TuneResult:
     """Re-rank the ``top_k`` analytic candidates by wall clock on
     ``grid``.  With ``CASPER_TUNE_CACHE`` set, results persist on disk
     across process restarts (measured tunes are the expensive ones —
@@ -267,7 +211,7 @@ def autotune_measured(spec: StencilSpec, grid, sweeps: int = 1,
     from . import engine  # local import: tune is importable without jax use
 
     key = _tune_cache_key(spec, grid.shape, grid.dtype.itemsize, sweeps,
-                          backend, top_k, reps, interpret)
+                          top_k, reps, interpret)
     if _tune_cache_dir() is not None:
         cached = _tune_cache_load(key)
         if cached is not None:
@@ -276,14 +220,12 @@ def autotune_measured(spec: StencilSpec, grid, sweeps: int = 1,
         TUNE_DISK_CACHE.misses += 1
 
     analytic = autotune(spec, tuple(grid.shape), sweeps=sweeps,
-                        itemsize=grid.dtype.itemsize, backend=backend)
+                        itemsize=grid.dtype.itemsize)
     finite = [(t, c) for t, c in analytic.table if math.isfinite(c)]
-    lowering = "triton" if backend == "triton" else None
     timed = []
     for tile, _ in finite[:top_k]:
         fn = functools.partial(engine.stencil_apply, spec, tile=tile,
-                               sweeps=sweeps, interpret=interpret,
-                               lowering=lowering)
+                               sweeps=sweeps, interpret=interpret)
         fn(grid).block_until_ready()            # warm up / compile
         t0 = time.perf_counter()
         for _ in range(reps):

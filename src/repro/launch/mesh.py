@@ -3,13 +3,21 @@ module never touches jax device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all ``Auto``: the model code places arrays
+    with ``with_sharding_constraint`` and lets the partitioner resolve
+    the rest (``jax.make_mesh`` otherwise makes ``Explicit`` axes)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """The production mesh: 16x16 = 256 chips/pod; 2 pods multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_stencil_mesh(ndim: int, *, multi_pod: bool = False):
@@ -32,4 +40,4 @@ def make_stencil_mesh(ndim: int, *, multi_pod: bool = False):
 
 def make_host_mesh(n_data: int = 1, n_model: int = 1):
     """Small mesh over locally available devices (tests / examples)."""
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

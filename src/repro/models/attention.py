@@ -185,7 +185,6 @@ def _flash_decode_seqsharded(q, k, v, kv_len, c: AttnCfg, ctx):
     q: (B, Hkv, G, 1, D) replicated over model; k/v: (B, Hkv, S, D) with S
     sharded over 'model'.  Returns (B, Hkv, G, 1, D).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.sharding import _mesh_axes
 
@@ -216,13 +215,13 @@ def _flash_decode_seqsharded(q, k, v, kv_len, c: AttnCfg, ctx):
             "model")
         return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q_.dtype)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(bax, None, None, None, None),
                   P(bax, None, "model", None),
                   P(bax, None, "model", None), P()),
         out_specs=P(bax, None, None, None, None),
-        check_rep=False)
+        check_vma=False)
     return fn(q, k, v, jnp.int32(kv_len))
 
 

@@ -118,35 +118,3 @@ def build_roofline(arch_id: str, cell, mesh_name: str, n_devices: int,
         t_collective=t_coll, bottleneck=bottleneck, model_flops=mf,
         useful_flops_ratio=ratio, memory_per_device=memory,
         collective_ops=totals.collective_ops())
-
-
-def stencil_roofline(*, flops: float, bytes_moved: float, measured_s: float,
-                     measured_bw: float, peak_flops: float) -> dict:
-    """Achieved-vs-peak roofline placement of one *measured* stencil
-    kernel run (the single-device analogue of :func:`build_roofline`,
-    used by ``benchmarks/roofline_stencil.py``).
-
-    ``flops``/``bytes_moved`` come from the compiled HLO
-    (:func:`repro.roofline.hlo_walk.walk_jit`), ``measured_s`` from the
-    wall clock, ``measured_bw`` from a same-process bandwidth
-    microbenchmark and ``peak_flops`` from the perfmodel (calibrated or
-    not).  ``roofline_fraction`` is the fraction of the measured time
-    the roofline lower bound accounts for — 1.0 means the kernel runs
-    exactly at the measured-bandwidth/peak-compute envelope; values can
-    exceed 1 slightly when the working set is cache-resident (the
-    microbenchmark streams, the kernel may not).
-    """
-    t_mem = bytes_moved / measured_bw if measured_bw > 0 else 0.0
-    t_comp = flops / peak_flops if peak_flops > 0 else 0.0
-    lower_bound = max(t_mem, t_comp)
-    return {
-        "hlo_flops": float(flops),
-        "hlo_bytes": float(bytes_moved),
-        "measured_s": float(measured_s),
-        "achieved_bw": bytes_moved / measured_s if measured_s > 0 else 0.0,
-        "t_memory_s": t_mem,
-        "t_compute_s": t_comp,
-        "bound": "memory" if t_mem >= t_comp else "compute",
-        "roofline_fraction": (lower_bound / measured_s
-                              if measured_s > 0 else 0.0),
-    }

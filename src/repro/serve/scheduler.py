@@ -48,7 +48,7 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 from repro.core import perfmodel as _pm
 from repro.core import plan as _plan

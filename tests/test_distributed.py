@@ -100,7 +100,7 @@ def test_distributed_fused_equals_chained_and_fewer_launches():
     steps and to the oracle, and its compiled HLO carries ~4x fewer
     collective-permute launches."""
     run_sub(8, """
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import PAPER_STENCILS, distributed_stencil_fn
         from repro.core import ref
@@ -140,7 +140,7 @@ def test_distributed_boundary_modes_bit_identical():
     multi-hop deep-halo case (t*halo > shard, periodic wrap-ring crossing
     several devices), a sliver mesh, and both shard-local backends."""
     out = run_sub(8, """
-        from jax.experimental import enable_x64
+        from jax import enable_x64
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.core import PAPER_STENCILS, distributed_stencil_fn
         from repro.core import ref as cref
@@ -272,6 +272,7 @@ def test_sharded_train_step_matches_single_device():
         from repro.optim import AdamWConfig, init_opt_state
         from repro.sharding import ShardCtx
         from repro.train import make_train_step
+        from repro.launch.mesh import make_host_mesh
 
         cfg = get_config("yi-9b", reduced=True)
         arch = make_arch(cfg)
@@ -285,7 +286,7 @@ def test_sharded_train_step_matches_single_device():
         p1, s1, m1 = jax.jit(step1)(params, st, batch)
 
         # 2x2 mesh
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_host_mesh(2, 2)
         ctx = ShardCtx(mesh)
         sh = param_shardings(arch.param_specs(cfg), mesh)
         params_sh = jax.tree.map(jax.device_put, params, sh)
@@ -313,6 +314,7 @@ def test_elastic_remesh_checkpoint_restore():
         from repro.models import make_arch
         from repro.optim import AdamWConfig
         from repro.train import Trainer, TrainLoopConfig
+        from repro.launch.mesh import make_host_mesh
 
         shutil.rmtree("/tmp/repro_remesh_test", ignore_errors=True)
         cfg = get_config("yi-9b", reduced=True)
@@ -320,11 +322,11 @@ def test_elastic_remesh_checkpoint_restore():
         opt = AdamWConfig(lr=1e-3)
         lc = TrainLoopConfig(total_steps=4, ckpt_every=2,
                              ckpt_dir="/tmp/repro_remesh_test", log_every=1)
-        mesh1 = jax.make_mesh((2, 2), ("data", "model"))
+        mesh1 = make_host_mesh(2, 2)
         tr = Trainer(arch, opt, lc, mesh=mesh1)
         tr.run()
 
-        mesh2 = jax.make_mesh((4, 1), ("data", "model"))
+        mesh2 = make_host_mesh(4, 1)
         tr2 = Trainer(arch, opt, lc, mesh=mesh2)
         assert tr2.try_resume()
         assert tr2.step == 4
@@ -344,6 +346,7 @@ def test_flash_decode_seqsharded_matches_dense():
         from repro.models import make_arch
         from repro.models.common import init_params, abstract_params, param_shardings
         from repro.sharding import ShardCtx
+        from repro.launch.mesh import make_host_mesh
 
         base = get_config("yi-9b", reduced=True)
         cfg = dataclasses.replace(base, decode_kv_seq_shard=True)
@@ -357,7 +360,7 @@ def test_flash_decode_seqsharded_matches_dense():
 
         # reference: dense decode on the SAME mesh (isolates the flash
         # softmax-combine from generic bf16 TP partial-sum reordering)
-        mesh = jax.make_mesh((1, 4), ("data", "model"))
+        mesh = make_host_mesh(1, 4)
         outs = {}
         for name, c_, a_ in (("dense", base, arch),
                              ("flash", cfg, arch_fd)):

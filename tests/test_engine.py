@@ -13,7 +13,7 @@ import jax.numpy as jnp
 from repro.core import CasperEngine, PAPER_STENCILS
 from repro.core import perfmodel as pm
 from repro.core import ref as cref
-from repro.kernels import engine, gpu, tune
+from repro.kernels import engine, tune
 
 # Small odd shapes: non-divisible by every candidate tile on every axis.
 SHAPES = {1: (1000,), 2: (70, 130), 3: (9, 20, 150)}
@@ -59,17 +59,29 @@ def test_batched_leading_dim(name, rng):
                                atol=1e-5)
 
 
+# Grain-aligned (grid, tile) per rank: each extent a multiple of the
+# tile, each tile at least the aligned fetch depth, so the kernel takes
+# the pad-free DMA path (SHAPES take the padded-window fallback).
+PADFREE = {1: ((4096,), (1024,)), 2: ((32, 384), (16, 128)),
+           3: ((16, 24, 384), (8, 8, 128))}
+
+
+@pytest.mark.parametrize("strategy", ["padded-window", "pad-free"])
 @pytest.mark.parametrize("name", list(PAPER_STENCILS))
-def test_f64_bit_identical_to_oracle(name, rng):
+def test_f64_bit_identical_to_oracle(name, strategy, rng):
     """sweeps=1 f64 output is bit-identical to the core.ref oracle in
     every evaluation form — eager, jitted, and the pure-numpy oracle —
-    because ref.tap_sum pins the accumulation order (XLA otherwise
-    regroups add chains differently per compiled program)."""
-    from jax.experimental import enable_x64
+    under both kernel strategies, because ref.tap_sum pins the
+    accumulation order (XLA otherwise regroups add chains differently
+    per compiled program)."""
+    from jax import enable_x64
     spec = PAPER_STENCILS[name]
+    shape, tile = ((SHAPES[spec.ndim], None) if strategy == "padded-window"
+                   else PADFREE[spec.ndim])
     with enable_x64():
-        g = jnp.asarray(rng.standard_normal(SHAPES[spec.ndim]), jnp.float64)
-        got = engine.stencil_apply(spec, g)
+        g = jnp.asarray(rng.standard_normal(shape), jnp.float64)
+        assert engine._resolve_strategy(spec, g, 1, tile) == strategy
+        got = engine.stencil_apply(spec, g, tile=tile)
         assert got.dtype == jnp.float64
         assert bool(jnp.all(got == cref.apply_stencil(spec, g))), name
         assert bool(jnp.all(
@@ -79,7 +91,7 @@ def test_f64_bit_identical_to_oracle(name, rng):
 
 
 def test_f64_fused_sweeps_bit_identical(rng):
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     spec = PAPER_STENCILS["jacobi2d"]
     with enable_x64():
         g = jnp.asarray(rng.standard_normal((70, 130)), jnp.float64)
@@ -236,7 +248,7 @@ def test_boundary_modes_f64_bit_identical(boundary, rng):
     the boundary subsystem): the window is built from bitwise copies of
     interior elements (pad_boundary), ghosts are restored bitwise between
     sweeps, and tap_sum pins the accumulation order."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     spec = PAPER_STENCILS["jacobi2d"].with_boundary(boundary)
     with enable_x64():
         g = jnp.asarray(rng.standard_normal((70, 130)), jnp.float64)
@@ -313,143 +325,29 @@ def test_compat_shims_match_engine(rng):
             np.asarray(_chained(spec, g, 1)), atol=1e-5)
 
 
-# ---------------------------------------------------------------------------
-# GPU (triton) lowering: the same kernel bodies behind a second target
-# ---------------------------------------------------------------------------
-@pytest.mark.parametrize("name", list(PAPER_STENCILS))
-def test_triton_f64_bit_identical_to_oracle(name, rng):
-    """The ``backend="triton"`` lowering (interpret mode on this CPU
-    host) is f64 bit-identical to the core.ref oracle — the triton path
-    reuses the pallas kernel bodies verbatim, so bit-identity holds by
-    construction, and this matrix pins that it stays that way."""
-    from jax.experimental import enable_x64
-    spec = PAPER_STENCILS[name]
-    with enable_x64():
-        g = jnp.asarray(rng.standard_normal(SHAPES[spec.ndim]), jnp.float64)
-        got = gpu.stencil_apply(spec, g)
-        assert got.dtype == jnp.float64
-        assert bool(jnp.all(got == cref.apply_stencil(spec, g))), name
-
-
-@pytest.mark.parametrize("boundary", ["zero", "constant(0.75)", "periodic",
-                                      "reflect"])
-def test_triton_f64_fused_sweeps_bit_identical(boundary, rng):
-    """Fused triton sweeps match both the chained oracle and the pallas
-    lowering bitwise under every boundary mode."""
-    from jax.experimental import enable_x64
-    spec = PAPER_STENCILS["jacobi2d"].with_boundary(boundary)
-    with enable_x64():
-        g = jnp.asarray(rng.standard_normal((70, 130)), jnp.float64)
-        got = gpu.stencil_apply(spec, g, sweeps=3)
-        want = jax.jit(lambda x: cref.run_iterations(spec, x, 3))(g)
-        assert bool(jnp.all(got == want)), boundary
-        assert bool(jnp.all(got == engine.stencil_apply(spec, g, sweeps=3)))
-
-
-@pytest.mark.parametrize("name", ["jacobi1d", "blur2d", "star33_3d"])
-def test_triton_grids_smaller_than_halo_window(name, rng):
-    """The padded-window fallback threads the lowering through too."""
-    spec = PAPER_STENCILS[name]
-    g = jnp.asarray(rng.standard_normal(TINY[spec.ndim]), jnp.float32)
-    got = gpu.stencil_apply(spec, g, sweeps=3)
-    np.testing.assert_allclose(np.asarray(got),
-                               np.asarray(_chained(spec, g, 3)), atol=1e-5)
-
-
-@pytest.mark.parametrize("name", list(PAPER_STENCILS))
-@pytest.mark.parametrize("sweeps", [1, 2])
-def test_gpu_autotuner_picks_warp_aligned_feasible_tile(name, sweeps):
-    """The GPU candidate set is warp/CTA-shaped: every chosen tile is
-    innermost warp-aligned, minimal over its candidate table, and
-    feasible under the shared-memory model.  (Unlike the 16 MB VMEM
-    model, 96 KB of shared memory genuinely cannot hold deep-sweep 3-D
-    windows — that refusal is pinned separately below.)"""
-    spec = PAPER_STENCILS[name]
-    shape = SHAPES[spec.ndim]
-    res = tune.autotune(spec, shape, sweeps=sweeps, backend="triton")
-    assert len(res.tile) == spec.ndim
-    assert np.isfinite(res.cost_s)
-    assert res.tile[-1] % pm.WARP_LANES == 0
-    assert res.cost_s == min(c for _, c in res.table)
-    assert np.isfinite(pm.triton_tile_cost(spec, shape, res.tile,
-                                           sweeps=sweeps))
-    # distinct ranking universe from the TPU model: the pallas choice
-    # for the same workload comes from the lane-aligned candidate set
-    assert tune.autotune(spec, shape, sweeps=sweeps).tile[-1] % 128 == 0 \
-        or spec.ndim == 1
-
-
-def test_gpu_autotuner_refuses_infeasible_deep_sweeps():
-    """A fused working set no candidate tile can fit in one SM's shared
-    memory is a clear lowering-time error, not a silently-wrong tile:
-    star33_3d at sweeps=4 widens every 3-D window past 96 KB (the same
-    workload autotunes fine under the 16 MB VMEM model)."""
-    spec = PAPER_STENCILS["star33_3d"]
-    with pytest.raises(ValueError, match="GPU shared memory"):
-        tune.autotune(spec, SHAPES[3], sweeps=4, backend="triton")
-    assert np.isfinite(tune.autotune(spec, SHAPES[3], sweeps=4).cost_s)
-
-
 def test_measured_autotune_disk_cache_roundtrip(tmp_path, monkeypatch, rng):
     """``CASPER_TUNE_CACHE`` persistence: the first measured tune
     misses and stores, an identical second call is served from disk
     (hit, no new store), and the cached result round-trips exactly.
     Unsetting the env var disables persistence entirely."""
     spec = PAPER_STENCILS["jacobi1d"]
-    g = jnp.asarray(rng.standard_normal((512,)), jnp.float32)
+    g = jnp.asarray(rng.standard_normal((2048,)), jnp.float32)
     monkeypatch.setenv(tune.TUNE_CACHE_ENV, str(tmp_path))
     tune.TUNE_DISK_CACHE.reset()
-    first = tune.autotune_measured(spec, g, sweeps=1, top_k=2, reps=1,
-                                   backend="triton")
+    first = tune.autotune_measured(spec, g, sweeps=1, top_k=2, reps=1)
     assert tune.TUNE_DISK_CACHE.as_dict() == {"hits": 0, "misses": 1,
                                               "stores": 1}
     assert first.measured
-    again = tune.autotune_measured(spec, g, sweeps=1, top_k=2, reps=1,
-                                   backend="triton")
+    again = tune.autotune_measured(spec, g, sweeps=1, top_k=2, reps=1)
     assert tune.TUNE_DISK_CACHE.as_dict() == {"hits": 1, "misses": 1,
                                               "stores": 1}
     assert again.tile == first.tile
     assert again.table == first.table
-    # the pallas-backend key never aliases the triton one
-    tune.autotune_measured(spec, g, sweeps=1, top_k=2, reps=1)
+    # a different measurement configuration never aliases the first
+    tune.autotune_measured(spec, g, sweeps=2, top_k=2, reps=1)
     assert tune.TUNE_DISK_CACHE.as_dict() == {"hits": 1, "misses": 2,
                                               "stores": 2}
     monkeypatch.delenv(tune.TUNE_CACHE_ENV)
     counters = tune.TUNE_DISK_CACHE.as_dict()
     tune.autotune_measured(spec, g, sweeps=1, top_k=2, reps=1)
     assert tune.TUNE_DISK_CACHE.as_dict() == counters   # untouched
-
-
-def test_calibration_overrides_rerank_and_validate(monkeypatch):
-    """``CASPER_CALIBRATION`` measured-constant overrides are consulted
-    at call time by the cost models, fingerprinted into the autotune
-    memo key, filtered to recognized keys, and validated (rates must be
-    strictly positive, overheads may clamp to zero)."""
-    spec = PAPER_STENCILS["jacobi2d"]
-    base = pm.triton_tile_cost(spec, (64, 128), (32, 64))
-    monkeypatch.setenv(pm.CALIBRATION_ENV,
-                       '{"gpu_bw": 1e6, "provenance": "test-rig"}')
-    slowed = pm.triton_tile_cost(spec, (64, 128), (32, 64))
-    assert slowed > base                      # 1 MB/s is much slower
-    assert pm.calibration() == {"gpu_bw": 1e6}   # unknown keys dropped
-    assert dict(pm.calibration_fingerprint())["gpu_bw"] == 1e6
-    # the fitted serial-host SM count saturates occupancy at one tile:
-    # a low-CTA-count tiling stops paying the unsaturated-bandwidth
-    # penalty (see fit_calibration in benchmarks/roofline_stencil.py)
-    monkeypatch.setenv(pm.CALIBRATION_ENV, '{"gpu_n_sms": 0.5}')
-    assert pm.triton_tile_cost(spec, (64, 128), (32, 64)) < base
-    monkeypatch.setenv(pm.CALIBRATION_ENV, '{"tpu_grid_step_s": 0.0}')
-    assert pm.calibration()["tpu_grid_step_s"] == 0.0   # overheads may be 0
-    for bad in ('{"gpu_bw": 0}', '{"gpu_bw": -1}', '{"gpu_bw": NaN}',
-                '{"tpu_grid_step_s": -1e-9}', '{broken'):
-        monkeypatch.setenv(pm.CALIBRATION_ENV, bad)
-        with pytest.raises(ValueError):
-            pm.calibration()
-    # anything not inline-JSON-shaped is a file path
-    monkeypatch.setenv(pm.CALIBRATION_ENV, "/nonexistent/calibration.json")
-    with pytest.raises(OSError):
-        pm.calibration()
-    monkeypatch.delenv(pm.CALIBRATION_ENV)
-    assert pm.calibration() == {}
-    assert pm.calibration_fingerprint() == ()
-    assert pm.triton_tile_cost(spec, (64, 128), (32, 64)) == base

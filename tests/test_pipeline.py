@@ -13,7 +13,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 from jax.sharding import Mesh
 
 import repro.core as rc
@@ -183,6 +183,22 @@ def test_staged_fallback_matches_oracle_f64():
             plan = _plan.lower(mixed, g.shape, g.dtype, backend=backend)
             got = np.asarray(_plan.run_plan(plan, g, 2))
             np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("name", sorted(PIPES))
+def test_padfree_pipeline_matches_oracle_f64(name):
+    # grain-aligned grid and tile: the fused chain DMAs its windows,
+    # wrapped ghost slabs included, straight from the unpadded grid
+    p = PIPES[name]
+    shape, tile = ((4096,), (1024,)) if p.ndim == 1 else ((48, 384), (16, 128))
+    with enable_x64():
+        g = _grid(shape, np.random.default_rng(13))
+        want = np.asarray(chained_oracle(p, g, iters=4))
+        plan = _plan.lower(p, shape, g.dtype, backend="pallas", sweeps=2,
+                           tile=tile)
+        assert plan.ghost_strategy == "pad-free"
+        np.testing.assert_array_equal(np.asarray(_plan.run_plan(plan, g, 4)),
+                                      want)
 
 
 @pytest.mark.parametrize("name", ["reaction_diffusion2d",

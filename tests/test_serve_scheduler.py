@@ -168,7 +168,7 @@ def test_x64_worker_serves_f64(rng):
     """``ServeConfig.x64`` makes the worker thread enable x64 itself
     (the jax context manager is thread-local): f64 grids stay f64 end to
     end and match the sequential oracle bit for bit."""
-    from jax.experimental import enable_x64
+    from jax import enable_x64
     g = rng.standard_normal((10, 14))
     assert g.dtype == np.float64
     server = AsyncStencilServer(config=ServeConfig(x64=True),

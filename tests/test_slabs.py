@@ -25,7 +25,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
+from jax import enable_x64
 
 import repro.core as rc
 from repro.core import perfmodel as pm
@@ -109,18 +109,6 @@ def test_slab_matrix_pallas(boundary, sweeps, which):
     spec = SPECS[2][which].with_boundary(boundary)
     iters = 3 if sweeps == 1 else 7
     _check_streamed(spec, SHAPES[2], sweeps, iters, "pallas")
-
-
-@pytest.mark.parametrize("boundary", ("zero", "periodic"))
-@pytest.mark.parametrize("sweeps", (1, 3))
-@pytest.mark.parametrize("which", (0, 1), ids=("star", "separable"))
-def test_slab_matrix_triton(boundary, sweeps, which):
-    """The triton (interpret) lowering streams slabs bit-identically:
-    the slab executor threads the plan backend through to the kernel
-    call, so the GPU path inherits out-of-core streaming for free."""
-    spec = SPECS[2][which].with_boundary(boundary)
-    iters = 3 if sweeps == 1 else 7
-    _check_streamed(spec, SHAPES[2], sweeps, iters, "triton")
 
 
 # ---------------------------------------------------------------------------

@@ -13,7 +13,6 @@ from repro.core import (PAPER_STENCILS, CasperEngine, StencilSpec, assemble,
                         factor_taps, plan_streams)
 from repro.core import ref as cref
 from repro.kernels import engine
-from repro.kernels import gpu
 
 SHAPES = {1: (1000,), 2: (70, 130), 3: (9, 20, 150)}
 
@@ -125,30 +124,44 @@ def test_random_coupled_specs_fall_back_dense(rng):
 # ---------------------------------------------------------------------------
 MATRIX_SPECS = ("jacobi1d", "jacobi2d", "heat3d", "blur2d", "star33_3d")
 BOUNDARIES = ("zero", "constant(0.75)", "periodic", "reflect")
+STRATEGIES = ("padded-window", "pad-free")
+
+# (grid, tile) per rank for the two kernel strategies.  The padded grids
+# are a multiple of no candidate tile, so they take the padded-window
+# fallback under the autotuned tile.  The pad-free grids are a multiple
+# of a granule-aligned tile at least as deep as the aligned fetch halo
+# of the deepest case (blur2d / star33_3d at sweeps=3), so the kernel
+# DMAs every window, wrapped ghost slabs included, from the bare grid.
+# Where the fetch halo equals the tile, the axis has more than two tiles,
+# so a tile's two neighbours (and its two ghost slabs) are distinct.
+STRATEGY_CASES = {
+    "padded-window": {1: ((260,), None), 2: ((33, 47), None),
+                      3: ((9, 13, 21), None)},
+    "pad-free": {1: ((4096,), (1024,)), 2: ((32, 384), (16, 128)),
+                 3: ((16, 24, 384), (8, 8, 128))},
+}
 
 
+@pytest.mark.parametrize("strategy", STRATEGIES)
 @pytest.mark.parametrize("name", MATRIX_SPECS)
 @pytest.mark.parametrize("boundary", BOUNDARIES)
 @pytest.mark.parametrize("sweeps", [1, 3])
 def test_structure_equivalence_matrix_f64_bitwise(name, boundary, sweeps,
-                                                  rng):
-    """The fused pad-free Pallas engine, the triton (interpret)
-    lowering of the very same kernel bodies, the jnp oracle chain and
-    the numpy oracle chain agree *bitwise* in f64 for every structure
-    class, boundary mode, rank and sweep count — they share the
-    factored compute core and its pinned accumulation order — and all
-    stay within float tolerance of the forced-dense oracle."""
-    from jax.experimental import enable_x64
+                                                  strategy, rng):
+    """The fused Pallas engine, the jnp oracle chain and the numpy
+    oracle chain agree *bitwise* in f64 for every structure
+    class, boundary mode, rank, sweep count and kernel strategy — they
+    share the factored compute core and its pinned accumulation order —
+    and all stay within float tolerance of the forced-dense oracle."""
+    from jax import enable_x64
     spec = PAPER_STENCILS[name].with_boundary(boundary)
-    shape = {1: (260,), 2: (33, 47), 3: (9, 13, 21)}[spec.ndim]
+    shape, tile = STRATEGY_CASES[strategy][spec.ndim]
     with enable_x64():
         g = jnp.asarray(rng.standard_normal(shape), jnp.float64)
-        got = engine.stencil_apply(spec, g, sweeps=sweeps)
+        assert engine._resolve_strategy(spec, g, sweeps, tile) == strategy
+        got = engine.stencil_apply(spec, g, tile=tile, sweeps=sweeps)
         want = jax.jit(lambda x: cref.run_iterations(spec, x, sweeps))(g)
         assert bool(jnp.all(got == want)), (name, boundary)
-        got_triton = gpu.stencil_apply(spec, g, sweeps=sweeps)
-        assert bool(jnp.all(got_triton == want)), (name, boundary,
-                                                   "triton")
         gn = np.asarray(g)
         for _ in range(sweeps):
             gn = cref.apply_stencil_numpy(spec, gn)
@@ -168,35 +181,61 @@ def test_dense_spec_through_engine(rng):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
 
 
+@pytest.mark.parametrize("strategy", STRATEGIES)
+@pytest.mark.parametrize("boundary", BOUNDARIES)
+def test_dense_spec_f64_bitwise(boundary, strategy, rng):
+    """The dense per-tap path is f64 bit-identical to the jnp and numpy
+    oracle chains under both kernel strategies and every boundary."""
+    from jax import enable_x64
+    spec = DENSE2D.with_boundary(boundary)
+    shape, tile = STRATEGY_CASES[strategy][2]
+    with enable_x64():
+        g = jnp.asarray(rng.standard_normal(shape), jnp.float64)
+        assert engine._resolve_strategy(spec, g, 2, tile) == strategy
+        got = engine.stencil_apply(spec, g, tile=tile, sweeps=2)
+        want = jax.jit(lambda x: cref.run_iterations(spec, x, 2))(g)
+        assert bool(jnp.all(got == want)), boundary
+        gn = np.asarray(g)
+        for _ in range(2):
+            gn = cref.apply_stencil_numpy(spec, gn)
+        np.testing.assert_array_equal(np.asarray(got), gn)
+
+
 @pytest.mark.parametrize("boundary", BOUNDARIES)
 def test_padfree_matches_padded_window_path(boundary, rng):
-    """The pad-free kernel's in-kernel ghost materialization is bitwise
-    what pad_boundary would have produced: the pad-free stencil_sweep
-    equals the legacy padded stencil_window_sweep exactly in f64."""
-    from jax.experimental import enable_x64
+    """The pad-free kernel's wrapped fetch plus in-kernel ghost
+    restoration is bitwise what pad_boundary would have produced: the
+    pad-free stencil_sweep equals the padded stencil_window_sweep
+    exactly in f64."""
+    from jax import enable_x64
     spec = PAPER_STENCILS["jacobi2d"].with_boundary(boundary)
     with enable_x64():
-        g = jnp.asarray(rng.standard_normal((70, 130)), jnp.float64)
-        sweeps = 3
-        padfree = engine.stencil_sweep(spec, g, sweeps=sweeps)
+        g = jnp.asarray(rng.standard_normal((64, 256)), jnp.float64)
+        sweeps, tile = 3, (16, 128)
+        assert engine._resolve_strategy(spec, g, sweeps, tile) == "pad-free"
+        padfree = engine.stencil_sweep(spec, g, tile=tile, sweeps=sweeps)
         wide = tuple(sweeps * h for h in spec.halo)
         window = cref.pad_boundary(g, wide, spec.boundary_mode,
                                    spec.boundary_value)
         padded = engine.stencil_window_sweep(
-            spec, window, g.shape, (0, 0), g.shape, sweeps=sweeps)
+            spec, window, g.shape, (0, 0), g.shape, tile=tile,
+            sweeps=sweeps)
         assert bool(jnp.all(padfree == padded)), boundary
 
 
-def test_periodic_large_grid_falls_back_to_padded(monkeypatch, rng):
-    """The pad-free periodic path blocks the whole grid (the wrap gather
-    needs the far edge); past the VMEM budget it must fall back to the
-    wrap-padded window path — same bits either way."""
-    from jax.experimental import enable_x64
-    monkeypatch.setattr(engine, "_PERIODIC_WHOLE_GRID_BYTES", 1024)
+def test_periodic_strategy_independent_of_grid_size(rng):
+    """Periodic grids take the same windowed pad-free fetch as every
+    other mode, at any size (the ghost slabs wrap around the grid edge
+    by DMA); a grid that is not a multiple of the tile falls back to
+    the wrap-padded window path — same bits either way."""
+    from jax import enable_x64
     spec = PAPER_STENCILS["jacobi2d"].with_boundary("periodic")
+    big = jax.ShapeDtypeStruct((4096, 4096), jnp.float32)
+    assert engine._resolve_strategy(spec, big, 4, (32, 256)) == "pad-free"
     with enable_x64():
         g = jnp.asarray(rng.standard_normal((70, 130)), jnp.float64)
-        got = engine.stencil_sweep(spec, g, sweeps=3)     # forced fallback
+        assert engine._resolve_strategy(spec, g, 3, None) == "padded-window"
+        got = engine.stencil_sweep(spec, g, sweeps=3)
         want = jax.jit(lambda x: cref.run_iterations(spec, x, 3))(g)
         assert bool(jnp.all(got == want))
 
@@ -208,6 +247,7 @@ def test_periodic_large_grid_falls_back_to_padded(monkeypatch, rng):
 # bounds on the oracle, the pass bounds every lowered plan's executor.
 # ---------------------------------------------------------------------------
 from repro.analysis import count_primitive as _count_primitive  # noqa: E402
+from repro.analysis import count_tap_windows as _count_taps  # noqa: E402
 
 
 @pytest.mark.parametrize("name", MATRIX_SPECS)
@@ -219,7 +259,7 @@ def test_jaxpr_slice_count_guard(name, rng):
     spec = PAPER_STENCILS[name]
     g = jnp.zeros(SHAPES[spec.ndim], jnp.float32)
     jaxpr = jax.make_jaxpr(lambda x: cref.apply_stencil(spec, x))(g).jaxpr
-    n_slices = _count_primitive(jaxpr, "dynamic_slice")
+    n_slices = _count_taps(jaxpr)
     fz = factor_taps(spec)
     assert n_slices <= fz.tap_ops, (name, n_slices, fz.tap_ops)
     if spec.structure == "star":
@@ -281,20 +321,12 @@ def test_interpret_auto_detection(rng, monkeypatch):
     assert engine.resolve_interpret(None) == (jax.default_backend() == "cpu")
     assert engine.resolve_interpret(True) is True
     assert engine.resolve_interpret(False) is False
-    # backend-aware resolution: on the CPU host every kernel backend
-    # interprets; a TPU host must reject the triton lowering with a
-    # clear error instead of an opaque mosaic traceback, and a GPU host
-    # compiles it.
+    # the CPU host interprets; the chip compiles
     from repro.core import plan as _plan
-    assert _plan.resolve_interpret(None, "triton") is True   # cpu host
     with monkeypatch.context() as mp:
         mp.setattr(_plan.jax, "default_backend", lambda: "tpu")
-        with pytest.raises(ValueError, match="triton"):
-            _plan.resolve_interpret(None, "triton")
-        assert _plan.resolve_interpret(None, "pallas") is False
-        assert _plan.resolve_interpret(True, "triton") is True   # explicit
-        mp.setattr(_plan.jax, "default_backend", lambda: "gpu")
-        assert _plan.resolve_interpret(None, "triton") is False
+        assert _plan.resolve_interpret(None) is False
+        assert _plan.resolve_interpret(True) is True   # explicit
     # default (None) paths run fine on CPU without passing the flag
     g = jnp.asarray(rng.standard_normal((48, 64)), jnp.float32)
     spec = PAPER_STENCILS["jacobi2d"]
