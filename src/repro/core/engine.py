@@ -49,6 +49,7 @@ from typing import Literal, Sequence
 import jax
 
 from . import plan as _plan
+from . import trace as _trace
 from .halo import distributed_stencil_fn
 from .isa import assemble_any
 from .plan import resolve_interpret  # canonical home is core.plan
@@ -123,8 +124,10 @@ class CasperEngine:
         comes from the plan cache).  A grid past the device-memory
         budget (``CASPER_SLAB_BUDGET``) transparently runs out-of-core:
         the shared runner routes it through the slab-streaming executor
-        (``kernels.stream``) and returns a host array."""
-        return self._run_jit(grid, iters=iters)
+        (``kernels.stream``) and returns a host array.  The call is the
+        profiler span ``casper.run`` (:mod:`repro.core.trace`)."""
+        with _trace.span(_trace.RUN):
+            return self._run_jit(grid, iters=iters)
 
     def analyze(self, shape: Sequence[int], dtype=None, *,
                 sweeps: int | None = None, lint: bool = True):

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from . import perfmodel as _pm
+from . import trace as _trace
 from .isa import assemble, assemble_pipeline
 from .stencil import (Factorization, StencilPipeline, StencilSpec, as_stages,
                       factor_taps)
@@ -365,8 +366,9 @@ class PlanCache:
             if hit is not None:
                 return hit
             self.lowers += 1
-            plan = factory()
-            _verify_new_plan(plan)
+            with _trace.span(_trace.LOWER, event=True):
+                plan = factory()
+                _verify_new_plan(plan)
             self.put(key, plan)
             return plan
 
@@ -408,7 +410,8 @@ def _verify_new_plan(plan) -> None:
     module for its constants and decision functions, and lowering must
     stay importable without the analysis package being touched."""
     from repro import analysis  # lazy: avoids the import cycle
-    analysis.verify_and_record(plan)
+    with _trace.span(_trace.VERIFY, event=True):
+        analysis.verify_and_record(plan)
 
 
 #: The process-wide plan cache: one per process, shared by every engine,
@@ -579,9 +582,10 @@ def _lower_pipeline_uncached(pipe, shape, dtype, backend, sweeps, tile_req,
         if tile_req == "auto":
             from repro.kernels import tune      # lazy: optional dep
             PLAN_CACHE.autotune_calls += 1
-            resolved_tile = tune.autotune_pipeline(
-                pipe, tune_shape, sweeps=sweeps,
-                itemsize=dtype.itemsize).tile
+            with _trace.span(_trace.AUTOTUNE, event=True):
+                resolved_tile = tune.autotune_pipeline(
+                    pipe, tune_shape, sweeps=sweeps,
+                    itemsize=dtype.itemsize).tile
         else:
             resolved_tile = normalize_tile(pipe, tile_req)
         if mesh is not None:
@@ -640,8 +644,10 @@ def _lower_uncached(spec, shape, dtype, backend, sweeps, tile_req, mesh,
         if tile_req == "auto":
             from repro.kernels import tune      # lazy: optional dep
             PLAN_CACHE.autotune_calls += 1
-            resolved_tile = tune.autotune(spec, tune_shape, sweeps=sweeps,
-                                          itemsize=dtype.itemsize).tile
+            with _trace.span(_trace.AUTOTUNE, event=True):
+                resolved_tile = tune.autotune(spec, tune_shape,
+                                              sweeps=sweeps,
+                                              itemsize=dtype.itemsize).tile
         else:
             resolved_tile = normalize_tile(spec, tile_req)
         if mesh is not None:
@@ -875,9 +881,3 @@ def batch_handle(spec: StencilSpec | StencilPipeline, backend: str,
                        canonical_tile_request(tile_req),
                        resolve_interpret(interpret))
 
-
-def runner_cache_stats() -> dict:
-    """Hit/miss counters of the jitted-runner caches (a runner-cache hit
-    means the second engine re-used an already-traced callable)."""
-    return {"runner": runner.cache_info()._asdict(),
-            "batch_runner": batch_runner.cache_info()._asdict()}

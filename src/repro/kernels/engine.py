@@ -78,6 +78,7 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core import plan as _plan
 from repro.core import ref as _ref
 from repro.core import perfmodel as _pm
+from repro.core import trace as _trace
 from repro.core.plan import resolve_interpret  # canonical auto-detect
 from repro.core.stencil import StencilPipeline, StencilSpec
 
@@ -113,6 +114,32 @@ def _fetch_pieces(i, tile: int, n: int, lo: int, ext: int, wrap: bool):
     return ((0, lo, (i * tile - lo + n) % n),
             (lo, tile, i * tile),
             (lo + tile, lo, (i * tile + tile) % n))
+
+
+def _kernel_tag(strategy: str, sweeps: int, tile: Sequence[int],
+                grid: Sequence[int], ext: Sequence[int],
+                itemsize: int) -> dict[str, str]:
+    """The metadata of one fused ``pallas_call``, all strings; bytes and
+    steps count one execution of the kernel op:
+
+    ``casper``       ``"fused"``;
+    ``strategy``     ``"pad-free"`` (ghost slabs fetched by wrapped DMAs
+                     from the unpadded grid) or ``"window"`` (the
+                     source already carries them: the padded-window
+                     fallback and the mesh path's exchanged block);
+    ``sweeps``       stencil applications fused in the call;
+    ``tile``         the output tile, e.g. ``"32x512"``;
+    ``grid_steps``   grid steps, a batch axis included;
+    ``fetch_bytes``  bytes all steps DMA from HBM into VMEM: each step
+                     fills one whole aligned ``ext`` buffer;
+    ``write_bytes``  bytes of all the output blocks.
+    """
+    steps = math.prod(grid)
+    return {"casper": "fused", "strategy": strategy, "sweeps": str(sweeps),
+            "tile": "x".join(str(t) for t in tile),
+            "grid_steps": str(steps),
+            "fetch_bytes": str(steps * math.prod(ext) * itemsize),
+            "write_bytes": str(steps * math.prod(tile) * itemsize)}
 
 
 def _fused_kernel(org_ref, src_ref, o_ref, buf, sem, *, core, tile, wide,
@@ -159,7 +186,8 @@ def _fused_kernel(org_ref, src_ref, o_ref, buf, sem, *, core, tile, wide,
 
 def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
                 grid_shape: Sequence[int], tile: Sequence[int],
-                wide: Sequence[int], *, fix, interpret: bool) -> jax.Array:
+                wide: Sequence[int], *, fix, sweeps: int,
+                interpret: bool) -> jax.Array:
     """The one ``pallas_call`` emitter behind every fused kernel.
 
     The source stays in HBM (``memory_space=pl.ANY``) and each grid
@@ -177,6 +205,11 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
     ghost layers per side (the padded-window fallback and the mesh
     path's exchanged block): tile ``i``'s window starts at ``i*tile``,
     and only the buffer's far end is rounded up.
+
+    The call is named :data:`repro.core.trace.KERNEL_NAME` and tagged
+    with what one execution of it does (:func:`_kernel_tag`), so a
+    profile of the chip says which strategy, tile and sweep depth each
+    kernel event ran, and how many grid steps and HBM bytes it took.
     """
     ndim = len(tile)
     tile = tuple(tile)
@@ -211,6 +244,8 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
                pltpu.SemaphoreType.DMA((n_copies,))]
 
     def emit(org, src, batch):
+        tag = _kernel_tag("pad-free" if wrap else "window", sweeps, tile,
+                          batch + grid_dims, ext, src.dtype.itemsize)
         kernel = functools.partial(
             _fused_kernel, core=core, tile=tile, wide=wide, lo=lo, cut=cut,
             grain=grain, grid_shape=grid_shape, wrap=wrap, fix=fix,
@@ -229,6 +264,8 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
             out_shape=jax.ShapeDtypeStruct(batch + padded, src.dtype),
             scratch_shapes=scratch,
             interpret=interpret,
+            name=_trace.KERNEL_NAME,
+            metadata=tag,
         )(org, src)
 
     # A memory_space=ANY operand has no pallas batching rule, so a
@@ -308,7 +345,7 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
     core = _spec_core(spec, tile, sweeps, grid_shape,
                       _acc_dtype(window.dtype))
     return _fused_call(core, window, out_shape, origin, grid_shape, tile,
-                       wide, fix=None, interpret=interpret)
+                       wide, fix=None, sweeps=sweeps, interpret=interpret)
 
 
 def _resolve_strategy(spec, grid, sweeps, tile) -> str:
@@ -358,7 +395,7 @@ def stencil_sweep(spec: StencilSpec, grid: jax.Array,
     return _fused_call(core, grid, grid.shape, (0,) * spec.ndim, grid.shape,
                        tile, wide,
                        fix=(spec.boundary_mode, spec.boundary_value),
-                       interpret=interpret)
+                       sweeps=sweeps, interpret=interpret)
 
 
 def stencil_apply(spec: StencilSpec, grid: jax.Array,
@@ -422,7 +459,7 @@ def pipeline_window_sweep(pipeline: StencilPipeline, window: jax.Array,
     core = _pipeline_core(pipeline, tile, sweeps, grid_shape,
                           _acc_dtype(window.dtype))
     return _fused_call(core, window, out_shape, origin, grid_shape, tile,
-                       wide, fix=None, interpret=interpret)
+                       wide, fix=None, sweeps=sweeps, interpret=interpret)
 
 
 def pipeline_sweep(pipeline: StencilPipeline, grid: jax.Array,
@@ -476,7 +513,7 @@ def pipeline_sweep(pipeline: StencilPipeline, grid: jax.Array,
     return _fused_call(core, grid, grid.shape, (0,) * pipeline.ndim,
                        grid.shape, tile, wide,
                        fix=(pipeline.boundary_mode, pipeline.boundary_value),
-                       interpret=interpret)
+                       sweeps=sweeps, interpret=interpret)
 
 
 def pipeline_apply(pipeline: StencilPipeline, grid: jax.Array,

@@ -90,6 +90,53 @@ def test_f64_bit_identical_to_oracle(name, strategy, rng):
             np.asarray(got), cref.apply_stencil_numpy(spec, np.asarray(g)))
 
 
+def _kernel_tags(fn, *args):
+    """``(name, metadata)`` of every ``pallas_call`` ``fn`` traces to."""
+    from repro.analysis.jaxpr_lint import _walk_eqns
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [(str(e.params["name"]), dict(e.params["metadata"]))
+            for e in _walk_eqns(jaxpr) if e.primitive.name == "pallas_call"]
+
+
+# (stencil, grid, tile, sweeps=2) -> (strategy, grid steps, fetched
+# window per step, written tile per step).  A pad-free window is
+# tile + 2*ceil_to(sweeps*halo, grain) per dim (grain (8, 128) in 2-D,
+# (1, 8, 128) in 3-D); a padded window is ceil_to(tile + 2*sweeps*halo,
+# grain) per dim.
+KERNEL_TAG_CASES = {
+    "2d-pad-free": (("jacobi2d", (64, 512), (32, 256)),
+                    ("pad-free", 2 * 2, (32 + 2 * 8) * (256 + 2 * 128),
+                     32 * 256)),
+    "3d-pad-free": (("heat3d", (16, 16, 256), (8, 8, 128)),
+                    ("pad-free", 2 * 2 * 2,
+                     (8 + 2 * 2) * (8 + 2 * 8) * (128 + 2 * 128),
+                     8 * 8 * 128)),
+    "padded-window": (("jacobi2d", (70, 130), (32, 256)),
+                      ("window", 3 * 1, 40 * 384, 32 * 256)),
+    "vmapped-bucket": (("jacobi2d", (3, 64, 512), (32, 256)),
+                       ("pad-free", 3 * 2 * 2,
+                        (32 + 2 * 8) * (256 + 2 * 128), 32 * 256)),
+}
+
+
+@pytest.mark.parametrize("case", list(KERNEL_TAG_CASES))
+def test_kernel_tag_counts_what_the_kernel_moves(case):
+    """Every fused kernel is named and tagged with its strategy, sweeps,
+    tile, grid steps and the HBM bytes one execution fetches and
+    writes (float32: 4 bytes a point)."""
+    (name, shape, tile), (strategy, steps, window, out) = (
+        KERNEL_TAG_CASES[case])
+    spec = PAPER_STENCILS[name]
+    g = jnp.zeros(shape, jnp.float32)
+    tags = _kernel_tags(lambda x: engine.stencil_apply(
+        spec, x, tile=tile, sweeps=2, interpret=True), g)
+    assert tags == [("casper_fused", {
+        "casper": "fused", "strategy": strategy, "sweeps": "2",
+        "tile": "x".join(map(str, tile)), "grid_steps": str(steps),
+        "fetch_bytes": str(steps * window * 4),
+        "write_bytes": str(steps * out * 4)})]
+
+
 def test_f64_fused_sweeps_bit_identical(rng):
     from jax import enable_x64
     spec = PAPER_STENCILS["jacobi2d"]
@@ -202,6 +249,26 @@ def test_casper_engine_pallas_sweeps(sweeps, rng):
         np.testing.assert_allclose(
             np.asarray(fused.run(g, iters=iters)),
             np.asarray(unfused.run(g, iters=iters)), atol=1e-4)
+
+
+def test_engine_run_and_lowering_are_profiler_spans(tmp_path, rng):
+    """A profile of ``CasperEngine.run`` holds its ``casper.*`` host
+    spans: the call, and the first call's plan lowering, autotune and
+    verification inside it."""
+    import glob
+    from jax.profiler import ProfileData
+    spec = PAPER_STENCILS["jacobi2d"].with_boundary("periodic")
+    g = jnp.asarray(rng.standard_normal((24, 136)), jnp.float32)
+    eng = CasperEngine(spec, backend="pallas", sweeps=2, tile="auto")
+    with jax.profiler.trace(str(tmp_path)):
+        eng.run(g, iters=4).block_until_ready()
+        eng.run(g, iters=4).block_until_ready()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    names = [ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events
+             if ev.name.startswith("casper.")]
+    assert sorted(names) == ["casper.autotune", "casper.lower",
+                             "casper.run", "casper.run", "casper.verify"]
 
 
 def test_engine_frozen_after_init(rng):

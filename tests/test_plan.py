@@ -172,6 +172,57 @@ def test_cache_eviction_order_is_lru():
 
 
 # ---------------------------------------------------------------------------
+# Set-up events: each lowering step is a /casper/ duration event
+# ---------------------------------------------------------------------------
+class _Events:
+    """The ``/casper/`` duration events recorded while open."""
+
+    def __init__(self):
+        self.names = []
+
+    def _listen(self, event, duration, **_):
+        if event.startswith("/casper/"):
+            assert duration >= 0
+            self.names.append(event)
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._listen)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._listen)
+
+
+@pytest.mark.parametrize("tile,autotunes", [("auto", 1), ((8, 128), 0)])
+def test_cold_lower_records_one_lower_event_warm_none(tile, autotunes):
+    spec = PAPER_STENCILS["jacobi2d"].with_boundary("constant(0.5)")
+    shape = (48, 136) if tile == "auto" else (56, 136)   # fresh keys
+    with _Events() as cold:
+        lower(spec, shape, jnp.float32, backend="pallas", sweeps=2,
+              tile=tile, interpret=True)
+    assert sorted(cold.names) == sorted(
+        ["/casper/casper.lower", "/casper/casper.verify"]
+        + ["/casper/casper.autotune"] * autotunes)
+    with _Events() as warm:
+        lower(spec, shape, jnp.float32, backend="pallas", sweeps=2,
+              tile=tile, interpret=True)
+    assert warm.names == []
+
+
+def test_engine_run_records_no_event_once_lowered(rng):
+    """The hot path (``CasperEngine.run``) is a profiler span only."""
+    spec = PAPER_STENCILS["heat3d"].with_boundary("reflect")
+    g = jnp.asarray(rng.standard_normal((8, 12, 40)), jnp.float32)
+    eng = CasperEngine(spec, backend="pallas", sweeps=2, tile="auto")
+    with _Events() as first:
+        eng.run(g, iters=5).block_until_ready()
+    assert first.names.count("/casper/casper.lower") == 2   # sweeps 2, 1
+    with _Events() as again:
+        eng.run(g, iters=5).block_until_ready()
+    assert again.names == []
+
+
+# ---------------------------------------------------------------------------
 # Retrace guard: second engine = zero lowers, zero autotunes, same runner
 # ---------------------------------------------------------------------------
 def test_second_identical_engine_zero_lowers_zero_autotunes(rng):

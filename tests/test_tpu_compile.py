@@ -12,6 +12,9 @@ module is imported, so every test worker collects the same tests and
 only the worker running this file loads the TPU library.
 """
 import functools
+import json
+import math
+import re
 
 import numpy as np
 import pytest
@@ -21,6 +24,7 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro.core import PAPER_PIPELINES, PAPER_STENCILS, CasperEngine
+from repro.core import perfmodel as _pm
 from repro.core import plan as _plan
 from repro.kernels import engine
 
@@ -120,3 +124,27 @@ def test_shard_local_mesh_kernel_compiles(topo):
     text = step.lower(arg).compile().as_text()
     assert "tpu_custom_call" in text
     assert "collective-permute" in text
+
+
+@pytest.mark.parametrize("name,shape", [("jacobi2d", (16384, 16384)),
+                                        ("heat3d", (512, 512, 512))])
+def test_benchmarked_runner_carries_the_kernel_tag(name, shape, one_chip):
+    """The 1,000-step solves the benchmark times: the compiled kernel op
+    is named ``casper_fused`` and its ``kernel_metadata`` states the
+    plan's strategy, sweeps and tile, and the grid steps and bytes one
+    block takes (each step fetches the aligned pad-free window)."""
+    eng = CasperEngine(PAPER_STENCILS[name], backend="pallas", sweeps=4,
+                       tile="auto", interpret=False)
+    text = _compiled_text(lambda g: eng.run(g, iters=1000), shape,
+                          sharding=one_chip)
+    assert re.search(r"%casper_fused[.\d]* = .* custom-call\(", text)
+    tags = [json.loads(m) for m in re.findall(
+        r"kernel_metadata=(\{.*?\})\}", text, flags=re.DOTALL)]
+    plan = eng.plan_for(shape, jnp.float32)
+    steps = math.prod(n // t for n, t in zip(shape, plan.tile))
+    window = _pm.fetch_window(plan.tile, plan.deep_halo, 4)
+    assert tags == [{
+        "casper": "fused", "strategy": "pad-free", "sweeps": "4",
+        "tile": "x".join(map(str, plan.tile)), "grid_steps": str(steps),
+        "fetch_bytes": str(steps * math.prod(window) * 4),
+        "write_bytes": str(math.prod(shape) * 4)}]
