@@ -3,8 +3,9 @@
 The paper evaluates Casper in gem5.  We cannot run gem5 here, so this module
 re-derives the paper's Figures 10-13 and Tables 5-6 from an explicit
 first-order bottleneck model parameterized by the paper's own Table 2
-constants.  Every constant is either taken verbatim from the paper or marked
-CALIBRATED with its provenance; `benchmarks/` report model-vs-paper deltas
+constants.  Every constant is either taken verbatim from the paper, marked
+CALIBRATED with its provenance, or (the TPU tile model's) fitted to chip
+timings named beside it; `benchmarks/` report model-vs-paper deltas
 cell by cell, so the faithfulness of the reproduction is measurable.
 
 Units: seconds, bytes, Joules.  One "sweep" = one stencil application over
@@ -18,7 +19,8 @@ import os
 
 from .isa import Program, assemble
 from .segment import SegmentConfig, remote_fraction
-from .stencil import PAPER_STENCILS, DOMAIN_SIZES, StencilSpec
+from .stencil import (PAPER_STENCILS, DOMAIN_SIZES, StencilPipeline,
+                      StencilSpec)
 
 # ----------------------------------------------------------------------------
 # Machine constants (Table 2 unless noted)
@@ -204,14 +206,20 @@ def casper_sweep(
 # v5e figures. HBM matches repro.roofline.HBM_BW (single source for the
 # roofline benches; duplicated here so perfmodel stays import-light).
 TPU_HBM_BW = 819e9
-TPU_VMEM_BYTES = 16 * 1024 * 1024    # per-core VMEM (Pallas guide)
-TPU_VPU_FLOPS_F32 = 4.9e12           # element-wise f32 peak; CALIBRATED:
-                                     # 8x128 lanes x 2 ops x ~0.6 util @ 4 GHz-
-                                     # equivalent issue; only the traffic term
-                                     # ever binds for paper stencils (Fig. 1)
-TPU_GRID_STEP_S = 2e-7               # per-grid-step sequencing overhead;
-                                     # CALIBRATED: favors tiles >= a few KB,
-                                     # same role as GPU_LAUNCH_S above
+TPU_VMEM_BYTES = 16 * 1024 * 1024    # Mosaic's default scoped VMEM limit
+# The two constants below are least-squares fits (relative error) of
+# pallas_tile_cost's form to one fused call of the pad-free kernel on a
+# TPU v5e: jacobi2d 16384^2 and heat3d 512^3, f32, zero boundary, 12
+# 2-D and 11 3-D tiles at sweeps=4 plus two tiles each at sweeps=1 and
+# 2 (31 timings; residuals -18%..+13%, rms 8%).  See docs/kernels.md.
+TPU_VPU_FLOPS_F32 = 1.67e12          # effective rate of the compute term:
+                                     # structured flops at VPU-padded
+                                     # window points, sweeps and ghost
+                                     # restore included
+TPU_GRID_STEP_S = 1.02e-6            # fixed cost of one grid step: its
+                                     # sequencing, the window's DMA starts
+                                     # and waits, the cut and the ghost
+                                     # restore
 VPU_SUBLANES, VPU_LANES = 8, 128     # f32 min tile (sublane x lane)
 
 
@@ -331,19 +339,37 @@ def pad_free_fetch(shape: tuple[int, ...], tile: tuple[int, ...],
     return all(n % t == 0 and t >= f for n, t, f in zip(shape, tile, lo))
 
 
+def _vreg_padded(dims) -> int:
+    """Elements of an array of extents ``dims`` padded to whole f32
+    vregs: the last two dims round up to (sublane, lane)."""
+    dims = list(dims)
+    dims[-1] = _ceil_to(dims[-1], VPU_LANES)
+    if len(dims) >= 2:
+        dims[-2] = _ceil_to(dims[-2], VPU_SUBLANES)
+    return math.prod(dims)
+
+
 def vmem_residency(tile: tuple[int, ...], halo: tuple[int, ...],
                    sweeps: int = 1, itemsize: int = 4,
                    n_terms: int = 1) -> int:
     """Bytes resident in VMEM during one grid step of the fused kernel:
-    the aligned DMA buffer (:func:`fetch_window`), the window and a
-    same-size accumulator at the accumulation width, one live
-    window-sized intermediate per extra factored term, and the
-    double-buffered output block."""
+    the aligned DMA buffer (:func:`fetch_window`), the double-buffered
+    output block, and the values the kernel body keeps live at the
+    accumulation width, each padded to whole vregs: the window, its
+    accumulator and one tap temporary, plus one window-sized
+    intermediate per extra factored term.
+
+    Three live windows is what Mosaic allocates for the v5e: its scoped
+    allocation for the pad-free jacobi2d and heat3d kernels at
+    sweeps=4, over tiles from (32, 512) to (256, 2048) and from
+    (8, 16, 128) to (32, 32, 512), exceeds the buffers by 1.6 to 3.0
+    padded windows, and it refuses (32, 32, 512) (20.97 MiB), which two
+    unpadded windows counted at 15.97 MiB."""
     acc_itemsize = max(itemsize, 4)
     deep = tuple(sweeps * h for h in halo)
-    window = math.prod(tile_window(tile, halo, sweeps))
+    window = _vreg_padded(tile_window(tile, halo, sweeps))
     fetch = math.prod(fetch_window(tile, deep, itemsize))
-    return (fetch * itemsize + (1 + n_terms) * window * acc_itemsize
+    return (fetch * itemsize + (2 + n_terms) * window * acc_itemsize
             + 2 * math.prod(tile) * itemsize)
 
 
@@ -361,6 +387,74 @@ def _window_traffic(shape: tuple[int, ...], tile: tuple[int, ...],
     return traffic
 
 
+def _tile_flops(spec: StencilSpec, tile: tuple[int, ...],
+                sweeps: int) -> tuple[int, int]:
+    """Flops of one grid step of the fused kernel, and of them the
+    ``reflect`` re-mirror's: ``sweeps`` applications at their shrinking
+    VPU-padded windows at the structured per-point count, plus, for
+    ``reflect``, one per-axis gather pass over every intermediate
+    window (the other modes' fix-up is a masked select already folded
+    into the tap accounting)."""
+    halo = spec.halo
+
+    def padded_points(layers: int) -> int:
+        return _vreg_padded(t + 2 * layers * h for t, h in zip(tile, halo))
+
+    flops = sum(padded_points(sweeps - 1 - s)
+                for s in range(sweeps)) * spec.structured_flops_per_point()
+    mirror = 0
+    if spec.boundary_mode == "reflect":
+        mirror = sum(padded_points(sweeps - 1 - s)
+                     for s in range(sweeps - 1)) * len(tile)
+    return flops + mirror, mirror
+
+
+def _pipeline_tile_flops(pipeline, tile: tuple[int, ...],
+                         sweeps: int) -> tuple[int, int]:
+    """:func:`_tile_flops` for a fused chain, walking the exact
+    element-layer schedule of ``ref.masked_window_pipeline``: each stage
+    application at its shrinking window with its own structured count,
+    and a reflect-mode next stage's re-mirror on the intermediate."""
+    stages = pipeline.stages
+    n = len(stages)
+    rem = tuple(sweeps * h for h in pipeline.halo)
+    flops = mirror = 0
+    for step in range(sweeps * n):
+        stage = stages[step % n]
+        rem = tuple(r - h for r, h in zip(rem, stage.halo))
+        pts = _vreg_padded(t + 2 * r for t, r in zip(tile, rem))
+        flops += pts * stage.structured_flops_per_point()
+        if (step + 1 < sweeps * n
+                and stages[(step + 1) % n].boundary_mode == "reflect"):
+            mirror += pts * len(tile)
+    return flops + mirror, mirror
+
+
+#: Largest fused-kernel body, in f32 vreg operations (one grid step's
+#: flops over 1024 lanes x sublanes), that :func:`compiles_quickly`
+#: admits.  Mosaic unrolls the body per vreg, so its compile time
+#: follows the body: on a TPU v5e's host the pad-free jacobi2d and
+#: heat3d kernels at sweeps=4 compiled within 1 s of the (32, 512) and
+#: (8, 16, 128) kernels' 0.43 and 0.74 s for every tile of at most
+#: 9296 vreg operations, and 1.5 s or more longer from 11230 on.
+TPU_QUICK_COMPILE_VREG_OPS = 10_000
+
+
+def compiles_quickly(spec, tile: tuple[int, ...], sweeps: int = 1) -> bool:
+    """Whether Mosaic compiles the fused kernel of ``spec`` (a
+    :class:`StencilSpec` or a fused pipeline) at ``tile`` about as fast
+    as at the narrow tiles: a body of at most
+    :data:`TPU_QUICK_COMPILE_VREG_OPS` vreg operations that re-mirrors
+    no reflect ghosts between sweeps.  A re-mirroring body compiled 2.6
+    to 13 times longer than the same body with zero ghosts (jacobi2d,
+    blur2d, heat3d and star33_3d at sweeps=4, compiled for a described
+    v5e), far more than its flops account for."""
+    flops, mirror = (_pipeline_tile_flops(spec, tile, sweeps)
+                     if isinstance(spec, StencilPipeline)
+                     else _tile_flops(spec, tile, sweeps))
+    return not mirror and flops / 1024 <= TPU_QUICK_COMPILE_VREG_OPS
+
+
 def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
                      tile: tuple[int, ...], sweeps: int = 1,
                      itemsize: int = 4) -> float:
@@ -368,10 +462,12 @@ def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
     with output block ``tile`` (the kernels/engine.py temporal-blocking
     kernel).  Returns ``inf`` when the VMEM working set cannot fit.
 
-    First-order bottleneck model in the style of the Casper/CPU models
-    above: time = max(HBM traffic, VPU compute) + grid sequencing.  The
-    traffic term charges each tile one window read (halo widened to
-    ``sweeps*halo``) plus one tile write — against the *unpadded* grid;
+    First-order model of what a grid step does: it waits for its whole
+    window DMA, then computes, so time = HBM traffic + VPU compute +
+    a fixed cost per grid step (:data:`TPU_GRID_STEP_S`), fitted to the
+    chip.  The traffic term charges each tile one window read (halo
+    widened to ``sweeps*halo``) plus one tile write — against the
+    *unpadded* grid;
     the pad-free engine materializes boundary ghosts in-kernel, so no
     host-side pad traffic enters (the removed pad copy is charged to
     the unfused baseline by ``kernels.engine.hbm_traffic``).  The
@@ -409,22 +505,9 @@ def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
                               itemsize)
     t_mem = traffic / TPU_HBM_BW
 
-    def padded_points(layers: int) -> int:
-        dims = [t + 2 * layers * h for t, h in zip(tile, halo)]
-        dims[-1] = _ceil_to(dims[-1], VPU_LANES)
-        if len(dims) >= 2:
-            dims[-2] = _ceil_to(dims[-2], VPU_SUBLANES)
-        return math.prod(dims)
-
-    flops = sum(padded_points(sweeps - 1 - s)
-                * spec.structured_flops_per_point()
-                for s in range(sweeps)) * n_tiles
-    if spec.boundary_mode == "reflect":
-        # one elementwise gather pass per axis per intermediate window
-        flops += sum(padded_points(sweeps - 1 - s) * len(tile)
-                     for s in range(sweeps - 1)) * n_tiles
-    t_compute = flops / TPU_VPU_FLOPS_F32
-    return max(t_mem, t_compute) + n_tiles * TPU_GRID_STEP_S
+    t_compute = (_tile_flops(spec, tile, sweeps)[0] * n_tiles
+                 / TPU_VPU_FLOPS_F32)
+    return t_mem + t_compute + n_tiles * TPU_GRID_STEP_S
 
 
 def pallas_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
@@ -460,29 +543,9 @@ def pallas_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
                               tuple(sweeps * h for h in big_halo), itemsize)
     t_mem = traffic / TPU_HBM_BW
 
-    def padded_points(rem: tuple[int, ...]) -> int:
-        dims = [t + 2 * r for t, r in zip(tile, rem)]
-        dims[-1] = _ceil_to(dims[-1], VPU_LANES)
-        if len(dims) >= 2:
-            dims[-2] = _ceil_to(dims[-2], VPU_SUBLANES)
-        return math.prod(dims)
-
-    n = len(stages)
-    total = sweeps * n
-    rem = tuple(sweeps * h for h in big_halo)
-    flops = 0
-    step = 0
-    for _ in range(sweeps):
-        for k, stage in enumerate(stages):
-            rem = tuple(r - h for r, h in zip(rem, stage.halo))
-            pts = padded_points(rem)
-            flops += pts * stage.structured_flops_per_point()
-            step += 1
-            if (step < total
-                    and stages[(k + 1) % n].boundary_mode == "reflect"):
-                flops += pts * len(tile)
-    t_compute = flops * n_tiles / TPU_VPU_FLOPS_F32
-    return max(t_mem, t_compute) + n_tiles * TPU_GRID_STEP_S
+    t_compute = (_pipeline_tile_flops(pipeline, tile, sweeps)[0] * n_tiles
+                 / TPU_VPU_FLOPS_F32)
+    return t_mem + t_compute + n_tiles * TPU_GRID_STEP_S
 
 
 # ----------------------------------------------------------------------------

@@ -43,6 +43,7 @@ from typing import Sequence
 from repro.core import perfmodel as pm
 from repro.core.stencil import StencilSpec
 
+#: Output tiles the autotuner ranks for every kernel.
 CANDIDATE_TILES: dict[int, tuple[tuple[int, ...], ...]] = {
     1: ((1024,), (2048,), (4096,), (8192,), (16384,)),
     2: ((8, 128), (8, 256), (16, 128), (16, 256), (32, 128), (32, 256),
@@ -51,15 +52,34 @@ CANDIDATE_TILES: dict[int, tuple[tuple[int, ...], ...]] = {
         (4, 16, 256), (8, 8, 128), (4, 32, 128), (2, 32, 256)),
 }
 
+#: Wider tiles, ranked too where Mosaic compiles the kernel body about
+#: as fast as at the tiles above (``perfmodel.compiles_quickly``).  On
+#: a TPU v5e they amortise the ~1 us fixed cost of a grid step: (128,
+#: 1024) ran a 16384^2 jacobi2d block at sweeps=4 in 13.57 ms against
+#: 33.16 ms at (32, 512), (8, 32, 256) a 512^3 heat3d block in 16.07
+#: against 29.34 ms at (8, 16, 128).  Larger tiles ran faster still but
+#: compiled 1.5 s or more longer.
+WIDE_TILES: dict[int, tuple[tuple[int, ...], ...]] = {
+    2: ((64, 512), (64, 1024), (128, 512), (128, 1024)),
+    3: ((8, 32, 256),),
+}
+
 
 def candidate_tiles(ndim: int,
                     shape: Sequence[int] | None = None,
-                    itemsize: int = 4) -> tuple[tuple[int, ...], ...]:
+                    itemsize: int = 4, spec=None,
+                    sweeps: int = 1) -> tuple[tuple[int, ...], ...]:
     """Candidates for ``ndim`` whose extents are multiples of the HBM
     granule for ``itemsize``, dropping tiles absurdly larger than the
-    grid (a tile more than 4x the padded extent wastes every lane)."""
+    grid (a tile more than 4x the padded extent wastes every lane).
+    Given the ``spec`` (or fused pipeline) and ``sweeps`` of the kernel,
+    the :data:`WIDE_TILES` whose body compiles quickly join them."""
+    tiles = CANDIDATE_TILES[ndim]
+    if spec is not None:
+        tiles += tuple(t for t in WIDE_TILES.get(ndim, ())
+                       if pm.compiles_quickly(spec, t, sweeps))
     grain = pm.fetch_grain(ndim, itemsize)
-    cands = tuple(t for t in CANDIDATE_TILES[ndim]
+    cands = tuple(t for t in tiles
                   if all(td % g == 0 for td, g in zip(t, grain)))
     if shape is None:
         return cands
@@ -91,21 +111,22 @@ def autotune(spec: StencilSpec, shape: tuple[int, ...], sweeps: int = 1,
     return _autotune(spec, tuple(shape), sweeps, itemsize)
 
 
-def _rank(name, ndim, shape, sweeps, itemsize, cost) -> TuneResult:
-    scored = sorted(((tile, cost(tile))
-                     for tile in candidate_tiles(ndim, shape, itemsize)),
+def _rank(kernel, shape, sweeps, itemsize, cost) -> TuneResult:
+    tiles = candidate_tiles(kernel.ndim, shape, itemsize, kernel, sweeps)
+    scored = sorted(((tile, cost(tile)) for tile in tiles),
                     key=lambda tc: tc[1])
     best, best_cost = scored[0]
     if math.isinf(best_cost):
         raise ValueError(
-            f"no candidate tile fits VMEM for {name} sweeps={sweeps}")
+            f"no candidate tile fits VMEM for {kernel.name} "
+            f"sweeps={sweeps}")
     return TuneResult(best, best_cost, tuple(scored))
 
 
 @functools.lru_cache(maxsize=512)
 def _autotune(spec: StencilSpec, shape: tuple[int, ...], sweeps: int,
               itemsize: int) -> TuneResult:
-    return _rank(spec.name, spec.ndim, shape, sweeps, itemsize,
+    return _rank(spec, shape, sweeps, itemsize,
                  lambda tile: pm.pallas_tile_cost(
                      spec, shape, tile, sweeps=sweeps, itemsize=itemsize))
 
@@ -123,7 +144,7 @@ def autotune_pipeline(pipeline, shape: tuple[int, ...], sweeps: int = 1,
 @functools.lru_cache(maxsize=512)
 def _autotune_pipeline(pipeline, shape: tuple[int, ...], sweeps: int,
                        itemsize: int) -> TuneResult:
-    return _rank(pipeline.name, pipeline.ndim, shape, sweeps, itemsize,
+    return _rank(pipeline, shape, sweeps, itemsize,
                  lambda tile: pm.pallas_pipeline_tile_cost(
                      pipeline, shape, tile, sweeps=sweeps,
                      itemsize=itemsize))

@@ -11,6 +11,7 @@ second identical lower re-runs zero analyses, strict mode raises
 ``PlanVerificationError`` and keeps the bad plan out of the cache.
 """
 import dataclasses
+import math
 import warnings
 
 import numpy as np
@@ -322,17 +323,32 @@ def test_engine_analyze_end_to_end():
 def test_vmem_residency_charges_aligned_fetch_buffer():
     """The kernel DMAs each window rounded out to the HBM granule
     ((8, 128) for f32 rank 2), so the resident set charges that buffer
-    on top of the exact window, its accumulator and the double-buffered
-    output block."""
+    on top of three live windows (window, accumulator, tap temporary),
+    each padded to whole vregs, and the double-buffered output block."""
     spec = PAPER_STENCILS["jacobi2d"]           # star, halo (1, 1)
     tile = (32, 256)
     assert pm.fetch_window(tile, (4, 4), 4) == (48, 512)
-    expect = (48 * 512 * 4 + 2 * (40 * 264) * 4 + 2 * 32 * 256 * 4)
+    # the 40 x 264 window takes 40 x 384 in whole (8, 128) vregs
+    expect = (48 * 512 * 4 + 3 * (40 * 384) * 4 + 2 * 32 * 256 * 4)
     assert pm.vmem_residency(tile, spec.halo, 4, 4, 1) == expect
     # bf16 packs two rows per sublane: the row granule doubles
     assert pm.fetch_grain(2, 2) == (16, 128)
     assert pm.fetch_grain(1, 4) == (1024,)
     assert pm.fetch_grain(3, 4) == (1, 8, 128)
+
+
+@pytest.mark.parametrize("tile,fits", [((16, 32, 512), True),
+                                       ((8, 64, 512), True),
+                                       ((32, 32, 512), False)])
+def test_vmem_residency_refuses_what_mosaic_refuses(tile, fits):
+    """heat3d at sweeps=4: Mosaic for the v5e allocates 12.00 and 13.60
+    MiB of scoped VMEM for the first two tiles and refuses the third
+    (20.97 MiB over the 16 MiB limit); the model agrees on each."""
+    spec = PAPER_STENCILS["heat3d"]
+    vmem = pm.vmem_residency(tile, spec.halo, 4, 4, 1)
+    assert (vmem <= pm.TPU_VMEM_BYTES) is fits
+    assert math.isinf(pm.pallas_tile_cost(spec, (512, 512, 512), tile,
+                                          sweeps=4)) is not fits
 
 
 def test_verifier_vmem_check_uses_residency_math():
