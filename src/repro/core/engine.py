@@ -157,14 +157,23 @@ class CasperEngine:
         shard-local sweeps) and the Pallas backend apply in the
         distributed path exactly as in :meth:`run`; ``iters`` decomposes
         as ``q*sweeps + r`` the same way (both through the plan's
-        ``decompose``).
+        ``decompose``).  Each call of the returned function is the
+        profiler span ``casper.run``, as :meth:`run` is; its ``lower``
+        is the jitted function's, for ahead-of-time callers.
         """
-        return distributed_stencil_fn(
+        fn = distributed_stencil_fn(
             self.spec, mesh, grid_axes, iters,
             sweeps=self.sweeps if sweeps is None else sweeps,
             backend=self.backend if backend is None else backend,
             tile=self.tile if tile is CasperEngine._INHERIT else tile,
             interpret=self.interpret)
+
+        @functools.wraps(fn)
+        def run(grid: jax.Array) -> jax.Array:
+            with _trace.span(_trace.RUN):
+                return fn(grid)
+        run.lower = fn.lower
+        return run
 
     # Casper API surface (Table 1), as thin documentation shims -------------
     def init_stencil_segment(self, size_bytes: int) -> SegmentConfig:

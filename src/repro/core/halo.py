@@ -44,6 +44,7 @@ docs/boundaries.md).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Callable, Literal, Sequence
 
 import jax
@@ -55,6 +56,13 @@ from . import plan as _plan
 from . import ref as _ref
 from . import stencil as _stencil
 from .stencil import StencilSpec
+
+
+def _hop_widths(halo: int, size: int) -> list[int]:
+    """Width of the piece neighbour ``j`` (1, 2, ...) contributes to a
+    ``halo``-deep exchange of ``size``-long blocks: its edge nearest to
+    us, whole blocks except (possibly) the farthest hop."""
+    return [min(size, halo - j * size) for j in range(-(-halo // size))]
 
 
 def exchange_halo_1axis(x: jax.Array, axis: int, halo: int,
@@ -83,12 +91,8 @@ def exchange_halo_1axis(x: jax.Array, axis: int, halo: int,
         strategy = _plan.exchange_strategy_for(mode)
     n = lax.psum(1, axis_name)  # static mesh size along the axis
     size = x.shape[axis]
-    hops = -(-halo // size)
     from_left, from_right = [], []
-    for j in range(1, hops + 1):
-        # the piece neighbor ±j contributes: its edge nearest to us,
-        # full blocks except (possibly) the farthest hop.
-        w = min(size, halo - (j - 1) * size)
+    for j, w in enumerate(_hop_widths(halo, size), start=1):
         right_edge = lax.slice_in_dim(x, size - w, size, axis=axis)
         left_edge = lax.slice_in_dim(x, 0, w, axis=axis)
         if strategy == "wrap-ring":     # wrap-around ring, every device
@@ -134,6 +138,34 @@ def _fix_edge_ghosts_1axis(padded: jax.Array, axis: int, halo: int,
     return _ref.reflect_gather(padded, axis, start - halo, grid_n, halo)
 
 
+def exchange_tag(plan: "_plan.ExecutionPlan", block: Sequence[int],
+                 itemsize: int) -> dict[str, str]:
+    """The mesh path's fields of the shard-local kernel's tag
+    (``kernels.engine._kernel_tag``): ``shards``, the mesh's extent
+    along each sharded grid dim (``"2x2"``), and ``exchange_bytes``,
+    the bytes a ``block``-shaped shard receives from its neighbours in
+    one fused block's exchange (:func:`_local_multisweep`'s order, so an
+    axis exchanged later carries the corners of those before it; every
+    hop counted; no bytes where a grid edge has no sender; the mean
+    over the shards where the mesh's edges make them differ)."""
+    shape = list(block)
+    received = 0.0
+    shards = []
+    for d, name in enumerate(plan.grid_axes):
+        if name is not None:
+            n = plan.mesh.shape[name]
+            shards.append(str(n))
+            across = math.prod(shape) // shape[d]
+            for j, w in enumerate(_hop_widths(plan.deep_halo[d], shape[d]),
+                                  start=1):
+                senders = (n if plan.exchange[d] == "wrap-ring"
+                           else max(n - j, 0))
+                received += 2 * w * across * senders / n
+        shape[d] += 2 * plan.deep_halo[d]
+    return {"shards": "x".join(shards),
+            "exchange_bytes": str(round(received * itemsize))}
+
+
 def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
     """Shard-local fused compute: widen the block by ``sweeps*halo`` once
     (exchange on sharded dims per the plan's per-axis strategy,
@@ -160,6 +192,7 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
             padded = _ref.pad_boundary(padded, pad, mode, value)
             origin.append(0)
             grid_shape.append(x.shape[d])
+    mesh_tag = exchange_tag(plan, x.shape, x.dtype.itemsize)
     if plan.is_pipeline:
         # Fused chain on the widened block: the exchange above already
         # fetched the sweeps * sum-of-stage-radii deep halo (plan.halo is
@@ -170,7 +203,8 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
             from repro.kernels import engine as keng  # lazy: optional dep
             return keng.pipeline_window_sweep(
                 spec, padded, x.shape, origin, grid_shape,
-                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret)
+                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
+                mesh_tag=mesh_tag)
         return _ref.masked_window_pipeline(
             padded, spec.stages, x.shape, plan.sweeps, origin, grid_shape,
             x.dtype).astype(x.dtype)
@@ -178,7 +212,8 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
         from repro.kernels import engine as keng  # lazy: optional dep
         return keng.stencil_window_sweep(
             spec, padded, x.shape, origin, grid_shape,
-            tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret)
+            tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
+            mesh_tag=mesh_tag)
     return _ref.masked_window_sweeps(
         padded, spec.taps, halo, x.shape, plan.sweeps, origin, grid_shape,
         x.dtype, mode=mode, value=value,

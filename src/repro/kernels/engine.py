@@ -118,7 +118,7 @@ def _fetch_pieces(i, tile: int, n: int, lo: int, ext: int, wrap: bool):
 
 def _kernel_tag(strategy: str, sweeps: int, tile: Sequence[int],
                 grid: Sequence[int], ext: Sequence[int],
-                itemsize: int) -> dict[str, str]:
+                itemsize: int, mesh_tag=None) -> dict[str, str]:
     """The metadata of one fused ``pallas_call``, all strings; bytes and
     steps count one execution of the kernel op:
 
@@ -133,13 +133,19 @@ def _kernel_tag(strategy: str, sweeps: int, tile: Sequence[int],
     ``fetch_bytes``  bytes all steps DMA from HBM into VMEM: each step
                      fills one whole aligned ``ext`` buffer;
     ``write_bytes``  bytes of all the output blocks.
+
+    ``mesh_tag`` adds the mesh path's fields
+    (:func:`repro.core.halo.exchange_tag`): ``shards``, the mesh's
+    extent per sharded grid dim (``"2x2"``), and ``exchange_bytes``,
+    the bytes a shard received in the halo exchange of this block.
     """
     steps = math.prod(grid)
     return {"casper": "fused", "strategy": strategy, "sweeps": str(sweeps),
             "tile": "x".join(str(t) for t in tile),
             "grid_steps": str(steps),
             "fetch_bytes": str(steps * math.prod(ext) * itemsize),
-            "write_bytes": str(steps * math.prod(tile) * itemsize)}
+            "write_bytes": str(steps * math.prod(tile) * itemsize),
+            **(mesh_tag or {})}
 
 
 def _fused_kernel(org_ref, src_ref, o_ref, buf, sem, *, core, tile, wide,
@@ -187,7 +193,7 @@ def _fused_kernel(org_ref, src_ref, o_ref, buf, sem, *, core, tile, wide,
 def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
                 grid_shape: Sequence[int], tile: Sequence[int],
                 wide: Sequence[int], *, fix, sweeps: int,
-                interpret: bool) -> jax.Array:
+                interpret: bool, mesh_tag=None) -> jax.Array:
     """The one ``pallas_call`` emitter behind every fused kernel.
 
     The source stays in HBM (``memory_space=pl.ANY``) and each grid
@@ -209,7 +215,8 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
     The call is named :data:`repro.core.trace.KERNEL_NAME` and tagged
     with what one execution of it does (:func:`_kernel_tag`), so a
     profile of the chip says which strategy, tile and sweep depth each
-    kernel event ran, and how many grid steps and HBM bytes it took.
+    kernel event ran, and how many grid steps and HBM bytes it took;
+    ``mesh_tag`` adds what the mesh path exchanged for it.
     """
     ndim = len(tile)
     tile = tuple(tile)
@@ -245,7 +252,8 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
 
     def emit(org, src, batch):
         tag = _kernel_tag("pad-free" if wrap else "window", sweeps, tile,
-                          batch + grid_dims, ext, src.dtype.itemsize)
+                          batch + grid_dims, ext, src.dtype.itemsize,
+                          mesh_tag)
         kernel = functools.partial(
             _fused_kernel, core=core, tile=tile, wide=wide, lo=lo, cut=cut,
             grain=grain, grid_shape=grid_shape, wrap=wrap, fix=fix,
@@ -316,7 +324,8 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
                          grid_shape: Sequence[int],
                          tile: Sequence[int] | int | None = None,
                          sweeps: int = 1,
-                         interpret: bool | None = None) -> jax.Array:
+                         interpret: bool | None = None,
+                         mesh_tag=None) -> jax.Array:
     """``sweeps`` fused applications to a block that already carries its
     ``sweeps*halo``-wide halo.
 
@@ -328,7 +337,8 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
     shard_map) of a ``grid_shape`` grid, against which the between-sweep
     ghost restoration is evaluated.  This is the shard-local entry point
     of the distributed deep-halo path; :func:`stencil_sweep` uses it for
-    the single-device padded-window fallback.
+    the single-device padded-window fallback.  ``mesh_tag`` (the mesh
+    path's) adds its fields to the kernel tag (:func:`_kernel_tag`).
     """
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
@@ -345,7 +355,8 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
     core = _spec_core(spec, tile, sweeps, grid_shape,
                       _acc_dtype(window.dtype))
     return _fused_call(core, window, out_shape, origin, grid_shape, tile,
-                       wide, fix=None, sweeps=sweeps, interpret=interpret)
+                       wide, fix=None, sweeps=sweeps, interpret=interpret,
+                       mesh_tag=mesh_tag)
 
 
 def _resolve_strategy(spec, grid, sweeps, tile) -> str:
@@ -431,7 +442,8 @@ def pipeline_window_sweep(pipeline: StencilPipeline, window: jax.Array,
                           grid_shape: Sequence[int],
                           tile: Sequence[int] | int | None = None,
                           sweeps: int = 1,
-                          interpret: bool | None = None) -> jax.Array:
+                          interpret: bool | None = None,
+                          mesh_tag=None) -> jax.Array:
     """``sweeps`` fused chain applications to a block that already
     carries its ``sweeps * H`` halo (``H`` = summed stage radii) filled
     with stage 0's boundary extension — the pipeline analogue of
@@ -459,7 +471,8 @@ def pipeline_window_sweep(pipeline: StencilPipeline, window: jax.Array,
     core = _pipeline_core(pipeline, tile, sweeps, grid_shape,
                           _acc_dtype(window.dtype))
     return _fused_call(core, window, out_shape, origin, grid_shape, tile,
-                       wide, fix=None, sweeps=sweeps, interpret=interpret)
+                       wide, fix=None, sweeps=sweeps, interpret=interpret,
+                       mesh_tag=mesh_tag)
 
 
 def pipeline_sweep(pipeline: StencilPipeline, grid: jax.Array,

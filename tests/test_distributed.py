@@ -237,6 +237,84 @@ def test_engine_distributed_fn_inherits_engine_options():
     """)
 
 
+def test_mesh_kernel_tag_carries_shards_and_exchange_bytes():
+    """The shard-local kernel's tag adds ``shards`` and the bytes a shard
+    received in the block's exchange (an axis exchanged later carries
+    the corners of the one before; hops past the grid's edge send
+    nothing); a single-device tag keeps its seven fields; each call of
+    ``distributed_fn``'s function is a ``casper.run`` span and it still
+    lowers ahead of time."""
+    out = run_sub(8, """
+        import contextlib
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from repro.analysis.jaxpr_lint import _walk_eqns
+        from repro.core import (PAPER_PIPELINES, CasperEngine, blur2d,
+                                heat3d)
+        from repro.core import trace as _trace
+
+        def tags(fn, x):
+            jaxpr = jax.make_jaxpr(fn)(x).jaxpr
+            return [dict(e.params["metadata"]) for e in _walk_eqns(jaxpr)
+                    if e.primitive.name == "pallas_call"]
+
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("sx", "sy"))
+        axes = ("sx", "sy", None)
+        x = jax.device_put(jnp.ones((32, 32, 256), jnp.float32),
+                           NamedSharding(mesh, P(*axes)))
+        eng = CasperEngine(heat3d(), backend="pallas", sweeps=4, tile="auto")
+        # 16x16x256 a shard, one neighbour per axis: a 4-deep face on
+        # sx, then a 4-deep face 16+8 wide on sy; 6 steps add a 2-deep
+        # remainder block
+        four = (4 * 16 * 256 + 4 * 24 * 256) * 4
+        two = (2 * 16 * 256 + 2 * 20 * 256) * 4
+        got = tags(eng.distributed_fn(mesh, axes, iters=6), x)
+        assert [(t["shards"], t["exchange_bytes"], t["strategy"])
+                for t in got] == [("2x2", str(four), "window"),
+                                  ("2x2", str(two), "window")], got
+
+        # a 6-wide shard under an 8-deep halo: hops of 6 and 2 points,
+        # 7 and 6 of the 8 shards with a sender, rows 16+16 wide
+        mesh18 = jax.make_mesh((1, 8), ("sx", "sy"))
+        y = jax.device_put(jnp.ones((16, 48), jnp.float32),
+                           NamedSharding(mesh18, P("sx", "sy")))
+        blur = CasperEngine(blur2d(), backend="pallas", sweeps=4,
+                            tile="auto")
+        got = tags(blur.distributed_fn(mesh18, ("sx", "sy"), iters=4), y)
+        hops = 2 * (6 * 7 + 2 * 6) * 32 / 8 * 4
+        assert [(t["shards"], t["exchange_bytes"]) for t in got] == [
+            ("1x8", str(round(hops)))], got
+
+        # a fused pipeline's shard-local kernel carries them too
+        mesh22 = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("sx", "sy"))
+        z = jax.device_put(jnp.ones((32, 512), jnp.float32),
+                           NamedSharding(mesh22, P("sx", "sy")))
+        pipe = CasperEngine(PAPER_PIPELINES["reaction_diffusion2d"],
+                            backend="pallas", sweeps=2, tile="auto")
+        got = tags(pipe.distributed_fn(mesh22, ("sx", "sy"), iters=2), z)
+        assert [t["shards"] for t in got] == ["2x2"], got
+        assert int(got[0]["exchange_bytes"]) > 0, got
+
+        single = tags(lambda g: eng.run(g, iters=4),
+                      jnp.ones((32, 32, 256), jnp.float32))
+        assert [sorted(t) for t in single] == [sorted([
+            "casper", "strategy", "sweeps", "tile", "grid_steps",
+            "fetch_bytes", "write_bytes"])], single
+
+        spans = []
+        @contextlib.contextmanager
+        def record(name, **kw):
+            spans.append(name)
+            yield
+        _trace.span = record
+        fn = eng.distributed_fn(mesh, axes, iters=4)
+        fn(x).block_until_ready()
+        assert spans == [_trace.RUN], spans
+        fn.lower(x).compile()              # ahead-of-time callers
+        print("mesh tag ok")
+    """)
+    assert "mesh tag ok" in out
+
+
 def test_deep_halo_exchange_validation():
     """sweeps/iters validation and the zero-iters identity."""
     run_sub(4, """

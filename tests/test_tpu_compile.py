@@ -162,3 +162,59 @@ def test_benchmarked_runner_carries_the_kernel_tag(name, shape, one_chip):
         "tile": "x".join(map(str, plan.tile)), "grid_steps": str(steps),
         "fetch_bytes": str(steps * math.prod(window) * 4),
         "write_bytes": str(math.prod(shape) * 4)}]
+
+
+@pytest.fixture(scope="module")
+def mesh_cell(topo):
+    """The mesh cell's 2x2 mesh of described chips and its 8 GiB grid's
+    sharded shape (``bench/configs/heat3d-2k-mesh.json``)."""
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("sx", "sy"))
+    axes = ("sx", "sy", None)
+    arg = jax.ShapeDtypeStruct((2048, 2048, 512), jnp.float32,
+                               sharding=NamedSharding(mesh, P(*axes)))
+    return mesh, axes, arg
+
+
+def test_mesh_cell_solve_fits_and_carries_the_mesh_tag(mesh_cell):
+    """The mesh cell's 1,000-step solve: one shard-local kernel tagged
+    with the 2x2 shards and the 16 MiB a shard receives per block (a
+    4-deep 1024x512 face on sx, then a 1032x512 one on sy), and the
+    grid, its output and the temporaries inside a chip's HBM."""
+    mesh, axes, arg = mesh_cell
+    eng = CasperEngine(PAPER_STENCILS["heat3d"], backend="pallas", sweeps=4,
+                       tile="auto", interpret=False)
+    compiled = eng.distributed_fn(mesh, axes, iters=1000).lower(arg).compile()
+    tags = [json.loads(m) for m in re.findall(
+        r"kernel_metadata=(\{.*?\})\}", compiled.as_text(), flags=re.DOTALL)]
+    assert [(t["strategy"], t["shards"], t["exchange_bytes"])
+            for t in tags] == [("window", "2x2",
+                                str((4 * 1024 + 4 * 1032) * 512 * 4))]
+    mem = compiled.memory_analysis()
+    shard = 1024 * 1024 * 512 * 4
+    assert mem.argument_size_in_bytes == mem.output_size_in_bytes == shard
+    assert 2 * shard + mem.temp_size_in_bytes < 12e9
+
+
+def test_mesh_cell_reference_check_fits(mesh_cell):
+    """The benchmark's check of the mesh cell (``bench/references/
+    heat3d_mesh.py`` stepped 1,000 times over the sharded grid against
+    the program's output) holds two grids' shares and two of its own a
+    chip, with no padded copy of a shard."""
+    import os
+    import sys
+    from jax import lax
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    from bench import harness
+    mesh, axes, arg = mesh_cell
+    config = harness.load_config("heat3d-2k-mesh")
+    step = harness.load_module("references", config["reference"]).make_step(
+        config, mesh, axes)
+
+    def gap(u, got):
+        want = lax.fori_loop(0, 1000, lambda _, v: step(v), u)
+        return jnp.max(jnp.abs(got - want))
+    mem = jax.jit(gap).lower(arg, arg).compile().memory_analysis()
+    shard = 1024 * 1024 * 512 * 4
+    assert mem.argument_size_in_bytes == 2 * shard
+    assert mem.temp_size_in_bytes < 2 * shard + 64 * 2**20
