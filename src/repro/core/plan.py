@@ -270,6 +270,27 @@ class ExecutionPlan:
         return False
 
     @property
+    def blocks_per_scan_step(self) -> int:
+        """Fused blocks :func:`run_plan` runs per ``lax.scan`` step: 2
+        where the block's kernel reads the scan carry in place — the
+        single-device pad-free Pallas kernel, which DMAs its windows
+        straight from the carried grid (``pl.ANY``) — else 1.
+
+        With one such block a step, XLA must hand the kernel's fresh
+        output back in the carry's buffer while the kernel still reads
+        that buffer, so it copies the whole grid after every block.
+        With two, the second block's output takes the carry's buffer
+        (the carry is dead after the first block) and no copy is made.
+        Every other plan keeps one: the distributed kernel reads the
+        exchanged window, not the carry, so there is no copy to remove;
+        and jnp executors (``ref``) could be fused by XLA across two
+        blocks, changing their pinned f64 order."""
+        if (self.backend in KERNEL_BACKENDS and not self.is_distributed
+                and self.ghost_strategy == "pad-free"):
+            return 2
+        return 1
+
+    @property
     def is_pipeline(self) -> bool:
         return isinstance(self.spec, StencilPipeline)
 
@@ -716,6 +737,13 @@ def run_plan(plan: ExecutionPlan, grid, iters: int):
     loop shared by the engine, the distributed path and the serving
     front-end.
 
+    Pad-free Pallas plans run two fused blocks per scan step
+    (``plan.blocks_per_scan_step``; an odd ``q`` runs its last block
+    after the loop): their kernel reads the carried grid in place, and
+    with one block a step XLA copies the whole grid after every block to
+    give the output the carry's buffer.  The result is bitwise that of
+    ``q`` chained :func:`execute` calls either way.
+
     ``iters == 0`` returns a *defensive copy* of the input, never the
     input itself: the slab executor donates device buffers, so a no-op
     result aliasing a caller-held array would be corrupted by the next
@@ -734,7 +762,8 @@ def run_plan(plan: ExecutionPlan, grid, iters: int):
     if q:
         def body(g, _):
             return execute(plan, g), None
-        out, _ = jax.lax.scan(body, out, None, length=q)
+        out, _ = jax.lax.scan(body, out, None, length=q,
+                              unroll=plan.blocks_per_scan_step)
     if r:
         out = execute(plan.remainder(r), out)
     return out

@@ -579,7 +579,10 @@ def run_sweeps(spec: StencilSpec, grid: jax.Array, iters: int,
     single ``lax.scan`` (one traced/compiled step instead of ``q``
     unrolled copies of the kernel graph) plus one remainder call whose
     narrower plan also comes from the cache, so any ``iters`` is exact
-    for any blocking factor.
+    for any blocking factor.  A pad-free plan's scan runs two calls a
+    step: its kernel reads the carried grid in place, and with one call
+    a step XLA would copy the whole grid after every call
+    (``plan.run_plan``).
     """
     plan = _plan.lower(spec, _plan._grid_shape_for(spec, grid), grid.dtype,
                        backend="pallas", sweeps=sweeps, tile=tile,

@@ -140,28 +140,79 @@ def test_autotuned_tile_compiles_at_benchmark_size(name, shape, one_chip):
     assert "tpu_custom_call" in _compiled_text(fn, shape, sharding=one_chip)
 
 
-@pytest.mark.parametrize("name,shape", [("jacobi2d", (16384, 16384)),
-                                        ("heat3d", (512, 512, 512))])
-def test_benchmarked_runner_carries_the_kernel_tag(name, shape, one_chip):
+BENCHMARKED = [("jacobi2d", (16384, 16384)), ("heat3d", (512, 512, 512))]
+
+
+@pytest.fixture(scope="module")
+def benchmarked_solves(one_chip):
+    """The 1,000-step solves the benchmark times, compiled once per
+    cell: ``{name: (engine, compiled)}``, filled on first use."""
+    solves = {}
+
+    def get(name, shape):
+        if name not in solves:
+            eng = CasperEngine(PAPER_STENCILS[name], backend="pallas",
+                               sweeps=4, tile="auto", interpret=False)
+            arg = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+            solves[name] = (eng, jax.jit(lambda g: eng.run(g, iters=1000))
+                            .lower(arg).compile())
+        return solves[name]
+    return get
+
+
+def _loop_body(text: str) -> str:
+    """The HLO text of the body of the compiled program's one ``while``
+    loop (the scan of fused blocks)."""
+    (name,) = re.findall(r"= .* while\(.*?body=%([\w.\-]+)", text)
+    return re.search(r"^%" + re.escape(name) + r" .*?^}$", text,
+                     flags=re.M | re.S).group(0)
+
+
+@pytest.mark.parametrize("name,shape", BENCHMARKED)
+def test_benchmarked_runner_carries_the_kernel_tag(name, shape,
+                                                   benchmarked_solves):
     """The 1,000-step solves the benchmark times: the compiled kernel op
     is named ``casper_fused`` and its ``kernel_metadata`` states the
     plan's strategy, sweeps and tile, and the grid steps and bytes one
-    block takes (each step fetches the aligned pad-free window)."""
-    eng = CasperEngine(PAPER_STENCILS[name], backend="pallas", sweeps=4,
-                       tile="auto", interpret=False)
-    text = _compiled_text(lambda g: eng.run(g, iters=1000), shape,
-                          sharding=one_chip)
+    block takes (each step fetches the aligned pad-free window).  The
+    loop runs two fused blocks a step, so two kernel ops carry the
+    same tag."""
+    eng, compiled = benchmarked_solves(name, shape)
+    text = compiled.as_text()
     assert re.search(r"%casper_fused[.\d]* = .* custom-call\(", text)
     tags = [json.loads(m) for m in re.findall(
         r"kernel_metadata=(\{.*?\})\}", text, flags=re.DOTALL)]
     plan = eng.plan_for(shape, jnp.float32)
     steps = math.prod(n // t for n, t in zip(shape, plan.tile))
     window = _pm.fetch_window(plan.tile, plan.deep_halo, 4)
-    assert tags == [{
+    assert len(tags) == 2
+    assert all(tag == {
         "casper": "fused", "strategy": "pad-free", "sweeps": "4",
         "tile": "x".join(map(str, plan.tile)), "grid_steps": str(steps),
         "fetch_bytes": str(steps * math.prod(window) * 4),
-        "write_bytes": str(math.prod(shape) * 4)}]
+        "write_bytes": str(math.prod(shape) * 4)} for tag in tags)
+
+
+@pytest.mark.parametrize("name,shape", BENCHMARKED)
+def test_benchmarked_solve_carries_the_grid_without_a_copy(
+        name, shape, benchmarked_solves):
+    """The pad-free kernel reads the scan carry in place, so with one
+    block a scan step XLA copied the whole grid after every block to
+    hand the output back in the carry's buffer.  Two blocks a step
+    leave one grid-shaped copy in the program, the input's, outside the
+    loop; the loop body runs both kernels; and the temporaries stay at
+    one grid."""
+    _, compiled = benchmarked_solves(name, shape)
+    text = compiled.as_text()
+    grid_copy = r"= f32\[" + ",".join(map(str, shape)) + r"\][^ ]* copy\("
+    assert len(re.findall(grid_copy, text)) == 1
+    body = _loop_body(text)
+    assert not re.search(grid_copy, body)
+    assert len(re.findall(r"%casper_fused[.\d]* = .* custom-call\(",
+                          body)) == 2
+    grid_bytes = math.prod(shape) * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        <= grid_bytes + 2**20
 
 
 @pytest.fixture(scope="module")
@@ -189,6 +240,10 @@ def test_mesh_cell_solve_fits_and_carries_the_mesh_tag(mesh_cell):
     assert [(t["strategy"], t["shards"], t["exchange_bytes"])
             for t in tags] == [("window", "2x2",
                                 str((4 * 1024 + 4 * 1032) * 512 * 4))]
+    # one block a scan step: the kernel reads the exchanged window, not
+    # the carry, so there is no carry copy for a second block to remove
+    assert len(re.findall(r"%casper_fused[.\d]* = .* custom-call\(",
+                          _loop_body(compiled.as_text()))) == 1
     mem = compiled.memory_analysis()
     shard = 1024 * 1024 * 512 * 4
     assert mem.argument_size_in_bytes == mem.output_size_in_bytes == shard
