@@ -37,9 +37,9 @@ and the shard-shape tile autotune now live in ``plan.lower``, not here:
 
 Between fused sweeps, the shard-local compute restores intermediates that
 fall outside the *global* grid to the mode's boundary extension
-(`ref.masked_window_sweeps`), matching the oracle's re-pad-every-sweep
-semantics exactly — f64 bit-identically for all four modes (see
-docs/boundaries.md).
+(`ref.masked_window_pipeline`, the one fused core), matching the
+oracle's re-pad-every-sweep semantics exactly — f64 bit-identically for
+all four modes (see docs/boundaries.md).
 """
 from __future__ import annotations
 
@@ -173,7 +173,6 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
     applications on the widened block.  Every decision — exchange
     strategy, tile, halo depth — was resolved at lowering time."""
     spec = plan.spec
-    halo = plan.halo
     mode, value = plan.boundary_mode, plan.boundary_value
     deep = plan.deep_halo
     padded = x
@@ -193,31 +192,19 @@ def _local_multisweep(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:
             origin.append(0)
             grid_shape.append(x.shape[d])
     mesh_tag = exchange_tag(plan, x.shape, x.dtype.itemsize)
-    if plan.is_pipeline:
-        # Fused chain on the widened block: the exchange above already
-        # fetched the sweeps * sum-of-stage-radii deep halo (plan.halo is
-        # the per-dim stage sum), so every stage of every sweep computes
-        # from exchanged data — one collective launch pair per sharded
-        # axis per sweeps*n_stages stage applications.
-        if plan.backend in _plan.KERNEL_BACKENDS:
-            from repro.kernels import engine as keng  # lazy: optional dep
-            return keng.pipeline_window_sweep(
-                spec, padded, x.shape, origin, grid_shape,
-                tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
-                mesh_tag=mesh_tag)
-        return _ref.masked_window_pipeline(
-            padded, spec.stages, x.shape, plan.sweeps, origin, grid_shape,
-            x.dtype).astype(x.dtype)
+    # The exchange above fetched the sweeps * halo deep halo (a
+    # pipeline's halo is its per-dim sum of stage radii), so every stage
+    # of every sweep computes from exchanged data: one collective launch
+    # pair per sharded axis per fused block.
     if plan.backend in _plan.KERNEL_BACKENDS:
         from repro.kernels import engine as keng  # lazy: optional dep
         return keng.stencil_window_sweep(
             spec, padded, x.shape, origin, grid_shape,
             tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret,
             mesh_tag=mesh_tag)
-    return _ref.masked_window_sweeps(
-        padded, spec.taps, halo, x.shape, plan.sweeps, origin, grid_shape,
-        x.dtype, mode=mode, value=value,
-        structure=spec.structure).astype(x.dtype)
+    return _ref.masked_window_pipeline(
+        padded, plan.stages, x.shape, plan.sweeps, origin, grid_shape,
+        x.dtype).astype(x.dtype)
 
 
 def execute_plan(plan: "_plan.ExecutionPlan", x: jax.Array) -> jax.Array:

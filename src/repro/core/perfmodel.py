@@ -19,8 +19,7 @@ import os
 
 from .isa import Program, assemble
 from .segment import SegmentConfig, remote_fraction
-from .stencil import (PAPER_STENCILS, DOMAIN_SIZES, StencilPipeline,
-                      StencilSpec)
+from .stencil import PAPER_STENCILS, DOMAIN_SIZES, StencilSpec, as_stages
 
 # ----------------------------------------------------------------------------
 # Machine constants (Table 2 unless noted)
@@ -387,37 +386,20 @@ def _window_traffic(shape: tuple[int, ...], tile: tuple[int, ...],
     return traffic
 
 
-def _tile_flops(spec: StencilSpec, tile: tuple[int, ...],
+def _tile_flops(spec, tile: tuple[int, ...],
                 sweeps: int) -> tuple[int, int]:
     """Flops of one grid step of the fused kernel, and of them the
-    ``reflect`` re-mirror's: ``sweeps`` applications at their shrinking
-    VPU-padded windows at the structured per-point count, plus, for
-    ``reflect``, one per-axis gather pass over every intermediate
-    window (the other modes' fix-up is a masked select already folded
-    into the tap accounting)."""
-    halo = spec.halo
-
-    def padded_points(layers: int) -> int:
-        return _vreg_padded(t + 2 * layers * h for t, h in zip(tile, halo))
-
-    flops = sum(padded_points(sweeps - 1 - s)
-                for s in range(sweeps)) * spec.structured_flops_per_point()
-    mirror = 0
-    if spec.boundary_mode == "reflect":
-        mirror = sum(padded_points(sweeps - 1 - s)
-                     for s in range(sweeps - 1)) * len(tile)
-    return flops + mirror, mirror
-
-
-def _pipeline_tile_flops(pipeline, tile: tuple[int, ...],
-                         sweeps: int) -> tuple[int, int]:
-    """:func:`_tile_flops` for a fused chain, walking the exact
-    element-layer schedule of ``ref.masked_window_pipeline``: each stage
-    application at its shrinking window with its own structured count,
-    and a reflect-mode next stage's re-mirror on the intermediate."""
-    stages = pipeline.stages
+    ``reflect`` re-mirror's, walking the element-layer schedule of
+    ``ref.masked_window_pipeline`` over the stage chain of ``spec`` (a
+    plain spec is its own one-stage chain): each stage application at its
+    shrinking VPU-padded window with its own structured per-point
+    count, plus, where the next stage to run is ``reflect``, one
+    per-axis gather pass over the intermediate window (the other modes'
+    fix-up is a masked select already folded into the tap
+    accounting)."""
+    stages = as_stages(spec)
     n = len(stages)
-    rem = tuple(sweeps * h for h in pipeline.halo)
+    rem = tuple(sweeps * h for h in spec.halo)
     flops = mirror = 0
     for step in range(sweeps * n):
         stage = stages[step % n]
@@ -449,16 +431,16 @@ def compiles_quickly(spec, tile: tuple[int, ...], sweeps: int = 1) -> bool:
     to 13 times longer than the same body with zero ghosts (jacobi2d,
     blur2d, heat3d and star33_3d at sweeps=4, compiled for a described
     v5e), far more than its flops account for."""
-    flops, mirror = (_pipeline_tile_flops(spec, tile, sweeps)
-                     if isinstance(spec, StencilPipeline)
-                     else _tile_flops(spec, tile, sweeps))
+    flops, mirror = _tile_flops(spec, tile, sweeps)
     return not mirror and flops / 1024 <= TPU_QUICK_COMPILE_VREG_OPS
 
 
-def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
+def pallas_tile_cost(spec, shape: tuple[int, ...],
                      tile: tuple[int, ...], sweeps: int = 1,
                      itemsize: int = 4) -> float:
-    """Predicted seconds for ``sweeps`` fused applications over ``shape``
+    """Predicted seconds for ``sweeps`` fused applications of ``spec``
+    (a :class:`StencilSpec`, or a fused
+    :class:`~repro.core.stencil.StencilPipeline` chain) over ``shape``
     with output block ``tile`` (the kernels/engine.py temporal-blocking
     kernel).  Returns ``inf`` when the VMEM working set cannot fit.
 
@@ -466,34 +448,36 @@ def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
     window DMA, then computes, so time = HBM traffic + VPU compute +
     a fixed cost per grid step (:data:`TPU_GRID_STEP_S`), fitted to the
     chip.  The traffic term charges each tile one window read (halo
-    widened to ``sweeps*halo``) plus one tile write — against the
-    *unpadded* grid;
-    the pad-free engine materializes boundary ghosts in-kernel, so no
+    widened to ``sweeps*halo``, ``halo`` the per-dim sum of the stage
+    radii) plus one tile write — against the *unpadded* grid; the
+    pad-free engine materializes boundary ghosts in-kernel, so no
     host-side pad traffic enters (the removed pad copy is charged to
-    the unfused baseline by ``kernels.engine.hbm_traffic``).  The
-    compute term charges every intermediate application at its
+    the unfused baseline by ``kernels.engine.hbm_traffic``), and a
+    chain's intermediates stay in VMEM.  The compute term
+    (:func:`_tile_flops`) charges every stage application at its
     shrinking window size, padded up to the VPU (sublane, lane) grain
     so misaligned tiles pay for the lanes they waste.
 
     The compute term is **structure-aware**: per-point flops come from
-    ``spec.structured_flops_per_point()`` — the factored MAC count of
-    separable specs (e.g. 15 tap-ops for ``star33_3d`` instead of 33;
-    star/dense compute the plain tap chain and keep their dense count)
-    — and each extra computed term holds one more live window-sized
-    intermediate, charged to the VMEM resident set.  Cheaper compute
-    and the extra resident intermediates both shift the autotuner's
-    tile choice for separable specs.
+    each stage's ``structured_flops_per_point()`` — the factored MAC
+    count of separable specs (e.g. 15 tap-ops for ``star33_3d`` instead
+    of 33; star/dense compute the plain tap chain and keep their dense
+    count) — and each extra computed term of the richest stage holds
+    one more live window-sized intermediate, charged to the VMEM
+    resident set.  Cheaper compute and the extra resident intermediates
+    both shift the autotuner's tile choice for separable specs.
 
-    The boundary mode enters through ``spec.boundary``: traffic is
-    mode-independent (the window is fetched whole either way), but
-    ``reflect`` adds one per-axis ghost-re-mirroring gather pass over
-    every intermediate window between fused sweeps (the other modes'
-    fix-up is a masked select already folded into the tap accounting).
+    The boundary mode enters through each stage's ``boundary``: traffic
+    is mode-independent (the window is fetched whole either way), but a
+    ``reflect`` stage adds one per-axis ghost-re-mirroring gather pass
+    over every intermediate window it consumes (the other modes' fix-up
+    is a masked select already folded into the tap accounting).
     """
     halo = spec.halo
     n_tiles = math.prod(-(-n // t) for n, t in zip(shape, tile))
-    terms = spec.factorization.compute_terms
-    n_terms = 1 if terms is None else len(terms)
+    n_terms = max(
+        (1 if s.factorization.compute_terms is None
+         else len(s.factorization.compute_terms)) for s in as_stages(spec))
 
     # Resident set: DMA buffer + window + accumulator + output block,
     # plus one live window-sized intermediate per extra factored term.
@@ -506,44 +490,6 @@ def pallas_tile_cost(spec: StencilSpec, shape: tuple[int, ...],
     t_mem = traffic / TPU_HBM_BW
 
     t_compute = (_tile_flops(spec, tile, sweeps)[0] * n_tiles
-                 / TPU_VPU_FLOPS_F32)
-    return t_mem + t_compute + n_tiles * TPU_GRID_STEP_S
-
-
-def pallas_pipeline_tile_cost(pipeline, shape: tuple[int, ...],
-                              tile: tuple[int, ...], sweeps: int = 1,
-                              itemsize: int = 4) -> float:
-    """:func:`pallas_tile_cost` generalized to a fused
-    :class:`~repro.core.stencil.StencilPipeline` chain.
-
-    Traffic charges each tile one window read at the chain's summed halo
-    (``tile + 2*sweeps*H`` per dim, ``H`` = per-dim sum of stage radii)
-    plus one tile write — the fused pipeline's whole HBM footprint, all
-    intermediates staying in VMEM.  Compute walks the exact element-layer
-    schedule of ``ref.masked_window_pipeline``: each stage application
-    runs at its shrinking window size with *its own* structured per-point
-    flop count, and a reflect-mode next stage charges the per-axis ghost
-    re-mirror gather on the intermediate.  VMEM feasibility charges the
-    widened window, an accumulator, one live window-sized intermediate
-    per extra factored term of the richest stage, and the output block.
-    Returns ``inf`` when that resident set cannot fit.
-    """
-    stages = pipeline.stages
-    big_halo = pipeline.halo
-    n_tiles = math.prod(-(-n // t) for n, t in zip(shape, tile))
-    max_terms = max(
-        (1 if s.factorization.compute_terms is None
-         else len(s.factorization.compute_terms)) for s in stages)
-
-    vmem = vmem_residency(tile, big_halo, sweeps, itemsize, max_terms)
-    if vmem > TPU_VMEM_BYTES:
-        return float("inf")
-
-    traffic = _window_traffic(shape, tile,
-                              tuple(sweeps * h for h in big_halo), itemsize)
-    t_mem = traffic / TPU_HBM_BW
-
-    t_compute = (_pipeline_tile_flops(pipeline, tile, sweeps)[0] * n_tiles
                  / TPU_VPU_FLOPS_F32)
     return t_mem + t_compute + n_tiles * TPU_GRID_STEP_S
 

@@ -9,9 +9,17 @@ tile choice and the ``iters = q*sweeps + r`` decomposition for itself.
 This module is now the single home of those decisions:
 
 ``lower(spec, shape, dtype, *, backend, sweeps, tile, mesh, grid_axes,
-interpret)`` resolves, once, everything a backend needs —
+interpret)`` resolves, once, everything a backend needs, for a
+:class:`~repro.core.stencil.StencilSpec` or a
+:class:`~repro.core.stencil.StencilPipeline` alike: a spec is the
+one-stage chain :func:`~repro.core.stencil.as_stages` of itself, so one
+lowering ladder, one autotuner and one cost model serve both —
 
-* the tap **factorization** (:func:`repro.core.stencil.factor_taps`);
+* whether the chain **fuses** (:func:`repro.core.stencil.fusable`; a
+  mixed periodic/non-periodic chain runs ``"staged"``);
+* the tap **factorization** of a spec
+  (:func:`repro.core.stencil.factor_taps`; each stage of a pipeline
+  keeps its own);
 * the **boundary-ghost strategy**: per-sweep ``pad_boundary`` for the
   oracles (``"pad"``), in-kernel ghost materialization vs the padded
   window fallback for Pallas (:func:`ghost_strategy_for`), the
@@ -58,9 +66,9 @@ import numpy as np
 
 from . import perfmodel as _pm
 from . import trace as _trace
-from .isa import assemble, assemble_pipeline
+from .isa import assemble_any
 from .stencil import (Factorization, StencilPipeline, StencilSpec, as_stages,
-                      factor_taps)
+                      factor_taps, fusable)
 
 Backend = Literal["ref", "pallas", "vm"]
 
@@ -264,7 +272,7 @@ class ExecutionPlan:
         traced, so runners route these around their jitted paths)."""
         if self.streams_from_host:
             return True
-        if self.is_pipeline and not self.fused and self.slab_budget is not None:
+        if not self.fused and self.slab_budget is not None:
             grid_bytes = math.prod(self.shape) * jnp.dtype(self.dtype).itemsize
             return grid_bytes > self.slab_budget
         return False
@@ -552,27 +560,31 @@ def _shard_shape(shape, mesh, axes) -> tuple[int, ...]:
     return tuple(out)
 
 
-def _lower_pipeline_uncached(pipe, shape, dtype, backend, sweeps, tile_req,
-                             mesh, axes, interp, fingerprint) -> ExecutionPlan:
-    """Lower a :class:`~repro.core.stencil.StencilPipeline` to one fused
-    plan: the fetched halo per application is the per-dim **sum of the
-    stage radii** (``plan.halo``), widened ``sweeps``-deep exactly like
-    single-spec temporal blocking (``deep_halo = sweeps * halo``) — the
-    ``sweeps=t`` math with heterogeneous taps per sweep.  Intermediate
-    stage fields live in the VMEM window and never round-trip HBM.
+def _lower_uncached(spec, shape, dtype, backend, sweeps, tile_req, mesh,
+                    axes, interp, fingerprint) -> ExecutionPlan:
+    """Lower a spec or a pipeline to one fused plan.  A spec is the
+    one-stage chain :func:`~repro.core.stencil.as_stages` of itself, so
+    one ladder serves both: the fetched halo per application is the
+    per-dim **sum of the stage radii** (``plan.halo``; a spec's own
+    radius), widened ``sweeps``-deep (``deep_halo = sweeps * halo``),
+    and the window's initial ghost extension is stage 0's mode (between
+    stages the fused core restores ghosts per the consuming stage's
+    own mode).  Intermediate stage fields live in the VMEM window and
+    never round-trip HBM.
 
     A chain mixing periodic with non-periodic stages cannot restore
-    between-stage ghosts tile-locally (see ``StencilPipeline.fusable``):
-    the plan is then marked ``fused=False`` with ghost strategy
-    ``"staged"`` and :func:`execute` runs the per-stage cached plans in
-    sequence instead.
+    between-stage ghosts tile-locally (:func:`~repro.core.stencil
+    .fusable`): the plan is then marked ``fused=False`` with ghost
+    strategy ``"staged"`` and :func:`execute` runs the per-stage cached
+    plans in sequence instead.
+
+    Counters (``lowers``, ``autotune_calls``) update under the cache
+    lock: this only runs from :meth:`PlanCache.get_or_lower`.
     """
-    halo = pipe.halo                        # per-dim sum of stage radii
+    halo = spec.halo
     deep = tuple(sweeps * h for h in halo)
-    fused = pipe.fusable
-    # stage 0's mode: the *initial* window extension (between stages the
-    # fused core restores ghosts per the consuming stage's own mode)
-    mode, value = pipe.boundary_mode, pipe.boundary_value
+    fused = fusable(spec)
+    mode, value = spec.boundary_mode, spec.boundary_value
 
     shard_shape = exchange = None
     if mesh is not None:
@@ -580,7 +592,7 @@ def _lower_pipeline_uncached(pipe, shape, dtype, backend, sweeps, tile_req,
         if fused:
             exchange = tuple(
                 exchange_strategy_for(mode) if axes[d] is not None else None
-                for d in range(pipe.ndim))
+                for d in range(spec.ndim))
 
     slab_budget = slabs = slab_overlap = None
     if mesh is None and backend in ("ref",) + KERNEL_BACKENDS:
@@ -593,72 +605,10 @@ def _lower_pipeline_uncached(pipe, shape, dtype, backend, sweeps, tile_req,
             slab_budget = _pm.slab_budget_bytes()
 
     resolved_tile = None
-    ghost = "pad" if fused else "staged"
+    ghost = "pad" if fused else "staged"        # oracle default / staged
     if not fused:
-        pass                                # stage plans decide everything
+        pass                                    # stage plans decide
     elif backend in KERNEL_BACKENDS:
-        tune_shape = shard_shape if shard_shape is not None else shape
-        if slabs is not None:               # tune for the slab, not the grid
-            tune_shape = (slabs[0][1] - slabs[0][0],) + shape[1:]
-        if tile_req == "auto":
-            from repro.kernels import tune      # lazy: optional dep
-            PLAN_CACHE.autotune_calls += 1
-            with _trace.span(_trace.AUTOTUNE, event=True):
-                resolved_tile = tune.autotune_pipeline(
-                    pipe, tune_shape, sweeps=sweeps,
-                    itemsize=dtype.itemsize).tile
-        else:
-            resolved_tile = normalize_tile(pipe, tile_req)
-        if mesh is not None:
-            ghost = "padded-window"
-        elif slabs is not None:
-            ghost = "stream-from-host"
-        else:
-            ghost = ghost_strategy_for(pipe, shape, dtype.itemsize, sweeps,
-                                       resolved_tile)
-    elif backend == "vm":
-        ghost = "stream"
-    elif slabs is not None:                 # fused ref chain, over budget
-        ghost = "stream-from-host"
-
-    return ExecutionPlan(
-        spec=pipe, shape=shape, dtype=dtype.name, backend=backend,
-        sweeps=sweeps, interpret=interp, tile=resolved_tile,
-        tile_request=tile_req, ghost_strategy=ghost, halo=halo,
-        deep_halo=deep, factorization=None, boundary_mode=mode,
-        boundary_value=value, program=assemble_pipeline(pipe), mesh=mesh,
-        grid_axes=axes, exchange=exchange, shard_shape=shard_shape,
-        mesh_fingerprint=fingerprint, fused=fused, slabs=slabs,
-        slab_overlap=slab_overlap, slab_budget=slab_budget)
-
-
-def _lower_uncached(spec, shape, dtype, backend, sweeps, tile_req, mesh,
-                    axes, interp, fingerprint) -> ExecutionPlan:
-    # counters (lowers, autotune_calls) update under the cache lock:
-    # this only runs from PlanCache.get_or_lower
-    if isinstance(spec, StencilPipeline):
-        return _lower_pipeline_uncached(spec, shape, dtype, backend, sweeps,
-                                        tile_req, mesh, axes, interp,
-                                        fingerprint)
-    halo = spec.halo
-    deep = tuple(sweeps * h for h in halo)
-    mode, value = spec.boundary_mode, spec.boundary_value
-
-    shard_shape = exchange = None
-    if mesh is not None:
-        shard_shape = _shard_shape(shape, mesh, axes)
-        exchange = tuple(
-            exchange_strategy_for(mode) if axes[d] is not None else None
-            for d in range(spec.ndim))
-
-    slab_budget = slabs = slab_overlap = None
-    if mesh is None and backend in ("ref",) + KERNEL_BACKENDS:
-        slab_budget, slabs, slab_overlap = _slab_decomposition(
-            shape, deep, dtype.itemsize)
-
-    resolved_tile = None
-    ghost = "pad"                               # oracle default
-    if backend in KERNEL_BACKENDS:
         tune_shape = shard_shape if shard_shape is not None else shape
         if slabs is not None:                   # tune for the slab window
             tune_shape = (slabs[0][1] - slabs[0][0],) + shape[1:]
@@ -689,11 +639,14 @@ def _lower_uncached(spec, shape, dtype, backend, sweeps, tile_req, mesh,
         spec=spec, shape=shape, dtype=dtype.name, backend=backend,
         sweeps=sweeps, interpret=interp, tile=resolved_tile,
         tile_request=tile_req, ghost_strategy=ghost, halo=halo,
-        deep_halo=deep, factorization=factor_taps(spec),
-        boundary_mode=mode, boundary_value=value, program=assemble(spec),
-        mesh=mesh, grid_axes=axes, exchange=exchange,
-        shard_shape=shard_shape, mesh_fingerprint=fingerprint,
-        slabs=slabs, slab_overlap=slab_overlap, slab_budget=slab_budget)
+        deep_halo=deep,
+        factorization=(factor_taps(spec) if isinstance(spec, StencilSpec)
+                       else None),
+        boundary_mode=mode, boundary_value=value,
+        program=assemble_any(spec), mesh=mesh, grid_axes=axes,
+        exchange=exchange, shard_shape=shard_shape,
+        mesh_fingerprint=fingerprint, fused=fused, slabs=slabs,
+        slab_overlap=slab_overlap, slab_budget=slab_budget)
 
 
 # ---------------------------------------------------------------------------
@@ -709,10 +662,10 @@ def execute(plan: ExecutionPlan, grid):
     if plan.streams_from_host:
         from repro.kernels import stream as _stream     # lazy: optional dep
         return _stream.execute_plan(plan, grid)
-    if plan.is_pipeline and not plan.fused:
+    if not plan.fused:
         out = grid
         for _ in range(plan.sweeps):
-            for k in range(plan.spec.n_stages):
+            for k in range(len(plan.stages)):
                 out = execute(plan.stage_plan(k), out)
         return out
     if plan.is_distributed:

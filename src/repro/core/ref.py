@@ -216,8 +216,8 @@ def _window_apply(x, taps, halo, cur, acc_dtype, terms):
     """One stencil application on window ``x``: the taps slice ``halo``
     layers off per side, producing shape ``cur``.  Dispatches on the
     factored ``terms`` (separable) vs the dense per-tap path, both
-    accumulating through :func:`tap_sum` — the pinned f64 order shared
-    by single-spec and pipeline fused cores alike."""
+    accumulating through :func:`tap_sum` — the pinned f64 order of the
+    fused core (:func:`masked_window_pipeline`)."""
     if terms is not None:
         return factored_window_apply(x, terms, halo, cur, acc_dtype)
     return tap_sum(
@@ -260,96 +260,48 @@ def _restore_ghosts(acc, mode, value, g0s, grid_shape, depth):
     return acc
 
 
-def masked_window_sweeps(window: jax.Array, taps, halo, out_shape,
-                         sweeps: int, starts, grid_shape,
-                         acc_dtype, *, mode: str = "zero",
-                         value: float = 0.0,
-                         structure: str = "auto") -> jax.Array:
-    """Apply ``sweeps`` fused stencil applications to one widened window.
-
-    ``window`` carries ``sweeps`` halo layers per side around an
-    ``out_shape`` interior whose origin sits at global coordinate
-    ``starts`` of a ``grid_shape`` grid; application ``s`` consumes one
-    layer, so the intermediate after it has ``sweeps-1-s`` layers left
-    and the final result is exactly ``out_shape``.  The caller must have
-    filled the window's ghost layers with the boundary extension for
-    ``mode`` (see :func:`pad_boundary` / the distributed halo exchange).
-
-    Between applications, ghost elements — those whose *global*
-    coordinate falls outside the true grid — are restored to the boundary
-    extension of the intermediate, the closed form of the oracle
-    re-padding before every sweep:
-
-    * ``zero`` / ``constant``: ghosts are overwritten with the fill value
-      (which also kills values leaking in from any out-of-grid padding
-      around the window);
-    * ``reflect``: ghosts are re-mirrored from the intermediate's interior
-      by a per-axis gather (the mirror source of a ghost ``rem·halo``
-      layers deep is provably inside the same window);
-    * ``periodic``: nothing — a stencil applied to a periodically
-      extended window yields ghost values that are bitwise equal to their
-      wrapped interior counterparts, so the ghosts evolve correctly on
-      their own.
-
-    Per-application compute dispatches on ``structure`` (the spec's
-    tap-structure class, see :func:`repro.core.stencil.factor_taps`):
-    separable specs run :func:`factored_window_apply`; star and dense
-    specs the per-tap path (a star tap chain is already the
-    ``sum(2r_d)+1`` optimum).  Either way accumulation routes through
-    :func:`tap_sum` in the structure's pinned order, so f64 results stay
-    bit-identical to chained :func:`apply_stencil` calls under every
-    mode (``"auto"`` re-classifies from ``taps``; pass the spec's
-    ``structure`` to honor a forced-dense override).
-
-    This is the shared core of the Pallas kernel (``starts`` =
-    ``program_id * tile``) and the distributed shard-local path
-    (``starts`` = the shard's global offset, a traced ``axis_index``
-    value); ``out_shape``/``grid_shape``/``halo`` must be static.
-    """
-    ndim = len(out_shape)
-    terms = (None if structure == "dense"
-             else _classify(ndim, tuple(taps)).compute_terms)
-    x = window.astype(acc_dtype)
-    for s in range(sweeps):
-        rem = sweeps - 1 - s          # halo layers left after this sweep
-        cur = tuple(t + 2 * rem * h for t, h in zip(out_shape, halo))
-        acc = _window_apply(x, taps, halo, cur, acc_dtype, terms)
-        if rem:
-            g0s = tuple(starts[d] - rem * halo[d] for d in range(ndim))
-            acc = _restore_ghosts(acc, mode, value, g0s, grid_shape,
-                                  tuple(rem * h for h in halo))
-        x = acc
-    return x
-
-
 def masked_window_pipeline(window: jax.Array, stages, out_shape,
                            sweeps: int, starts, grid_shape,
                            acc_dtype) -> jax.Array:
-    """Apply ``sweeps`` fused applications of a stage *chain* to one
-    widened window — the pipeline generalization of
-    :func:`masked_window_sweeps` (to which it degenerates for one stage).
+    """Apply ``sweeps`` fused applications of a stage chain to one
+    widened window — the one fused core of the Pallas kernel
+    (``starts`` = ``program_id * tile``), the distributed shard-local
+    path (``starts`` = the shard's global offset, a traced
+    ``axis_index`` value) and the slab executor.  A spec runs as its
+    one-stage chain (:func:`~repro.core.stencil.as_stages`).
 
     ``window`` carries ``sweeps * H`` ghost layers per side around an
     ``out_shape`` interior at global coordinate ``starts`` of a
     ``grid_shape`` grid, where ``H`` is the per-dim **sum of the stage
-    halos** (each stage consumes its own radius per application).  The
-    caller must have filled the ghosts with the boundary extension of
-    ``stages[0]`` — the first consumer.
+    halos**: each stage application consumes its own radius, so the
+    final result is exactly ``out_shape``.  The caller must have filled
+    the ghosts with the boundary extension of ``stages[0]`` — the first
+    consumer (see :func:`pad_boundary` / the distributed halo
+    exchange).  ``out_shape``/``grid_shape`` must be static.
 
-    After each stage application (except the last overall), the
-    remaining ghost layers are restored to the boundary extension of the
-    **next stage to run** — ``stages[(k+1) % n]``, wrapping across
-    applications — via :func:`_restore_ghosts`.  That per-consumer
-    restoration is exactly the closed form of the chained oracle
-    re-padding with each stage's own mode, so f64 results are
-    bit-identical to ``sweeps`` chained :func:`apply_pipeline` calls.
-    Tile-local restoration is impossible for a periodic stage inside a
-    mixed chain (periodic ghosts are only correct while *every* stage
-    keeps them periodic), which is why lowering refuses to fuse such
-    pipelines — see :class:`repro.core.stencil.StencilPipeline.fusable`.
+    After each stage application (except the last overall), ghost
+    elements — those whose *global* coordinate falls outside the true
+    grid — are restored to the boundary extension of the **next stage
+    to run**, ``stages[(k+1) % n]``, wrapping across applications
+    (:func:`_restore_ghosts`): fill for ``zero``/``constant``, an
+    in-window mirror for ``reflect``, nothing for ``periodic`` (a
+    stencil applied to a periodically extended window keeps its ghosts
+    bitwise equal to their wrapped interior counterparts).  That
+    per-consumer restoration is exactly the closed form of the chained
+    oracle re-padding with each stage's own mode, so f64 results are
+    bit-identical to ``sweeps`` chained :func:`apply_pipeline` (for one
+    stage, :func:`apply_stencil`) calls.  Tile-local restoration is
+    impossible for a periodic stage inside a mixed chain (periodic
+    ghosts are only correct while *every* stage keeps them periodic),
+    which is why lowering refuses to fuse such pipelines — see
+    :func:`repro.core.stencil.fusable`.
 
     Per-stage compute dispatches on each stage's own structure class
-    through :func:`_window_apply`, pinning the f64 order per stage.
+    (:func:`repro.core.stencil.factor_taps`) through
+    :func:`_window_apply`: separable stages run
+    :func:`factored_window_apply`, star and dense stages the per-tap
+    path, each accumulating through :func:`tap_sum` in its pinned f64
+    order.
     """
     ndim = len(out_shape)
     stages = tuple(stages)

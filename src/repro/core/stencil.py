@@ -455,10 +455,9 @@ class StencilPipeline:
 
     @property
     def fusable(self) -> bool:
-        """Whether the chain admits tile-local fused execution (see the
-        class docstring): no periodic stage, or all stages periodic."""
-        modes = set(self.boundary_modes)
-        return "periodic" not in modes or modes == {"periodic"}
+        """Whether the chain admits tile-local fused execution
+        (:func:`fusable`)."""
+        return fusable(self)
 
     @property
     def n_taps(self) -> int:
@@ -485,6 +484,15 @@ def as_stages(spec) -> tuple[StencilSpec, ...]:
     if isinstance(spec, StencilPipeline):
         return spec.stages
     return (spec,)
+
+
+def fusable(spec) -> bool:
+    """Whether the stage chain of ``spec`` (:func:`as_stages`) admits
+    tile-local fused execution (see :class:`StencilPipeline`): no
+    periodic stage, or all stages periodic.  A plain spec always
+    fuses."""
+    modes = {s.boundary_mode for s in as_stages(spec)}
+    return "periodic" not in modes or modes == {"periodic"}
 
 
 def _star(ndim: int, radius: int, center: float, arm: float) -> tuple[Tap, ...]:

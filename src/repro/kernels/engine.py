@@ -2,12 +2,17 @@
 
 One generic `pallas_call` emitter replaces the three near-duplicate
 per-rank kernels the seed shipped (`stencil1d/2d/3d.py`).  For any
-:class:`~repro.core.stencil.StencilSpec` of rank 1-3 it builds a kernel
+:class:`~repro.core.stencil.StencilSpec` or fusable
+:class:`~repro.core.stencil.StencilPipeline` of rank 1-3 — a spec is
+the one-stage chain of itself, so each mechanism has one entry point
+for both (:func:`stencil_window_sweep`, :func:`stencil_sweep`,
+:func:`stencil_apply`, :func:`execute_plan`) — it builds a kernel
 parameterized by
 
 * **tile** — the VMEM output block owned by one grid step (the paper's
   "stencil segment block", §4.1);
-* **halo** — taken from the spec; the grid stays in HBM and each grid
+* **halo** — taken from the kernel (a chain's is the per-dim sum of its
+  stage radii); the grid stays in HBM and each grid
   step DMAs its window into VMEM, rounded out to the HBM layout's
   (8, 128) granule and cut to the exact window in VMEM — the software
   analogue of Casper's unaligned-load hardware;
@@ -24,17 +29,18 @@ parameterized by
   stencils.  This is the cache-aware time tiling of Frumkin & Van der
   Wijngaart applied at VMEM granularity.
 
-Boundary semantics (``spec.boundary``: zero / constant(c) / periodic /
-reflect) are preserved across fused sweeps: the fetched window is built
-with the mode's ghost extension (``ref.pad_boundary``), and between inner
-applications ghost elements — identified by *global* coordinate — are
-restored to the boundary extension of the intermediate: masked to the
-fill value (zero/constant), re-mirrored from the interior by an in-window
-gather (reflect), or left alone (periodic: the stencil of a periodically
-extended window keeps its ghosts bitwise equal to their wrapped interior
-counterparts).  Each is the closed form of the oracle re-padding before
-every sweep, so fused results stay f64 bit-identical to chained oracle
-applications under all four modes — see docs/boundaries.md.
+Boundary semantics (each stage's ``boundary``: zero / constant(c) /
+periodic / reflect) are preserved across fused sweeps: the fetched
+window is built with the mode's ghost extension (``ref.pad_boundary``),
+and between inner applications ghost elements — identified by *global*
+coordinate — are restored to the boundary extension of the
+intermediate: masked to the fill value (zero/constant), re-mirrored
+from the interior by an in-window gather (reflect), or left alone
+(periodic: the stencil of a periodically extended window keeps its
+ghosts bitwise equal to their wrapped interior counterparts).  Each is
+the closed form of the oracle re-padding before every sweep, so fused
+results stay f64 bit-identical to chained oracle applications under
+all four modes — see docs/boundaries.md.
 
 **Pad-free fused sweeps**: :func:`stencil_sweep` does not materialize a
 boundary-padded copy of the whole grid per fused call.  Each tile's
@@ -50,12 +56,13 @@ baseline only).  Grids that are not a multiple of the tile, or tiles
 shallower than the aligned fetch depth, fall back to the padded path.
 
 **Structure specialization**: per-application compute inside the kernel
-dispatches on ``spec.structure`` (star / separable / dense — see
-``repro.core.stencil.factor_taps``) through the shared
-``ref.masked_window_sweeps`` core, so separable specs (``blur2d``,
-``star33_3d``'s core) run factored axis passes with
-``O(sum)`` instead of ``O(prod)`` tap temporaries, bit-identically to
-the oracle in f64.
+dispatches on each stage's ``structure`` (star / separable / dense —
+see ``repro.core.stencil.factor_taps``) through the one fused core,
+``ref.masked_window_pipeline``, so separable specs (``blur2d``,
+``star33_3d``'s core) run factored axis passes with ``O(sum)`` instead
+of ``O(prod)`` tap temporaries, bit-identically to the oracle in f64.
+A chain's intermediate stage fields stay in VMEM; between stages the
+core restores ghosts per the *next* stage's mode.
 
 A leading batch dimension (``vmap``, see :func:`stencil_apply`) becomes
 a leading grid axis of the same kernel, so a stack of independent grids
@@ -80,7 +87,8 @@ from repro.core import ref as _ref
 from repro.core import perfmodel as _pm
 from repro.core import trace as _trace
 from repro.core.plan import resolve_interpret  # canonical auto-detect
-from repro.core.stencil import StencilPipeline, StencilSpec
+from repro.core.stencil import (StencilPipeline, StencilSpec, as_stages,
+                                fusable)
 
 # Tile defaulting/validation is a lowering decision and lives in
 # repro.core.plan; re-exported here for the existing call sites.
@@ -301,24 +309,19 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
     return out[tuple(slice(0, n) for n in out_shape)]
 
 
-def _spec_core(spec: StencilSpec, tile, sweeps, grid_shape, acc_dtype):
-    def core(x, starts):
-        return _ref.masked_window_sweeps(
-            x, tuple(spec.taps), spec.halo, tile, sweeps, starts,
-            grid_shape, acc_dtype, mode=spec.boundary_mode,
-            value=spec.boundary_value, structure=spec.structure)
-    return core
+def _core(spec, tile, sweeps, grid_shape, acc_dtype):
+    """The kernel body's compute: the one fused core over the stage
+    chain of ``spec`` (a plain spec is its own one-stage chain)."""
+    stages = as_stages(spec)
 
-
-def _pipeline_core(pipeline: StencilPipeline, tile, sweeps, grid_shape,
-                   acc_dtype):
     def core(x, starts):
         return _ref.masked_window_pipeline(
-            x, pipeline.stages, tile, sweeps, starts, grid_shape, acc_dtype)
+            x, stages, tile, sweeps, starts, grid_shape, acc_dtype)
     return core
 
 
-def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
+def stencil_window_sweep(spec: StencilSpec | StencilPipeline,
+                         window: jax.Array,
                          out_shape: Sequence[int],
                          origin,
                          grid_shape: Sequence[int],
@@ -326,22 +329,30 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
                          sweeps: int = 1,
                          interpret: bool | None = None,
                          mesh_tag=None) -> jax.Array:
-    """``sweeps`` fused applications to a block that already carries its
-    ``sweeps*halo``-wide halo.
+    """``sweeps`` fused applications of ``spec`` (a spec, or a fusable
+    pipeline chain) to a block that already carries its
+    ``sweeps*halo``-wide halo (a pipeline's ``halo`` is the per-dim sum
+    of its stage radii).
 
     ``window`` has shape ``out_shape + 2*sweeps*halo`` per dim and must
-    carry the ``spec.boundary`` ghost extension in its halo layers
+    carry stage 0's boundary ghost extension in its halo layers
     (``ref.pad_boundary`` on a single device, the halo exchange in the
     distributed path); the interior's origin sits at global coordinate
     ``origin`` (static ints or a traced value, e.g. ``axis_index`` inside
     shard_map) of a ``grid_shape`` grid, against which the between-sweep
     ghost restoration is evaluated.  This is the shard-local entry point
-    of the distributed deep-halo path; :func:`stencil_sweep` uses it for
-    the single-device padded-window fallback.  ``mesh_tag`` (the mesh
-    path's) adds its fields to the kernel tag (:func:`_kernel_tag`).
+    of the distributed deep-halo path and of the slab executor;
+    :func:`stencil_sweep` uses it for the single-device padded-window
+    fallback.  ``mesh_tag`` (the mesh path's) adds its fields to the
+    kernel tag (:func:`_kernel_tag`).  A chain that mixes periodic with
+    non-periodic stages cannot run fused and is refused.
     """
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
+    if not fusable(spec):
+        raise ValueError(
+            f"{spec.name}: mixed periodic/non-periodic stages cannot "
+            "run fused; lower the pipeline and use the staged plan")
     interpret = resolve_interpret(interpret)
     tile = _normalize_tile(spec, tile)
     out_shape = tuple(out_shape)
@@ -352,220 +363,111 @@ def stencil_window_sweep(spec: StencilSpec, window: jax.Array,
             f"window shape {window.shape} != out_shape + 2*sweeps*halo "
             f"{want}")
     grid_shape = tuple(int(n) for n in grid_shape)
-    core = _spec_core(spec, tile, sweeps, grid_shape,
-                      _acc_dtype(window.dtype))
+    core = _core(spec, tile, sweeps, grid_shape, _acc_dtype(window.dtype))
     return _fused_call(core, window, out_shape, origin, grid_shape, tile,
                        wide, fix=None, sweeps=sweeps, interpret=interpret,
                        mesh_tag=mesh_tag)
 
 
-def _resolve_strategy(spec, grid, sweeps, tile) -> str:
-    """Direct callers (no plan in hand) ask core.plan for the pad-free
-    vs padded-window decision; ``execute_plan`` passes the plan's."""
-    return _plan.ghost_strategy_for(spec, grid.shape, grid.dtype.itemsize,
-                                    sweeps, tile)
-
-
-def stencil_sweep(spec: StencilSpec, grid: jax.Array,
-                  tile: Sequence[int] | int | None = None,
-                  sweeps: int = 1,
-                  interpret: bool | None = None,
-                  strategy: str | None = None) -> jax.Array:
-    """``sweeps`` fused applications of ``spec`` to ``grid`` under the
-    spec's boundary mode, **pad-free**: the kernel fetches its window
-    straight from the unpadded grid and materializes boundary ghosts
-    in-kernel (see :func:`_fused_kernel`), so no padded copy of the grid
-    is built per fused call.
-
-    Equivalent to ``sweeps`` chained :func:`repro.core.ref.apply_stencil`
-    calls, but with a single HBM read/write per point instead of one per
-    sweep.  ``grid`` rank must equal ``spec.ndim`` (1-3); use
-    :func:`stencil_apply` for a leading batch dimension.  Grids that are
-    not a multiple of the tile, or whose tile is shallower than the
-    aligned fetch depth, fall back to the padded path
-    (:func:`stencil_window_sweep` on a ``ref.pad_boundary`` window —
-    identical results, the ghosts are bitwise equal either way).
-    """
-    if grid.ndim != spec.ndim:
-        raise ValueError(f"grid rank {grid.ndim} != spec ndim {spec.ndim}")
-    if sweeps < 1:
-        raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    interpret = resolve_interpret(interpret)
-    tile = _normalize_tile(spec, tile)
+def _sweep(spec, grid: jax.Array, tile: tuple[int, ...], sweeps: int,
+           interpret: bool, strategy: str) -> jax.Array:
+    """One fused block on one grid under a resolved ghost ``strategy``:
+    the pad-free fetch, or the padded-window fallback
+    (:func:`stencil_window_sweep` on a ``ref.pad_boundary`` window)."""
     wide = tuple(sweeps * h for h in spec.halo)
-    if strategy is None:
-        strategy = _resolve_strategy(spec, grid, sweeps, tile)
+    origin = (0,) * spec.ndim
     if strategy == "padded-window":
         window = _ref.pad_boundary(grid, wide, spec.boundary_mode,
                                    spec.boundary_value)
         return stencil_window_sweep(
-            spec, window, grid.shape, (0,) * spec.ndim, grid.shape,
+            spec, window, grid.shape, origin, grid.shape,
             tile=tile, sweeps=sweeps, interpret=interpret)
-    core = _spec_core(spec, tile, sweeps, grid.shape,
-                      _acc_dtype(grid.dtype))
-    return _fused_call(core, grid, grid.shape, (0,) * spec.ndim, grid.shape,
+    core = _core(spec, tile, sweeps, grid.shape, _acc_dtype(grid.dtype))
+    return _fused_call(core, grid, grid.shape, origin, grid.shape,
                        tile, wide,
                        fix=(spec.boundary_mode, spec.boundary_value),
                        sweeps=sweeps, interpret=interpret)
 
 
-def stencil_apply(spec: StencilSpec, grid: jax.Array,
-                  tile: Sequence[int] | int | None = None,
-                  sweeps: int = 1,
-                  interpret: bool | None = None,
-                  strategy: str | None = None) -> jax.Array:
-    """Rank-dispatching entry point with an optional leading batch dim.
-
-    ``grid.ndim == spec.ndim``    → one grid;
-    ``grid.ndim == spec.ndim+1``  → dim 0 is a batch of independent
-    grids, mapped with ``jax.vmap`` over one shared kernel.
-    """
-    interpret = resolve_interpret(interpret)
+def _batched(spec, grid: jax.Array, fn):
+    """``fn`` on one grid, or ``jax.vmap`` of it over a leading batch
+    dim (one shared kernel for the stack)."""
     if grid.ndim == spec.ndim:
-        return stencil_sweep(spec, grid, tile=tile, sweeps=sweeps,
-                             interpret=interpret, strategy=strategy)
+        return fn(grid)
     if grid.ndim == spec.ndim + 1:
-        fn = functools.partial(stencil_sweep, spec, tile=tile, sweeps=sweeps,
-                               interpret=interpret, strategy=strategy)
         return jax.vmap(fn)(grid)
     raise ValueError(
         f"grid rank {grid.ndim} incompatible with spec ndim {spec.ndim} "
         f"(expected ndim or ndim+1 for a batched grid)")
 
 
-# ---------------------------------------------------------------------------
-# Fused multi-stencil pipelines (StencilPipeline)
-# ---------------------------------------------------------------------------
-def pipeline_window_sweep(pipeline: StencilPipeline, window: jax.Array,
-                          out_shape: Sequence[int],
-                          origin,
-                          grid_shape: Sequence[int],
-                          tile: Sequence[int] | int | None = None,
-                          sweeps: int = 1,
-                          interpret: bool | None = None,
-                          mesh_tag=None) -> jax.Array:
-    """``sweeps`` fused chain applications to a block that already
-    carries its ``sweeps * H`` halo (``H`` = summed stage radii) filled
-    with stage 0's boundary extension — the pipeline analogue of
-    :func:`stencil_window_sweep`, and the shard-local entry point of the
-    distributed sum-of-radii deep-halo path.  The shared fused-chain
-    core (:func:`repro.core.ref.masked_window_pipeline`) consumes each
-    stage's radius in turn and restores between-stage ghosts per the
-    *next* stage's mode — bit-identical to the chained oracle in f64."""
-    if sweeps < 1:
-        raise ValueError(f"sweeps must be >= 1, got {sweeps}")
-    if not pipeline.fusable:
-        raise ValueError(
-            f"{pipeline.name}: mixed periodic/non-periodic stages cannot "
-            "run fused; lower the pipeline and use the staged plan")
-    interpret = resolve_interpret(interpret)
-    tile = _normalize_tile(pipeline, tile)
-    out_shape = tuple(out_shape)
-    wide = tuple(sweeps * h for h in pipeline.halo)
-    want = tuple(n + 2 * w for n, w in zip(out_shape, wide))
-    if window.shape != want:
-        raise ValueError(
-            f"window shape {window.shape} != out_shape + 2*sweeps*H "
-            f"{want}")
-    grid_shape = tuple(int(n) for n in grid_shape)
-    core = _pipeline_core(pipeline, tile, sweeps, grid_shape,
-                          _acc_dtype(window.dtype))
-    return _fused_call(core, window, out_shape, origin, grid_shape, tile,
-                       wide, fix=None, sweeps=sweeps, interpret=interpret,
-                       mesh_tag=mesh_tag)
+def stencil_sweep(spec: StencilSpec | StencilPipeline, grid: jax.Array,
+                  tile: Sequence[int] | int | None = None,
+                  sweeps: int = 1,
+                  interpret: bool | None = None) -> jax.Array:
+    """``sweeps`` fused applications of ``spec`` (a spec, or a
+    pipeline chain) to ``grid`` under its boundary modes, **pad-free**:
+    the kernel fetches its window straight from the unpadded grid and
+    materializes boundary ghosts in-kernel (see :func:`_fused_kernel`),
+    so no padded copy of the grid is built per fused call, and a
+    chain's intermediate stage fields stay in VMEM.
 
-
-def pipeline_sweep(pipeline: StencilPipeline, grid: jax.Array,
-                   tile: Sequence[int] | int | None = None,
-                   sweeps: int = 1,
-                   interpret: bool | None = None,
-                   strategy: str | None = None) -> jax.Array:
-    """``sweeps`` fused applications of a stage chain: one HBM read of
-    the ``sweeps * H``-widened window and one write per tile — every
-    intermediate stage field stays in VMEM, never round-tripping HBM.
-    Bit-identical in f64 to ``sweeps`` chained
-    :func:`repro.core.ref.apply_pipeline` calls.
-
-    Strategy resolution mirrors :func:`stencil_sweep` (pad-free fetch;
-    padded-window fallback).  A non-fusable chain (mixed periodic with
-    non-periodic stages — between-stage ghost restoration is not
-    tile-local) executes ``"staged"``: per-stage single-sweep kernels,
-    chained semantics at per-stage traffic.
+    Equivalent to ``sweeps`` chained :func:`repro.core.ref.apply_stencil`
+    (:func:`~repro.core.ref.apply_pipeline`) calls, but with a single
+    HBM read/write per point instead of one per stage application.
+    ``grid`` rank must equal ``spec.ndim`` (1-3); use
+    :func:`stencil_apply` for a leading batch dimension.  Grids that are
+    not a multiple of the tile, or whose tile is shallower than the
+    aligned fetch depth, fall back to the padded path
+    (:func:`stencil_window_sweep` on a ``ref.pad_boundary`` window —
+    identical results, the ghosts are bitwise equal either way); the
+    choice is ``plan.ghost_strategy_for``'s.  A chain that mixes
+    periodic with non-periodic stages runs stage by stage through its
+    staged plan (``plan.execute``).
     """
-    if grid.ndim != pipeline.ndim:
-        raise ValueError(
-            f"grid rank {grid.ndim} != pipeline ndim {pipeline.ndim}")
+    if grid.ndim != spec.ndim:
+        raise ValueError(f"grid rank {grid.ndim} != spec ndim {spec.ndim}")
     if sweeps < 1:
         raise ValueError(f"sweeps must be >= 1, got {sweeps}")
     interpret = resolve_interpret(interpret)
-    tile = _normalize_tile(pipeline, tile)
-    if strategy is None:
-        strategy = ("staged" if not pipeline.fusable else
-                    _resolve_strategy(pipeline, grid, sweeps, tile))
-    if strategy == "staged":
-        out = grid
-        for _ in range(sweeps):
-            for stage in pipeline.stages:
-                out = stencil_sweep(stage, out, tile=tile, sweeps=1,
-                                    interpret=interpret)
-        return out
-    if not pipeline.fusable:
-        raise ValueError(
-            f"{pipeline.name}: mixed periodic/non-periodic stages cannot "
-            f"run fused (requested strategy {strategy!r}); use "
-            "strategy='staged'")
-    wide = tuple(sweeps * h for h in pipeline.halo)
-    if strategy == "padded-window":
-        window = _ref.pad_boundary(grid, wide, pipeline.boundary_mode,
-                                   pipeline.boundary_value)
-        return pipeline_window_sweep(
-            pipeline, window, grid.shape, (0,) * pipeline.ndim, grid.shape,
-            tile=tile, sweeps=sweeps, interpret=interpret)
-    core = _pipeline_core(pipeline, tile, sweeps, grid.shape,
-                          _acc_dtype(grid.dtype))
-    return _fused_call(core, grid, grid.shape, (0,) * pipeline.ndim,
-                       grid.shape, tile, wide,
-                       fix=(pipeline.boundary_mode, pipeline.boundary_value),
-                       sweeps=sweeps, interpret=interpret)
+    if not fusable(spec):
+        return _plan.execute(
+            _plan.lower(spec, grid.shape, grid.dtype, backend="pallas",
+                        sweeps=sweeps, tile=tile, interpret=interpret), grid)
+    tile = _normalize_tile(spec, tile)
+    strategy = _plan.ghost_strategy_for(spec, grid.shape,
+                                        grid.dtype.itemsize, sweeps, tile)
+    return _sweep(spec, grid, tile, sweeps, interpret, strategy)
 
 
-def pipeline_apply(pipeline: StencilPipeline, grid: jax.Array,
-                   tile: Sequence[int] | int | None = None,
-                   sweeps: int = 1,
-                   interpret: bool | None = None,
-                   strategy: str | None = None) -> jax.Array:
-    """Pipeline analogue of :func:`stencil_apply`: one grid, or a
-    leading batch dim vmapped over one shared fused-chain kernel."""
+def stencil_apply(spec: StencilSpec | StencilPipeline, grid: jax.Array,
+                  tile: Sequence[int] | int | None = None,
+                  sweeps: int = 1,
+                  interpret: bool | None = None) -> jax.Array:
+    """Rank-dispatching entry point with an optional leading batch dim.
+
+    ``grid.ndim == spec.ndim``    → one grid (:func:`stencil_sweep`);
+    ``grid.ndim == spec.ndim+1``  → dim 0 is a batch of independent
+    grids, mapped with ``jax.vmap`` over one shared kernel.
+    """
     interpret = resolve_interpret(interpret)
-    if grid.ndim == pipeline.ndim:
-        return pipeline_sweep(pipeline, grid, tile=tile, sweeps=sweeps,
-                              interpret=interpret, strategy=strategy)
-    if grid.ndim == pipeline.ndim + 1:
-        fn = functools.partial(pipeline_sweep, pipeline, tile=tile,
-                               sweeps=sweeps, interpret=interpret,
-                               strategy=strategy)
-        return jax.vmap(fn)(grid)
-    raise ValueError(
-        f"grid rank {grid.ndim} incompatible with pipeline ndim "
-        f"{pipeline.ndim} (expected ndim or ndim+1 for a batched grid)")
+    return _batched(spec, grid, functools.partial(
+        stencil_sweep, spec, tile=tile, sweeps=sweeps,
+        interpret=interpret))
 
 
 def execute_plan(plan, grid: jax.Array) -> jax.Array:
     """Thin Pallas executor of one lowered
-    :class:`~repro.core.plan.ExecutionPlan`: one fused block of
-    ``plan.sweeps`` applications with the plan's resolved tile and
-    ghost strategy (an optional leading batch dim vmaps over one shared
-    kernel, exactly as :func:`stencil_apply`).  Pipeline plans run the
-    fused-chain kernel."""
+    :class:`~repro.core.plan.ExecutionPlan` (a spec's or a fused
+    chain's): one fused block of ``plan.sweeps`` applications with the
+    plan's resolved tile and ghost strategy (an optional leading batch
+    dim vmaps over one shared kernel, exactly as
+    :func:`stencil_apply`)."""
     if plan.backend != "pallas":
         raise ValueError(f"not a pallas plan: backend={plan.backend!r}")
-    if plan.is_pipeline:
-        return pipeline_apply(plan.spec, grid, tile=plan.tile,
-                              sweeps=plan.sweeps, interpret=plan.interpret,
-                              strategy=plan.ghost_strategy)
-    return stencil_apply(plan.spec, grid, tile=plan.tile,
-                         sweeps=plan.sweeps, interpret=plan.interpret,
-                         strategy=plan.ghost_strategy)
+    return _batched(plan.spec, grid, functools.partial(
+        _sweep, plan.spec, tile=plan.tile, sweeps=plan.sweeps,
+        interpret=plan.interpret, strategy=plan.ghost_strategy))
 
 
 def run_sweeps(spec: StencilSpec, grid: jax.Array, iters: int,

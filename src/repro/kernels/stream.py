@@ -7,11 +7,12 @@ host-resident and streams through the device in slabs along the
 outermost axis.  The slab boundary is *just a halo against host
 memory* — each uploaded window carries ``sweeps * halo`` ghost rows per
 side gathered from the neighbouring host rows (PR 2's deep-halo
-arithmetic verbatim), so the existing fused window executors
-(``ref.masked_window_sweeps`` / ``kernels.engine.stencil_window_sweep``
-and their pipeline twins) compute every slab unchanged and the result
-stays f64 bit-identical to the whole-grid oracle (pinned across the
-rank x boundary x sweeps x structure matrix in tests/test_slabs.py).
+arithmetic verbatim), so the fused window executors compute every slab
+unchanged — ``kernels.engine.stencil_window_sweep`` on ``pallas`` and
+its core ``ref.masked_window_pipeline`` on ``ref``, one call each for a
+spec or a pipeline — and the result stays f64 bit-identical to the
+whole-grid oracle (pinned across the rank x boundary x sweeps x
+structure matrix in tests/test_slabs.py).
 
 Execution double-buffers: while slab ``k`` computes on device, slab
 ``k+1``'s window is gathered and uploaded behind it and slab ``k-1``'s
@@ -79,26 +80,14 @@ def _slab_fn(plan, slab_len: int):
     @functools.partial(jax.jit, donate_argnums=donate)
     def run(window, start0):
         starts = [start0] + [0] * (len(plan.shape) - 1)
-        if plan.is_pipeline:
-            if plan.backend in _plan.KERNEL_BACKENDS:
-                from repro.kernels import engine as keng  # lazy: optional dep
-                return keng.pipeline_window_sweep(
-                    spec, window, out_shape, starts, plan.shape,
-                    tile=plan.tile, sweeps=plan.sweeps,
-                    interpret=plan.interpret)
-            return _ref.masked_window_pipeline(
-                window, spec.stages, out_shape, plan.sweeps, starts,
-                plan.shape, window.dtype).astype(window.dtype)
         if plan.backend in _plan.KERNEL_BACKENDS:
             from repro.kernels import engine as keng      # lazy: optional dep
             return keng.stencil_window_sweep(
                 spec, window, out_shape, starts, plan.shape,
                 tile=plan.tile, sweeps=plan.sweeps, interpret=plan.interpret)
-        return _ref.masked_window_sweeps(
-            window, spec.taps, plan.halo, out_shape, plan.sweeps, starts,
-            plan.shape, window.dtype, mode=plan.boundary_mode,
-            value=plan.boundary_value,
-            structure=spec.structure).astype(window.dtype)
+        return _ref.masked_window_pipeline(
+            window, plan.stages, out_shape, plan.sweeps, starts,
+            plan.shape, window.dtype).astype(window.dtype)
     return run
 
 

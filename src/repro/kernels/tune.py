@@ -4,7 +4,9 @@ Ranks candidate output tiles with the first-order TPU cost models in
 :mod:`repro.core.perfmodel` (``pallas_tile_cost``): memory traffic of
 the aligned fetch windows vs compute roofline, VMEM feasibility,
 alignment padding and per-step sequencing overhead.  The analytic pass
-is free, so it runs for every (spec, shape, sweeps) the engine sees;
+is free, so it runs for every (spec, shape, sweeps) the engine sees,
+the spec being a :class:`StencilSpec` or a fused pipeline (a spec is
+the one-stage chain of itself);
 ``autotune_measured`` additionally wall-clocks the top analytic
 candidates on the real array (interpret mode on CPU, compiled on the
 chip) and re-ranks by measurement.
@@ -14,13 +16,13 @@ Every candidate extent is a multiple of the HBM layout's granule
 sublanes x 128 lanes for 32-bit data in rank >= 2), so the kernel's
 window DMAs stay aligned.  See docs/kernels.md.
 
-The spec's boundary mode participates in the ranking (``reflect``
+Each stage's boundary mode participates in the ranking (``reflect``
 charges the between-sweep ghost re-mirroring gather) and so does its
 tap *structure*: the compute term uses the factored per-point flop
-count (``spec.structured_flops_per_point()``) and the feasibility check
+count (``structured_flops_per_point()``) and the feasibility check
 charges one live window-sized intermediate per factored term.  All of
-it enters the cache key: ``autotune`` is memoized on the full
-``StencilSpec`` (boundary + structure included).
+it enters the cache key: ``autotune`` is memoized on the full spec
+(stage order, boundary and structure included).
 
 ``autotune_measured`` results can persist across processes: point
 ``CASPER_TUNE_CACHE`` at a directory and each measured tune is stored
@@ -104,50 +106,30 @@ class TuneResult:
         }
 
 
-def autotune(spec: StencilSpec, shape: tuple[int, ...], sweeps: int = 1,
+def autotune(spec, shape: tuple[int, ...], sweeps: int = 1,
              itemsize: int = 4) -> TuneResult:
     """Best tile for (spec, shape, sweeps) under the analytic cost
-    model."""
+    model, where ``spec`` is a :class:`StencilSpec` or a fused
+    :class:`~repro.core.stencil.StencilPipeline` chain.  Memoized on
+    the full spec — taps, stage order, boundary and structure all
+    participate."""
     return _autotune(spec, tuple(shape), sweeps, itemsize)
 
 
-def _rank(kernel, shape, sweeps, itemsize, cost) -> TuneResult:
-    tiles = candidate_tiles(kernel.ndim, shape, itemsize, kernel, sweeps)
+@functools.lru_cache(maxsize=512)
+def _autotune(spec, shape: tuple[int, ...], sweeps: int,
+              itemsize: int) -> TuneResult:
+    tiles = candidate_tiles(spec.ndim, shape, itemsize, spec, sweeps)
+    cost = functools.partial(pm.pallas_tile_cost, spec, shape,
+                             sweeps=sweeps, itemsize=itemsize)
     scored = sorted(((tile, cost(tile)) for tile in tiles),
                     key=lambda tc: tc[1])
     best, best_cost = scored[0]
     if math.isinf(best_cost):
         raise ValueError(
-            f"no candidate tile fits VMEM for {kernel.name} "
+            f"no candidate tile fits VMEM for {spec.name} "
             f"sweeps={sweeps}")
     return TuneResult(best, best_cost, tuple(scored))
-
-
-@functools.lru_cache(maxsize=512)
-def _autotune(spec: StencilSpec, shape: tuple[int, ...], sweeps: int,
-              itemsize: int) -> TuneResult:
-    return _rank(spec, shape, sweeps, itemsize,
-                 lambda tile: pm.pallas_tile_cost(
-                     spec, shape, tile, sweeps=sweeps, itemsize=itemsize))
-
-
-def autotune_pipeline(pipeline, shape: tuple[int, ...], sweeps: int = 1,
-                      itemsize: int = 4) -> TuneResult:
-    """Best tile for a fused :class:`~repro.core.stencil.StencilPipeline`
-    chain: same candidate lists, ranked by the pipeline cost model
-    (summed-halo window traffic, per-stage structured compute at the
-    exact element-layer schedule).  Memoized on the full pipeline —
-    stage order, per-stage boundary and structure all participate."""
-    return _autotune_pipeline(pipeline, tuple(shape), sweeps, itemsize)
-
-
-@functools.lru_cache(maxsize=512)
-def _autotune_pipeline(pipeline, shape: tuple[int, ...], sweeps: int,
-                       itemsize: int) -> TuneResult:
-    return _rank(pipeline, shape, sweeps, itemsize,
-                 lambda tile: pm.pallas_pipeline_tile_cost(
-                     pipeline, shape, tile, sweeps=sweeps,
-                     itemsize=itemsize))
 
 
 # ---------------------------------------------------------------------------

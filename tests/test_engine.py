@@ -83,7 +83,8 @@ def test_f64_bit_identical_to_oracle(name, strategy, rng):
                    else PADFREE[spec.ndim])
     with enable_x64():
         g = jnp.asarray(rng.standard_normal(shape), jnp.float64)
-        assert engine._resolve_strategy(spec, g, 1, tile) == strategy
+        assert plan.ghost_strategy_for(spec, g.shape, g.dtype.itemsize, 1,
+                                       tile) == strategy
         got = engine.stencil_apply(spec, g, tile=tile)
         assert got.dtype == jnp.float64
         assert bool(jnp.all(got == cref.apply_stencil(spec, g))), name
@@ -242,7 +243,7 @@ def test_wide_tiles_only_for_quickly_compiled_bodies(name, boundary, shape,
     offered to every kernel."""
     if boundary is None:
         kernel = PAPER_PIPELINES[name]
-        tile = tune.autotune_pipeline(kernel, shape, sweeps=sweeps).tile
+        tile = tune.autotune(kernel, shape, sweeps=sweeps).tile
     else:
         kernel = PAPER_STENCILS[name].with_boundary(boundary)
         tile = tune.autotune(kernel, shape, sweeps=sweeps).tile

@@ -136,9 +136,10 @@ def test_unfusable_plan_lowers_staged():
 def test_pipeline_window_sweep_rejects_unfusable():
     from repro.kernels import engine as keng
     mixed = StencilPipeline("m3", (rc.jacobi2d(), rc.advect2d()))
+    window = jnp.zeros(tuple(n + 2 * h for n, h in zip((16, 32),
+                                                       mixed.halo)))
     with pytest.raises(ValueError, match="cannot\\s+run fused"):
-        keng.pipeline_sweep(mixed, jnp.zeros((16, 32)),
-                            strategy="pad-free")
+        keng.stencil_window_sweep(mixed, window, (16, 32), (0, 0), (16, 32))
 
 
 # ---------------------------------------------------------------------------
@@ -268,10 +269,59 @@ def test_pipeline_autotune_and_cost_model():
     from repro.core import perfmodel as pm
     from repro.kernels import tune
     p = rc.reaction_diffusion2d()
-    res = tune.autotune_pipeline(p, (256, 512))
+    res = tune.autotune(p, (256, 512))
     assert res.tile in dict(res.table)
-    cost = pm.pallas_pipeline_tile_cost(p, (256, 512), res.tile)
+    cost = pm.pallas_tile_cost(p, (256, 512), res.tile)
     assert np.isfinite(cost) and cost > 0
     # a tile that cannot hold the widened window in VMEM is infeasible
-    assert pm.pallas_pipeline_tile_cost(
+    assert pm.pallas_tile_cost(
         p, (1 << 14, 1 << 14), (8192, 8192)) == float("inf")
+
+
+# ---------------------------------------------------------------------------
+# A spec is the one-stage pipeline of itself
+# ---------------------------------------------------------------------------
+ONE_STAGE_BOUNDARIES = ("zero", "constant(0.75)", "periodic", "reflect")
+#: Per rank: a benchmark-sized grid (ranked by the cost model only) and
+#: a small grain-aligned one (also run through the kernel in f64).
+ONE_STAGE_SHAPES = {1: ((1 << 24,), (4096,)),
+                    2: ((16384, 16384), (32, 256)),
+                    3: ((512, 512, 512), (8, 16, 128))}
+
+
+@pytest.mark.parametrize("sweeps", [1, 2, 4])
+@pytest.mark.parametrize("boundary", ONE_STAGE_BOUNDARIES)
+@pytest.mark.parametrize("name", list(rc.PAPER_STENCILS))
+def test_one_stage_pipeline_is_its_spec(name, boundary, sweeps):
+    """A spec and the pipeline of that one spec take every decision
+    below ``plan.lower`` alike: the tile cost at every candidate and
+    wide tile, the quick-compile rule, the autotuned tile, the lowered
+    tile and ghost strategy, and the fused kernel's f64 output, bit for
+    bit."""
+    from repro.core import perfmodel as pm
+    from repro.kernels import engine as keng
+    from repro.kernels import tune
+    spec = rc.PAPER_STENCILS[name].with_boundary(boundary)
+    pipe = StencilPipeline("one", (spec,))
+    big, small = ONE_STAGE_SHAPES[spec.ndim]
+    tiles = tune.CANDIDATE_TILES[spec.ndim] + tune.WIDE_TILES.get(
+        spec.ndim, ())
+    for shape in (big, small):
+        for tile in tiles:
+            assert pm.pallas_tile_cost(spec, shape, tile, sweeps) \
+                == pm.pallas_tile_cost(pipe, shape, tile, sweeps)
+        assert tune.autotune(spec, shape, sweeps).tile \
+            == tune.autotune(pipe, shape, sweeps).tile
+    for tile in tiles:
+        assert pm.compiles_quickly(spec, tile, sweeps) \
+            == pm.compiles_quickly(pipe, tile, sweeps)
+    with enable_x64():
+        plans = [_plan.lower(k, small, jnp.float64, backend="pallas",
+                             sweeps=sweeps, tile="auto")
+                 for k in (spec, pipe)]
+        assert plans[0].tile == plans[1].tile
+        assert plans[0].ghost_strategy == plans[1].ghost_strategy
+        g = _grid(small, np.random.default_rng(23))
+        got = keng.stencil_sweep(pipe, g, tile=plans[1].tile, sweeps=sweeps)
+        want = keng.stencil_sweep(spec, g, tile=plans[0].tile, sweeps=sweeps)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

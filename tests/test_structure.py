@@ -11,6 +11,7 @@ import jax.numpy as jnp
 
 from repro.core import (PAPER_STENCILS, CasperEngine, StencilSpec, assemble,
                         factor_taps, plan_streams)
+from repro.core import plan as _plan
 from repro.core import ref as cref
 from repro.kernels import engine
 
@@ -158,7 +159,9 @@ def test_structure_equivalence_matrix_f64_bitwise(name, boundary, sweeps,
     shape, tile = STRATEGY_CASES[strategy][spec.ndim]
     with enable_x64():
         g = jnp.asarray(rng.standard_normal(shape), jnp.float64)
-        assert engine._resolve_strategy(spec, g, sweeps, tile) == strategy
+        assert _plan.ghost_strategy_for(
+            spec, g.shape, g.dtype.itemsize, sweeps, tile) \
+            == strategy
         got = engine.stencil_apply(spec, g, tile=tile, sweeps=sweeps)
         want = jax.jit(lambda x: cref.run_iterations(spec, x, sweeps))(g)
         assert bool(jnp.all(got == want)), (name, boundary)
@@ -191,7 +194,9 @@ def test_dense_spec_f64_bitwise(boundary, strategy, rng):
     shape, tile = STRATEGY_CASES[strategy][2]
     with enable_x64():
         g = jnp.asarray(rng.standard_normal(shape), jnp.float64)
-        assert engine._resolve_strategy(spec, g, 2, tile) == strategy
+        assert _plan.ghost_strategy_for(
+            spec, g.shape, g.dtype.itemsize, 2, tile) \
+            == strategy
         got = engine.stencil_apply(spec, g, tile=tile, sweeps=2)
         want = jax.jit(lambda x: cref.run_iterations(spec, x, 2))(g)
         assert bool(jnp.all(got == want)), boundary
@@ -212,7 +217,9 @@ def test_padfree_matches_padded_window_path(boundary, rng):
     with enable_x64():
         g = jnp.asarray(rng.standard_normal((64, 256)), jnp.float64)
         sweeps, tile = 3, (16, 128)
-        assert engine._resolve_strategy(spec, g, sweeps, tile) == "pad-free"
+        assert _plan.ghost_strategy_for(
+            spec, g.shape, g.dtype.itemsize, sweeps, tile) \
+            == "pad-free"
         padfree = engine.stencil_sweep(spec, g, tile=tile, sweeps=sweeps)
         wide = tuple(sweeps * h for h in spec.halo)
         window = cref.pad_boundary(g, wide, spec.boundary_mode,
@@ -231,10 +238,14 @@ def test_periodic_strategy_independent_of_grid_size(rng):
     from jax import enable_x64
     spec = PAPER_STENCILS["jacobi2d"].with_boundary("periodic")
     big = jax.ShapeDtypeStruct((4096, 4096), jnp.float32)
-    assert engine._resolve_strategy(spec, big, 4, (32, 256)) == "pad-free"
+    assert _plan.ghost_strategy_for(
+        spec, big.shape, big.dtype.itemsize, 4, (32, 256)) \
+        == "pad-free"
     with enable_x64():
         g = jnp.asarray(rng.standard_normal((70, 130)), jnp.float64)
-        assert engine._resolve_strategy(spec, g, 3, None) == "padded-window"
+        assert _plan.ghost_strategy_for(
+            spec, g.shape, g.dtype.itemsize, 3, None) \
+            == "padded-window"
         got = engine.stencil_sweep(spec, g, sweeps=3)
         want = jax.jit(lambda x: cref.run_iterations(spec, x, 3))(g)
         assert bool(jnp.all(got == want))

@@ -80,7 +80,7 @@ def test_padded_window_kernel_compiles(one_chip):
 
 def test_pipeline_kernel_compiles(one_chip):
     pipe = PAPER_PIPELINES["reaction_diffusion2d"]
-    fn = functools.partial(engine.pipeline_sweep, pipe, tile=(32, 512),
+    fn = functools.partial(engine.stencil_sweep, pipe, tile=(32, 512),
                            sweeps=2, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, (4096, 4096),
                                                sharding=one_chip)
