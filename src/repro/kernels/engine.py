@@ -156,6 +156,18 @@ def _kernel_tag(strategy: str, sweeps: int, tile: Sequence[int],
             **(mesh_tag or {})}
 
 
+def window_extent(out_shape: Sequence[int], tile: Sequence[int],
+                  wide: Sequence[int], itemsize: int) -> tuple[int, ...]:
+    """Extents of the source a ``window`` kernel fetches from: the
+    ``out_shape`` block with ``wide`` ghost layers per side, its far end
+    rounded out so that the last tile's aligned buffer (``tile +
+    2*wide`` rounded up to the HBM granule,
+    :func:`repro.core.perfmodel.fetch_grain`) lies inside it."""
+    grain = _pm.fetch_grain(len(tile), itemsize)
+    return tuple(-(-n // t) * t - t + -(-(t + 2 * w) // g) * g
+                 for n, t, w, g in zip(out_shape, tile, wide, grain))
+
+
 def _fused_kernel(org_ref, src_ref, o_ref, buf, sem, *, core, tile, wide,
                   lo, cut, grain, grid_shape, wrap, fix, batched):
     """One grid step of every fused kernel: DMA the tile's aligned
@@ -248,8 +260,10 @@ def _fused_call(core, src: jax.Array, out_shape: Sequence[int], origin,
         ext = tuple(-(-(t + 2 * w) // g) * g
                     for t, w, g in zip(tile, wide, grain))
         cut = (0,) * ndim
-        src = jnp.pad(src, [(0, p - t + e - n - 2 * w) for p, t, e, n, w
-                            in zip(padded, tile, ext, out_shape, wide)])
+        extent = window_extent(out_shape, tile, wide, src.dtype.itemsize)
+        if src.shape != extent:
+            src = jnp.pad(src, [(0, e - n)
+                                for e, n in zip(extent, src.shape)])
     if not interpret and any(t % g for t, g in zip(tile, grain)):
         raise ValueError(f"compiled tile {tile} is not a multiple of the "
                          f"HBM granule {grain}")
@@ -334,7 +348,9 @@ def stencil_window_sweep(spec: StencilSpec | StencilPipeline,
     ``sweeps*halo``-wide halo (a pipeline's ``halo`` is the per-dim sum
     of its stage radii).
 
-    ``window`` has shape ``out_shape + 2*sweeps*halo`` per dim and must
+    ``window`` has shape ``out_shape + 2*sweeps*halo`` per dim, or
+    already the :func:`window_extent` the kernel fetches from (its far
+    end rounded out; the mesh path assembles it so), and must
     carry stage 0's boundary ghost extension in its halo layers
     (``ref.pad_boundary`` on a single device, the halo exchange in the
     distributed path); the interior's origin sits at global coordinate
@@ -358,10 +374,11 @@ def stencil_window_sweep(spec: StencilSpec | StencilPipeline,
     out_shape = tuple(out_shape)
     wide = tuple(sweeps * h for h in spec.halo)
     want = tuple(n + 2 * w for n, w in zip(out_shape, wide))
-    if window.shape != want:
+    if window.shape not in (want, window_extent(out_shape, tile, wide,
+                                                window.dtype.itemsize)):
         raise ValueError(
             f"window shape {window.shape} != out_shape + 2*sweeps*halo "
-            f"{want}")
+            f"{want} (or its window_extent)")
     grid_shape = tuple(int(n) for n in grid_shape)
     core = _core(spec, tile, sweeps, grid_shape, _acc_dtype(window.dtype))
     return _fused_call(core, window, out_shape, origin, grid_shape, tile,

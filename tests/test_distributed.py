@@ -184,6 +184,83 @@ def test_distributed_boundary_modes_bit_identical():
     assert "boundary matrix ok 32" in out
 
 
+@pytest.mark.parametrize("boundary,strategy", [
+    ("zero", "zero-fill"), ("periodic", "wrap-ring"),
+    ("constant(0.5)", "edge-fixup"), ("reflect", "edge-fixup")])
+def test_face_built_window_equals_concatenated_block(boundary, strategy):
+    """The mesh path's window, assembled once from the shard and the
+    exchanged faces (``halo._faces``, ``halo._window``), is f64
+    bit-identical to the shard's slice of the whole grid extended by
+    ``ref.pad_boundary`` (the window the exchange used to build by
+    concatenating, axis by axis), on 1-D, 2-D and 3-D meshes, multi-hop
+    slivers, split edge rows and an unsharded middle dim; the rounded
+    extent the kernel fetches from holds the same window."""
+    out = run_sub(8, f"""
+        from jax import enable_x64
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import PAPER_STENCILS, halo
+        from repro.core import plan as cplan
+        from repro.core import ref as cref
+
+        boundary, strategy = {boundary!r}, {strategy!r}
+
+        def expected(plan, grid, mesh, axes):
+            # each shard's window, cut from the whole grid's boundary
+            # extension and laid out as shard_map gathers them
+            whole = np.asarray(cref.pad_boundary(
+                grid, list(plan.deep_halo), plan.boundary_mode,
+                plan.boundary_value))
+            index = []
+            for d, w in enumerate(plan.deep_halo):
+                k = 1 if axes[d] is None else mesh.shape[axes[d]]
+                n = grid.shape[d] // k
+                index.append(np.concatenate(
+                    [np.arange(i * n, i * n + n + 2 * w) for i in range(k)]))
+            return whole[np.ix_(*index)]
+
+        rng = np.random.default_rng(3)
+        # name, shape, mesh, axes, sweeps
+        cases = [
+            ("jacobi1d", (64,), (8,), ["sx"], 4),             # whole block
+            ("jacobi1d", (256,), (8,), ["sx"], 2),            # split rows
+            ("7pt1d", (32,), (8,), ["sx"], 4),                # 3 hops
+            ("blur2d", (16, 48), (1, 8), ["sx", "sy"], 4),    # sliver
+            ("blur2d", (64, 96), (4, 2), ["sx", "sy"], 2),
+            ("heat3d", (16, 16, 24), (2, 2, 2), ["sx", "sy", "sz"], 2),
+            ("heat3d", (32, 16, 24), (4, 2), ["sx", None, "sy"], 4),
+        ]
+        n_ok = 0
+        with enable_x64():
+            for name, shape, mshape, axes, t in cases:
+                mesh = jax.make_mesh(mshape, ("sx", "sy", "sz")[:len(mshape)])
+                spec = PAPER_STENCILS[name].with_boundary(boundary)
+                plan = cplan.lower(spec, shape, jnp.float64, backend="ref",
+                                   sweeps=t, mesh=mesh, grid_axes=tuple(axes))
+                assert set(plan.exchange[d] for d, a in enumerate(axes)
+                           if a is not None) == {{strategy}}, plan.exchange
+                grid = jnp.asarray(rng.standard_normal(shape), jnp.float64)
+                want = expected(plan, grid, mesh, axes)
+                g = jax.device_put(grid, NamedSharding(mesh, P(*axes)))
+                for more in (0, 3):
+                    def window(x, more=more):
+                        extent = [n + 2 * w + more for n, w
+                                  in zip(x.shape, plan.deep_halo)]
+                        got = halo._window(plan, x, halo._faces(plan, x),
+                                           extent)
+                        return got[tuple(slice(0, e - more)
+                                         for e in extent)]
+                    got = np.asarray(jax.jit(jax.shard_map(
+                        window, mesh=mesh, in_specs=(P(*axes),),
+                        out_specs=P(*axes), check_vma=False))(g))
+                    assert got.dtype == want.dtype == np.float64
+                    assert np.array_equal(got, want), (
+                        name, axes, more, np.max(np.abs(got - want)))
+                    n_ok += 1
+        print("windows equal", n_ok)
+    """)
+    assert "windows equal 14" in out
+
+
 def test_periodic_wrap_ring_has_no_extra_launches():
     """The periodic wrap-ring costs the same number of collective-permute
     launches as the zero-boundary exchange (the ring only changes the

@@ -226,28 +226,89 @@ def mesh_cell(topo):
     return mesh, axes, arg
 
 
-def test_mesh_cell_solve_fits_and_carries_the_mesh_tag(mesh_cell):
-    """The mesh cell's 1,000-step solve: one shard-local kernel tagged
-    with the 2x2 shards and the 16 MiB a shard receives per block (a
-    4-deep 1024x512 face on sx, then a 1032x512 one on sy), and the
-    grid, its output and the temporaries inside a chip's HBM."""
+@pytest.fixture(scope="module")
+def mesh_solve(mesh_cell):
+    """The mesh cell's 1,000-step solve, compiled once."""
     mesh, axes, arg = mesh_cell
     eng = CasperEngine(PAPER_STENCILS["heat3d"], backend="pallas", sweeps=4,
                        tile="auto", interpret=False)
-    compiled = eng.distributed_fn(mesh, axes, iters=1000).lower(arg).compile()
+    return eng.distributed_fn(mesh, axes, iters=1000).lower(arg).compile()
+
+
+MESH_SHARD = (1024, 1024, 512)
+MESH_WINDOW = (1032, 1032, 640)     # 4 ghosts a side, z rounded to 640
+
+
+def test_mesh_cell_solve_fits_and_carries_the_mesh_tag(mesh_solve):
+    """The mesh cell's 1,000-step solve: one shard-local kernel tagged
+    with the 2x2 shards, the 16 MiB a shard receives per block (a
+    4-deep 1024x512 face on sx, then a 1032x512 one on sy) and the
+    bytes of the window it assembles, and the grid, its output and the
+    temporaries inside a chip's HBM."""
+    compiled = mesh_solve
     tags = [json.loads(m) for m in re.findall(
         r"kernel_metadata=(\{.*?\})\}", compiled.as_text(), flags=re.DOTALL)]
-    assert [(t["strategy"], t["shards"], t["exchange_bytes"])
-            for t in tags] == [("window", "2x2",
-                                str((4 * 1024 + 4 * 1032) * 512 * 4))]
+    assert [(t["strategy"], t["shards"], t["exchange_bytes"],
+             t["window_bytes"]) for t in tags] == [
+        ("window", "2x2", str((4 * 1024 + 4 * 1032) * 512 * 4),
+         str(math.prod(MESH_WINDOW) * 4))]
     # one block a scan step: the kernel reads the exchanged window, not
     # the carry, so there is no carry copy for a second block to remove
     assert len(re.findall(r"%casper_fused[.\d]* = .* custom-call\(",
                           _loop_body(compiled.as_text()))) == 1
     mem = compiled.memory_analysis()
-    shard = 1024 * 1024 * 512 * 4
+    shard = math.prod(MESH_SHARD) * 4
     assert mem.argument_size_in_bytes == mem.output_size_in_bytes == shard
     assert 2 * shard + mem.temp_size_in_bytes < 12e9
+
+
+def _instructions(body: str) -> dict[str, tuple[str, tuple, list[str]]]:
+    """``{name: (opcode, largest result shape, operand names)}`` of the
+    instructions of one HLO computation."""
+    out = {}
+    for m in re.finditer(r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)"
+                         r"\(([^)]*)\)", body, flags=re.M):
+        name, result, opcode, operands = m.groups()
+        shapes = [tuple(int(v) for v in dims.split(",") if v)
+                  for dims in re.findall(r"[a-z]\w*\[([\d,]*)\]", result)]
+        out[name] = (opcode, max(shapes, key=math.prod, default=()),
+                     re.findall(r"%([\w.\-]+)", operands))
+    return out
+
+
+def test_mesh_cell_solve_builds_the_window_once_a_block(mesh_solve):
+    """The mesh path exchanges faces, not widened blocks, and assembles
+    the kernel's window once a block: in the loop body exactly one op,
+    neither kernel nor collective, makes the 1032x1032x640 window (one
+    pass over the shard); every other op there of a shard's size or
+    more writes one received face, under 64 MiB, into that window in
+    place; and the temporaries hold one window and at most 64 MiB
+    beside it, so no write copies the window."""
+    body = _instructions(_loop_body(mesh_solve.as_text()))
+    shard = math.prod(MESH_SHARD)
+    aliases = ("parameter", "get-tuple-element", "tuple", "bitcast")
+    big = {n: op for n, (op, shape, _) in body.items()
+           if math.prod(shape) >= shard and op not in aliases
+           and not n.startswith("casper_fused")}
+    builds = [n for n, op in big.items() if op != "dynamic-update-slice"]
+    assert len(builds) == 1, big
+    (build,) = builds
+    assert body[build][1] == MESH_WINDOW
+    assert "collective" not in big[build] and big[build] != "custom-call"
+    face_writes = set(big) - {build}
+    for n in face_writes:
+        window, face = body[n][2][:2]
+        assert body[n][1] == MESH_WINDOW
+        assert window == build or window in face_writes, (n, window)
+        assert math.prod(body[face][1]) * 4 < 64 * 2**20, (n, face)
+    window_bytes = math.prod(MESH_WINDOW) * 4
+    assert mesh_solve.memory_analysis().temp_size_in_bytes \
+        <= window_bytes + 64 * 2**20
+    tags = [json.loads(m) for m in re.findall(
+        r"kernel_metadata=(\{.*?\})\}", mesh_solve.as_text(),
+        flags=re.DOTALL)]
+    assert [(t["strategy"], t["exchange_bytes"], t["window_bytes"])
+            for t in tags] == [("window", "16842752", str(window_bytes))]
 
 
 def test_mesh_cell_reference_check_fits(mesh_cell):
